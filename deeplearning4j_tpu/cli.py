@@ -546,4 +546,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.runtime import ensure_compile_cache
+
+    ensure_compile_cache()
     sys.exit(main())

@@ -1,43 +1,26 @@
-"""jax version compatibility shims.
+"""The one ``shard_map`` import site.
 
-The package targets current jax (``jax.shard_map`` with ``check_vma``),
-but production fleets pin older runtimes — jax 0.4.x only ships
-``jax.experimental.shard_map`` whose replication check is spelled
-``check_rep``.  Before this module, every ``from jax import shard_map``
-import site hard-crashed at IMPORT time on 0.4.x, taking down not just
-the sharded trainers but everything that transitively imports
-``parallel/`` (the whole scaleout control plane, which contains no
-sharded code at all).  A robustness layer that promises self-healing
-training cannot lose its control plane to an import error.
-
-One shim, one rule: call it exactly like current ``jax.shard_map``
-(keyword ``mesh``/``in_specs``/``out_specs``, optional ``check_vma``);
-the shim translates for whichever jax is installed.
+Every module takes ``shard_map`` from here (jaxlint's ``raw-shard-map``
+rule enforces it), so the call convention — keyword ``mesh`` /
+``in_specs`` / ``out_specs``, optional ``check_vma`` — is spelled once.
 """
 
 from __future__ import annotations
 
 # jaxlint: disable-file=raw-shard-map — this module IS the designated
-# shim every other shard_map import is required to route through
+# import site every other shard_map user is required to route through
 
 from typing import Any, Callable
 
-try:                                      # jax >= 0.6: public API
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:                       # jax 0.4.x/0.5.x: experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
+from jax import shard_map as _shard_map
 
 
 def shard_map(f: Callable, *, mesh: Any, in_specs: Any, out_specs: Any,
               check_vma: "bool | None" = None, **kwargs: Any) -> Callable:
-    """``jax.shard_map`` with the replication-check kwarg translated to
-    whatever the installed jax calls it (``check_vma`` vs the old
-    ``check_rep``).  Extra kwargs pass through untouched."""
+    """``jax.shard_map`` with keyword-only placement arguments;
+    ``check_vma=None`` leaves JAX's default.  Extra kwargs pass through
+    untouched."""
     if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
+        kwargs["check_vma"] = check_vma
     return _shard_map(f, mesh=mesh, in_specs=in_specs,
                       out_specs=out_specs, **kwargs)

@@ -14,7 +14,10 @@ policy.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import dataclasses
+import logging
+import threading
+from typing import Callable, Optional, Tuple
 
 import jax
 
@@ -109,10 +112,76 @@ def resolve_kernel(kernel: str, block: int, desc: str
     return 0, False
 
 
-def kernel_name(pallas_block: int, pallas_interpret: bool) -> str:
-    """Human-readable verdict for a resolved (block, interpret) pair —
-    what benches record into round artifacts as the Mosaic
-    accept/reject evidence."""
-    if pallas_block and not pallas_interpret:
-        return "pallas"
-    return "pallas-interpret" if pallas_block else "xla"
+@dataclasses.dataclass(frozen=True)
+class KernelChoice:
+    """What a fit dispatched and WHY — kept on the fit object
+    (``kernel_used``) so the choice is readable afterwards: ``name`` is
+    "pallas" / "pallas-interpret" / "xla", ``block`` the Pallas grid
+    block (0 on the XLA path), ``why`` the request, the budget verdict
+    or the compiler's own message."""
+    name: str
+    block: int
+    interpret: bool
+    why: str
+
+
+def choose_kernel(kernel: str, block: int, desc: str,
+                  probe: Callable[[int], Optional[str]]) -> KernelChoice:
+    """:func:`resolve_kernel` plus, for ``auto`` on hardware, one real
+    compile: ``probe(block)`` returns None when Mosaic took the kernel
+    and its message when it did not, in which case auto uses XLA and
+    says so (an explicit ``kernel="pallas"`` is never probed: its
+    compile error surfaces from the fit itself)."""
+    blk, interpret = resolve_kernel(kernel, block, desc)
+    if kernel != "auto":
+        why = f"kernel={kernel!r} requested"
+    elif blk:
+        refusal = probe(blk)
+        if refusal is None:
+            why = f"auto: Mosaic compiled block {blk}"
+        else:
+            why, blk = f"auto: Mosaic refused block {blk}: {refusal}", 0
+    elif block:
+        why = f"auto: platform is {jax.devices()[0].platform}, not tpu"
+    else:
+        why = f"auto: no VMEM-resident block for {desc}"
+    name = ("xla" if not blk else
+            "pallas-interpret" if interpret else "pallas")
+    return KernelChoice(name, blk, interpret, why)
+
+
+def probe_mosaic(compile_once: Callable[[], None], what: str,
+                 timeout_s: float) -> Optional[str]:
+    """Run one real kernel compile (``compile_once`` must also fetch a
+    result value): None when Mosaic took it, else its message.  The
+    compile runs in a daemon thread joined with ``timeout_s``, so a
+    Mosaic compile that HANGS reads as a refusal and the fit proceeds
+    on XLA.  CAVEAT (ADVICE r4): a timeout verdict abandons the hung
+    compile thread ALIVE — it may still hold jaxlib's compile lock, so
+    the next in-process compile can block behind it until it finishes
+    or the process exits; a compile cannot be cancelled from Python,
+    and a killable-subprocess probe is impossible because by fit() time
+    this process already holds the (single-holder) TPU chip."""
+    failure = []
+
+    def attempt():
+        try:
+            compile_once()
+        except Exception as e:            # Mosaic/compile-specific
+            failure.append(e)
+
+    t = threading.Thread(target=attempt, daemon=True)
+    t.start()
+    t.join(timeout_s)
+    if t.is_alive():
+        refusal = (f"compile timed out after {timeout_s:.0f}s — the hung "
+                   f"Mosaic compile thread is abandoned alive and may "
+                   f"delay this process's next compile")
+    elif failure:
+        refusal = str(failure[0])
+    else:
+        return None
+    logging.getLogger(__name__).warning(
+        "%s Pallas kernel unavailable on this backend (%s); using the "
+        "XLA path", what, refusal)
+    return refusal

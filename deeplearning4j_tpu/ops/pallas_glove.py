@@ -30,6 +30,7 @@ interpreter mode).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,71 +47,94 @@ Array = jax.Array
 VMEM_BUDGET_BYTES = 14 * 2 ** 20
 
 
+def _pad(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _padded(vocab: int, dim: int):
+    """(Vp, Ep): table extents as the kernel sees them — vocab and the
+    extended width D+2 padded to whole 128-lane tiles, with at least one
+    spare column: the last one carries the per-row hit count."""
+    return _pad(vocab, 128), _pad(dim + 3, 128)
+
+
 def choose_block(vocab: int, dim: int, batch: int,
                  interpret: bool = False) -> int:
     """Largest grid block for which the VMEM model fits, else 0."""
-    # 2 extended fp32 tables + bf16 casts + 2 fp32 [V, 2D+3] accumulators
-    fixed = vocab * ((dim + 2) * (2 * 4 + 2 * 2) + 2 * (2 * dim + 3) * 4)
-    for blk in (2048, 1024):
+    vp, ep = _padded(vocab, dim)
+    # 2 bf16 tables (double-buffered by the pipeline) + 4 resident fp32
+    # [Vp, Ep] accumulators; per step two bf16 [Vp, BLK] one-hots.  At
+    # the bench shape (vocab 2000, dim 100) this admits 1024 and not
+    # 2048, which is where Mosaic's own VMEM accounting draws the line.
+    fixed = 2 * 2 * vp * ep * 2 + 4 * vp * ep * 4
+    for blk in (2048, 1024, 512, 256):
         if batch % blk:
             continue
-        if fixed + 2 * vocab * blk <= VMEM_BUDGET_BYTES:
+        if fixed + 2 * vp * blk * 2 <= VMEM_BUDGET_BYTES:
             return blk
     if interpret and batch <= 1024:
         return batch
     return 0
 
 
-def _kernel(rows_ref, cols_ref, x_ref, mask_ref,
-            wext_ref, wtext_ref, accw_ref, accwt_ref, loss_ref,
+def _kernel(rows_ref, cols_ref, x_ref, mask_ref, wext_ref, wtext_ref,
+            sw_ref, qw_ref, swt_ref, qwt_ref, loss_ref,
             *, x_max: float, power: float):
+    """Vocab-major throughout: one-hots are [Vp, BLK], the gathered
+    rows are [Ep, BLK], and every per-pair quantity is a [1, BLK] ROW
+    vector (a 1-D block is a layout Mosaic no longer takes, and a
+    column vector would need a lane-to-sublane move per use).  The
+    tables arrive bf16, padded to whole tiles."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
-        accw_ref[...] = jnp.zeros_like(accw_ref)
-        accwt_ref[...] = jnp.zeros_like(accwt_ref)
-        loss_ref[...] = jnp.zeros_like(loss_ref)
+        for ref in (sw_ref, qw_ref, swt_ref, qwt_ref):
+            ref[...] = jnp.zeros_like(ref)
+        loss_ref[0, 0] = 0.0
+        loss_ref[0, 1] = 0.0
 
     bf = jnp.bfloat16
-    BLK = rows_ref.shape[0]
-    V = wext_ref.shape[0]
-    E = wext_ref.shape[1]                       # D + 2
-    D = E - 2
+    BLK = rows_ref.shape[1]
+    Vp, Ep = wext_ref.shape
 
-    def one_hot_t(r):
-        iota = lax.broadcasted_iota(jnp.int32, (V, BLK), 0)
-        return (iota == r[None, :]).astype(bf)
-
-    ohr = one_hot_t(rows_ref[:])
-    ohc = one_hot_t(cols_ref[:])
-    wi = lax.dot_general(ohr, wext_ref[...].astype(bf),
-                         (((0,), (0,)), ((), ())),
-                         preferred_element_type=jnp.float32)  # [BLK, E]
-    wj = lax.dot_general(ohc, wtext_ref[...].astype(bf),
-                         (((0,), (0,)), ((), ())),
+    iota = lax.broadcasted_iota(jnp.int32, (Vp, BLK), 0)
+    ohr = (iota == rows_ref[...]).astype(bf)                   # [Vp, BLK]
+    ohc = (iota == cols_ref[...]).astype(bf)
+    rows0 = (((0,), (0,)), ((), ()))            # table^T . one-hot
+    wi = lax.dot_general(wext_ref[...], ohr, rows0,
+                         preferred_element_type=jnp.float32)   # [Ep, BLK]
+    wj = lax.dot_general(wtext_ref[...], ohc, rows0,
                          preferred_element_type=jnp.float32)
-    x = x_ref[:]
-    mask = mask_ref[:]
-    diff = jnp.sum(wi * wj, axis=1) - jnp.log(jnp.maximum(x, 1e-12))
+    x = x_ref[...]                                             # [1, BLK]
+    mask = mask_ref[...]
+    diff = jnp.sum(wi * wj, axis=0, keepdims=True) \
+        - jnp.log(jnp.maximum(x, 1e-12))
     fx = jnp.minimum((x / x_max) ** power, 1.0)
-    g = fx * diff * mask                                       # [BLK]
+    g = fx * diff * mask                                       # [1, BLK]
     loss_ref[0, 0] += 0.5 * jnp.sum(fx * diff * diff * mask)
     loss_ref[0, 1] += jnp.sum(mask)
 
-    def accumulate(acc_ref, oht, partner_cols):
-        grad = g[:, None] * partner_cols                       # [BLK, D+1]
-        payload = jnp.concatenate(
-            [grad, grad * grad, mask[:, None]], axis=1).astype(bf)
-        acc_ref[...] += lax.dot_general(
-            oht, payload, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # [V, 2D+3]
+    # the spare last column of each accumulator counts row hits: its
+    # payload row is the pair mask instead of a gradient
+    count_row = lax.broadcasted_iota(jnp.int32, (Ep, BLK), 0) == Ep - 1
 
-    # row side updates (w | b): partner columns = (wt_j | 1)
-    accumulate(accw_ref, ohr, wj[:, :D + 1])
-    # col side updates (wt | bt): partner columns = (w_i | 1)
-    accumulate(accwt_ref, ohc,
-               jnp.concatenate([wi[:, :D], wi[:, D + 1:D + 2]], axis=1))
+    def accumulate(s_ref, q_ref, oh, partner):
+        grad = g * partner                                     # [Ep, BLK]
+        # payloads are transposed in fp32 (the width Mosaic transposes
+        # natively) so the scatters are plain [Vp, BLK] x [BLK, Ep]
+        s_ref[...] += jnp.dot(
+            oh, jnp.where(count_row, mask, grad).T.astype(bf),
+            preferred_element_type=jnp.float32)                # [Vp, Ep]
+        q_ref[...] += jnp.dot(
+            oh, (grad * grad).T.astype(bf),
+            preferred_element_type=jnp.float32)
+
+    # row side updates (w | b): partner = (wt_j | 1 | .); col side
+    # updates (wt | . | bt): partner = (w_i | . | 1).  The dotted
+    # columns hold the other side's bias and are dropped outside.
+    accumulate(sw_ref, qw_ref, ohr, wj)
+    accumulate(swt_ref, qwt_ref, ohc, wi)
 
 
 @functools.partial(
@@ -131,33 +155,36 @@ def fused_glove_chunk(wext: Array, wtext: Array, rows: Array, cols: Array,
     assert NB * BLK == B, f"B={B} not a multiple of block={BLK}"
     V, E = wext.shape
     D = E - 2
-    W = 2 * D + 3
-    accw, accwt, loss = pl.pallas_call(
+    Vp, Ep = _padded(V, D)
+
+    def table(t):
+        return jnp.pad(t, ((0, Vp - V), (0, Ep - E))).astype(jnp.bfloat16)
+
+    vec = pl.BlockSpec((1, BLK), lambda i: (0, i))
+    full = pl.BlockSpec((Vp, Ep), lambda i: (0, 0))   # tables, accumulators
+    smem = None if pltpu is None else pltpu.SMEM
+    sw, qw, swt, qwt, loss = pl.pallas_call(
         functools.partial(_kernel, x_max=x_max, power=power),
         grid=(NB,),
-        in_specs=[
-            pl.BlockSpec((BLK,), lambda i: (i,)),          # rows
-            pl.BlockSpec((BLK,), lambda i: (i,)),          # cols
-            pl.BlockSpec((BLK,), lambda i: (i,)),          # x
-            pl.BlockSpec((BLK,), lambda i: (i,)),          # mask
-            pl.BlockSpec((V, E), lambda i: (0, 0)),
-            pl.BlockSpec((V, E), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((V, W), lambda i: (0, 0)),
-            pl.BlockSpec((V, W), lambda i: (0, 0)),
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((V, W), jnp.float32),
-            jax.ShapeDtypeStruct((V, W), jnp.float32),
-            jax.ShapeDtypeStruct((1, 2), jnp.float32),
-        ],
+        in_specs=[vec] * 4 + [full] * 2,
+        out_specs=[full] * 4 + [pl.BlockSpec(memory_space=smem)],
+        out_shape=[jax.ShapeDtypeStruct((Vp, Ep), jnp.float32)] * 4
+        + [jax.ShapeDtypeStruct((1, 2), jnp.float32)],
         interpret=interpret,
         compiler_params=None if (interpret or pltpu is None) else
         pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-    )(rows, cols, x.astype(jnp.float32), mask.astype(jnp.float32),
-      wext, wtext)
+    )(rows[None, :], cols[None, :], x.astype(jnp.float32)[None, :],
+      mask.astype(jnp.float32)[None, :], table(wext), table(wtext))
+
+    cnt = slice(Ep - 1, Ep)
+    accw = jnp.concatenate(
+        [sw[:V, :D + 1], qw[:V, :D + 1], sw[:V, cnt]], axis=1)
+
+    def col_side(a):                    # (wt | bt): skip the row bias
+        return jnp.concatenate([a[:V, :D], a[:V, D + 1:D + 2]], axis=1)
+
+    accwt = jnp.concatenate(
+        [col_side(swt), col_side(qwt), swt[:V, cnt]], axis=1)
     return accw, accwt, loss
 
 
@@ -179,59 +206,26 @@ _PROBE_CACHE: dict = {}
 
 
 def probe_compile(block: int, vocab_size: int = 128, dim: int = 8,
-                  timeout_s: float = 240.0) -> bool:
+                  timeout_s: float = 240.0) -> Optional[str]:
     """One real compile of the kernel at the given block size AND the
-    caller's actual (vocab, dim) — ``auto`` selection on hardware goes
-    through here so a Mosaic rejection degrades to the XLA path instead
-    of crashing fit() (the same guard pattern as the flash-attention
-    bench probe).  VMEM fit depends on the table shapes, so the probe
-    runs at the production shapes; cached per the full key.
+    caller's actual (vocab, dim): None when Mosaic took it, else its
+    message (``kernel_select.probe_mosaic``).  ``auto`` selection on
+    hardware goes through here (``kernel_select.choose_kernel``) so a
+    refusal degrades to the XLA path, and says so, instead of crashing
+    fit().  VMEM fit depends on the table shapes, so the probe runs at
+    the production shapes; cached per the full key."""
+    from deeplearning4j_tpu.ops.kernel_select import probe_mosaic
 
-    The compile runs in a daemon thread joined with ``timeout_s``: a
-    Mosaic compile that HANGS (round-3: glove died as a 900 s bench
-    timeout) reads as a reject and the fit proceeds on XLA.  CAVEAT
-    (ADVICE r4): a timeout verdict abandons the hung compile thread
-    ALIVE — it may still hold jaxlib's compile lock, so the subsequent
-    in-process XLA compile can block behind it until it finishes or the
-    process exits; there is no way to cancel a compile from Python, and
-    a killable-subprocess probe is impossible here because by fit()
-    time this process already holds the (single-holder) TPU chip.
-    Callers that can probe BEFORE backend init should do so in their
-    own subprocess — bench.py's ``_glove_mosaic_probe`` is that path."""
     key = (block, vocab_size, dim)
-    if key in _PROBE_CACHE:
-        return _PROBE_CACHE[key]
-
-    result = {}
-
-    def _try():
-        try:
-            V, D = vocab_size, dim
-            wext = jnp.zeros((V, D + 2), jnp.float32)
+    if key not in _PROBE_CACHE:
+        def compile_once():
+            wext = jnp.zeros((vocab_size, dim + 2), jnp.float32)
             rows = jnp.zeros((block,), jnp.int32)
             x = jnp.ones((block,), jnp.float32)
             accw, _, _ = fused_glove_chunk(
                 wext, wext, rows, rows, x, x, x_max=100.0, power=0.75,
                 block=block, interpret=False)
             float(accw[0, 0])
-            result["ok"] = True
-        except Exception as e:            # Mosaic/compile-specific
-            result["err"] = e
-            result["ok"] = False
 
-    import threading
-    t = threading.Thread(target=_try, daemon=True)
-    t.start()
-    t.join(timeout_s)
-    ok = bool(result.get("ok"))
-    if not ok:
-        import logging
-        why = ("compile timed out after %.0fs — the hung Mosaic compile "
-               "thread is abandoned alive and may delay this process's "
-               "next compile" % timeout_s
-               if t.is_alive() else result.get("err"))
-        logging.getLogger(__name__).warning(
-            "glove Pallas kernel unavailable on this backend (%s); "
-            "using the XLA path", why)
-    _PROBE_CACHE[key] = ok
-    return ok
+        _PROBE_CACHE[key] = probe_mosaic(compile_once, "glove", timeout_s)
+    return _PROBE_CACHE[key]

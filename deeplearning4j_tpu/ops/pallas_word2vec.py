@@ -14,20 +14,21 @@ reference's own test scale), via a DENSE-SCORES formulation:
 
 - syn0 / syn1 / syn1neg stay resident in VMEM (bf16) for the whole chunk;
 - ALL pair-vs-row dot products are computed at once:
-  ``scores = l1 · synᵀ`` — ONE [BLK, V] matmul per objective, amortized
+  ``scores = syn · l1ᵀ`` — ONE [V, BLK] matmul per objective, amortized
   over every HS level / negative partner, instead of one gather-matmul
   per level (the round-3 kernel's cost was ~4·V·D MXU flops per level
   per pair; this is ~6·V·D per OBJECTIVE per pair — ~4.7x fewer at
   Huffman depth ~14);
-- the per-level work drops to VPU-only: extract ``f = scores[b, pts]``
+- the per-level work drops to VPU-only: extract ``f = scores[pts, b]``
   by iota-compare, fold the resulting signed lr coefficient ``g`` into a
-  pair-major coefficient matrix ``G[b, v]`` (and its hit-mask twin
-  ``M``);
+  coefficient plane ``G[v, b]`` (and its hit-mask twin ``M``);
 - the level loop's matmuls then collapse to two per objective:
-  ``neu1e = G · syn`` (the input-side update) and ``acc += Gᵀ · l1``
-  (the output-side scatter), with per-row hit counts as column sums
-  of ``M`` — no [V, BLK]-narrow one-hots anywhere (pair-major [BLK, V]
-  layouts only, which Mosaic tiles cleanly at any BLK).
+  ``neu1e = synᵀ · G`` (the input-side update) and ``acc += G · l1ᵀ``
+  (the output-side scatter), with per-row hit counts as row sums of
+  ``M``;
+- every plane is VOCAB-major ``[V, BLK]`` and every per-pair quantity a
+  ``[1, BLK]`` row vector, so the level loop never moves data between
+  lanes and sublanes.
 
 The update math is IDENTICAL to ``nlp/word2vec._hs_update`` /
 ``_neg_update`` (bf16 matmuls, fp32 accumulation): per chunk, both
@@ -40,6 +41,7 @@ CPU test harness (tests/test_nlp.py compares it against the XLA path).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -70,17 +72,22 @@ def choose_block(vocab: int, dim: int, negative: int, batch: int,
     n_tables = 3 if negative > 0 else 2
     n_obj = 1 + (1 if negative > 0 else 0)
     vp = _pad(vocab, 128)
-    dp = _pad(dim, 128)
-    # bf16 tables + fp32 accumulators: acc0 is 2(D+1) wide, acc1/accn
-    # are [V, D+1] — pad(dim+1), not pad(dim): at dim%128==0 the +1
-    # forces a whole extra 128-lane tile per table (ADVICE r4)
-    fixed = n_tables * vocab * dp * 2 + \
-        vocab * (_pad(2 * (dim + 1), 128) + 2 * _pad(dim + 1, 128)) * 4
+    # pad(dim+1), not pad(dim): the spare last column of every
+    # accumulator carries the hit count, so at dim%128==0 the +1 costs a
+    # whole extra 128-lane tile per table (ADVICE r4)
+    dp = _pad(dim + 1, 128)
+    # bf16 tables (double-buffered by the pipeline) + resident fp32
+    # accumulators: two [V, Dp] for syn0 and one per output table
+    fixed = 2 * n_tables * vp * dp * 2 + (2 + n_obj) * vp * dp * 4
     for blk in (512, 256, 128):
         if batch % blk:
             continue
-        # per-step planes: oh0 + per-objective (scores + G + M), all bf16
-        planes = blk * vp * 2 * (1 + 3 * n_obj)
+        # per-step planes [V, BLK]: the bf16 input one-hot, and fp32
+        # scores + bf16 G + bf16 M of ONE objective (they run one after
+        # the other).  At the bench shape (vocab 2000, dim 100, HS +
+        # negative) this admits 256 and not 512, which is where Mosaic's
+        # own VMEM accounting draws the line.
+        planes = blk * vp * (2 + 4 + 2 + 2)
         if fixed + planes <= VMEM_BUDGET_BYTES:
             return blk
     if interpret and batch <= 1024:
@@ -91,32 +98,40 @@ def choose_block(vocab: int, dim: int, negative: int, batch: int,
 def _kernel(alpha_ref, inputs_ref, targets_ref, pmask_ref,
             codes_ref, points_ref, mask_ref, negs_ref,
             syn0_ref, syn1_ref, syn1neg_ref,
-            acc0_ref, acc1_ref, accn_ref,
+            acc0h_ref, acc0n_ref, acc1_ref, accn_ref,
             *, L: int, K: int, use_hs: bool):
+    """Vocab-major throughout: one-hots, scores and coefficient planes
+    are [V, BLK], gathered rows are [Dp, BLK], and every per-pair
+    quantity is a [1, BLK] ROW vector (a 1-D block is a layout Mosaic no
+    longer takes, and a column vector would need a lane-to-sublane move
+    per level).  Tables arrive bf16, padded to whole tiles; the spare
+    last column of each accumulator carries its hit count."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
-        acc0_ref[...] = jnp.zeros_like(acc0_ref)
-        acc1_ref[...] = jnp.zeros_like(acc1_ref)
-        accn_ref[...] = jnp.zeros_like(accn_ref)
+        for ref in (acc0h_ref, acc0n_ref, acc1_ref, accn_ref):
+            ref[...] = jnp.zeros_like(ref)
 
     bf = jnp.bfloat16
-    alpha = alpha_ref[0, 0]
-    BLK = inputs_ref.shape[0]
-    V0 = syn0_ref.shape[0]
+    alpha = alpha_ref[...]                                  # [1, 1]
+    BLK = inputs_ref.shape[1]
+    V0, Dp = syn0_ref.shape
+    rows0 = (((0,), (0,)), ((), ()))            # table^T . plane
 
-    def one_hot_pm(rows, v):
-        """[BLK, v] pair-major one-hot of ``rows`` [BLK] — iota compare
-        in VMEM (lane dim = vocab: wide layouts Mosaic tiles cleanly)."""
-        iota = lax.broadcasted_iota(jnp.int32, (BLK, v), 1)
-        return (iota == rows[:, None]).astype(bf)
+    def with_count(payload, count, axis):
+        """``payload`` with its spare last row/column (``axis``)
+        replaced by ``count`` (broadcast along the other axis)."""
+        idx = lax.broadcasted_iota(jnp.int32, payload.shape, axis)
+        return jnp.where(idx == Dp - 1, count, payload)
 
-    inp = inputs_ref[:]
-    oh0 = one_hot_pm(inp, V0)
-    l1 = lax.dot_general(oh0, syn0_ref[...], (((1,), (0,)), ((), ())),
-                         preferred_element_type=jnp.float32)  # [BLK, D]
+    oh0 = (lax.broadcasted_iota(jnp.int32, (V0, BLK), 0)
+           == inputs_ref[...]).astype(bf)                   # [V0, BLK]
+    l1 = lax.dot_general(syn0_ref[...], oh0, rows0,
+                         preferred_element_type=jnp.float32)  # [Dp, BLK]
     l1bf = l1.astype(bf)
+    # transposed in fp32 (the width Mosaic transposes natively)
+    l1t = l1.T.astype(bf)                                   # [BLK, Dp]
 
     def objective(syn_ref, coeff_levels, n_levels):
         """Shared dense-scores core: all pair-row dots in one matmul,
@@ -124,82 +139,73 @@ def _kernel(alpha_ref, inputs_ref, targets_ref, pmask_ref,
         M), then two matmuls recover the input-side update and the
         output-side accumulator payload.
 
-        ``coeff_levels(l, f) -> (rows, g, hit)``: the level's partner
-        rows [BLK], signed lr coefficient g [BLK] (from the extracted
-        dot products f [BLK]) and hit mask [BLK]."""
+        ``coeff_levels(l) -> (rows, g_fn, hit)``: the level's partner
+        rows [1, BLK], the map from the extracted dot products f
+        [1, BLK] to the signed lr coefficient g, and the hit mask
+        [1, BLK]."""
         v = syn_ref.shape[0]
-        scores = lax.dot_general(
-            l1bf, syn_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [BLK, v]
-        iota = lax.broadcasted_iota(jnp.int32, (BLK, v), 1)
+        scores = jnp.dot(syn_ref[...], l1bf,
+                         preferred_element_type=jnp.float32)   # [v, BLK]
+        iota = lax.broadcasted_iota(jnp.int32, (v, BLK), 0)
 
         def level(l, carry):
             G, M = carry
             rows, g_fn, hit = coeff_levels(l)
-            eq = iota == rows[:, None]                     # [BLK, v]
-            f = jnp.sum(jnp.where(eq, scores, 0.0), axis=1)
-            g = g_fn(f)                                    # [BLK] fp32
-            G = G + jnp.where(eq, g[:, None], 0.0).astype(bf)
-            M = M + jnp.where(eq, hit[:, None], 0.0).astype(bf)
+            eq = iota == rows                               # [v, BLK]
+            f = jnp.sum(jnp.where(eq, scores, 0.0), axis=0, keepdims=True)
+            g = g_fn(f)                                     # [1, BLK] fp32
+            G = G + jnp.where(eq, g, 0.0).astype(bf)
+            M = M + jnp.where(eq, hit, 0.0).astype(bf)
             return G, M
 
-        zero = jnp.zeros((BLK, v), bf)
+        zero = jnp.zeros((v, BLK), bf)
         G, M = lax.fori_loop(0, n_levels, level, (zero, zero))
         neu1e = lax.dot_general(
-            G, syn_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [BLK, D]
-        # output-side accumulator: [v, D] grad sums + [v] hit counts
-        dacc = lax.dot_general(
-            G, l1bf, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [v, D]
-        cnt = jnp.sum(M.astype(jnp.float32), axis=0)       # [v]
-        return neu1e, dacc, cnt
+            syn_ref[...], G, rows0,
+            preferred_element_type=jnp.float32)             # [Dp, BLK]
+        # output-side accumulator: [v, Dp] grad sums, hit counts in the
+        # spare column
+        dacc = jnp.dot(G, l1t, preferred_element_type=jnp.float32)
+        cnt = jnp.sum(M.astype(jnp.float32), axis=1, keepdims=True)
+        return neu1e, with_count(dacc, cnt, 1)
 
-    neu1e_hs = jnp.zeros_like(l1)
-    neu1e_ng = jnp.zeros_like(l1)
+    def scatter0(acc_ref, neu1e, row_hit):
+        """syn0 side: per-input-row update sums, each objective divided
+        by its OWN count outside (matching the XLA path exactly)."""
+        acc_ref[...] += jnp.dot(
+            oh0, with_count(neu1e, row_hit, 0).T.astype(bf),
+            preferred_element_type=jnp.float32)             # [V0, Dp]
 
     if use_hs:
         def hs_levels(l):
-            pts = points_ref[pl.dslice(l, 1), :][0]
-            code = codes_ref[pl.dslice(l, 1), :][0]
-            m = mask_ref[pl.dslice(l, 1), :][0]
-            return pts, (lambda f: (1.0 - code - jax.nn.sigmoid(f))
-                         * alpha * m), m
+            level = pl.dslice(l, 1)
+            code = codes_ref[level, :]                      # [1, BLK]
+            m = mask_ref[level, :]
+            return points_ref[level, :], (
+                lambda f: (1.0 - code - jax.nn.sigmoid(f)) * alpha * m), m
 
-        neu1e_hs, dacc1, cnt1 = objective(syn1_ref, hs_levels, L)
-        acc1_ref[...] += jnp.concatenate(
-            [dacc1, cnt1[:, None]], axis=1)
+        neu1e_hs, dacc1 = objective(syn1_ref, hs_levels, L)
+        acc1_ref[...] += dacc1
+        row_hs = (jnp.sum(mask_ref[...], axis=0, keepdims=True)
+                  > 0).astype(jnp.float32)
+        scatter0(acc0h_ref, neu1e_hs, row_hs)
 
     if K > 0:
-        tgt = targets_ref[:]
-        pmask = pmask_ref[:]
+        tgt = targets_ref[...]
+        pmask = pmask_ref[...]
 
         def ng_levels(k):
-            rows = lax.cond(
-                k == 0, lambda: tgt,
-                lambda: negs_ref[pl.dslice(jnp.maximum(k - 1, 0), 1),
-                                 :][0])
-            label = jnp.where(k == 0, 1.0, 0.0)
-            valid = jnp.where((k == 0) | (rows != tgt), 1.0, 0.0) * pmask
+            first = k == 0                      # level 0 is the target
+            rows = jnp.where(
+                first, tgt, negs_ref[pl.dslice(jnp.maximum(k - 1, 0), 1), :])
+            label = jnp.where(first, 1.0, 0.0)
+            valid = jnp.where(first | (rows != tgt), 1.0, 0.0) * pmask
             return rows, (lambda f: (label - jax.nn.sigmoid(f))
                           * alpha * valid), valid
 
-        neu1e_ng, daccn, cntn = objective(syn1neg_ref, ng_levels, K + 1)
-        accn_ref[...] += jnp.concatenate(
-            [daccn, cntn[:, None]], axis=1)
-
-    # syn0 accumulator: both objectives' contributions + their own count
-    # channels in ONE [V0, 2(D+1)] matmul (outside: each part is divided
-    # by its own count before the add, matching the XLA path exactly)
-    row_hs = (jnp.sum(mask_ref[...], axis=0) > 0).astype(jnp.float32) \
-        if use_hs else jnp.zeros((BLK,), jnp.float32)
-    row_ng = pmask_ref[:] if K > 0 else jnp.zeros((BLK,), jnp.float32)
-    payload0 = jnp.concatenate(
-        [neu1e_hs, row_hs[:, None], neu1e_ng, row_ng[:, None]],
-        axis=1).astype(bf)                               # [BLK, 2(D+1)]
-    acc0_ref[...] += lax.dot_general(
-        oh0, payload0, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        neu1e_ng, daccn = objective(syn1neg_ref, ng_levels, K + 1)
+        accn_ref[...] += daccn
+        scatter0(acc0n_ref, neu1e_ng, pmask)
 
 
 @functools.partial(
@@ -223,59 +229,58 @@ def fused_chunk_update(syn0: Array, syn1: Array, syn1neg: Array,
     NB = B // BLK
     assert NB * BLK == B, f"B={B} must be a multiple of block={BLK}"
     V0, D = syn0.shape
+    Dp = _pad(D + 1, 128)
 
+    def table(t):
+        """bf16 and padded to whole tiles: the kernel reads bf16 (halves
+        the VMEM footprint, no per-grid-step cast); the fp32 masters
+        stay out here where the accumulator updates are applied."""
+        return jnp.pad(t, ((0, _pad(t.shape[0], 128) - t.shape[0]),
+                           (0, Dp - D))).astype(jnp.bfloat16)
+
+    tables = [table(syn0), table(syn1), table(syn1neg)]
     codes = codes.astype(jnp.float32)
     mask = mask.astype(jnp.float32) * pmask[:, None]
-    grid = (NB,)
-    out_shapes = [
-        jax.ShapeDtypeStruct((V0, 2 * (D + 1)), jnp.float32),
-        jax.ShapeDtypeStruct((syn1.shape[0], D + 1), jnp.float32),
-        jax.ShapeDtypeStruct((syn1neg.shape[0], D + 1), jnp.float32),
-    ]
-    full = lambda r, c: pl.BlockSpec((r, c), lambda i: (0, 0))
-    acc0, acc1, accn = pl.pallas_call(
+
+    def full(shape):
+        return pl.BlockSpec(shape, lambda i: (0, 0))
+
+    def per_pair(n_rows):
+        return pl.BlockSpec((n_rows, BLK), lambda i: (0, i))
+
+    acc_shapes = [tables[0].shape, tables[0].shape,
+                  tables[1].shape, tables[2].shape]
+    acc0h, acc0n, acc1, accn = pl.pallas_call(
         functools.partial(_kernel, L=L, K=K, use_hs=use_hs),
-        grid=grid,
+        grid=(NB,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),          # alpha
-            pl.BlockSpec((BLK,), lambda i: (i,)),            # inputs
-            pl.BlockSpec((BLK,), lambda i: (i,)),            # targets
-            pl.BlockSpec((BLK,), lambda i: (i,)),            # pmask
-            pl.BlockSpec((L, BLK), lambda i: (0, i)),        # codes^T
-            pl.BlockSpec((L, BLK), lambda i: (0, i)),        # points^T
-            pl.BlockSpec((L, BLK), lambda i: (0, i)),        # mask^T
-            pl.BlockSpec((max(K, 1), BLK), lambda i: (0, i)),  # negs^T
-            full(*syn0.shape),
-            full(*syn1.shape),
-            full(*syn1neg.shape),
-        ],
-        out_specs=[
-            full(V0, 2 * (D + 1)),
-            full(syn1.shape[0], D + 1),
-            full(syn1neg.shape[0], D + 1),
-        ],
-        out_shape=out_shapes,
+            full((1, 1)),                                    # alpha
+            per_pair(1), per_pair(1), per_pair(1),   # inputs targets pmask
+            per_pair(L), per_pair(L), per_pair(L),   # codes^T points^T mask^T
+            per_pair(max(K, 1)),                             # negs^T
+        ] + [full(t.shape) for t in tables],
+        out_specs=[full(sh) for sh in acc_shapes],
+        out_shape=[jax.ShapeDtypeStruct(sh, jnp.float32)
+                   for sh in acc_shapes],
         interpret=interpret,
         compiler_params=None if (interpret or pltpu is None) else
         pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(jnp.reshape(alpha, (1, 1)).astype(jnp.float32),
-      inputs, targets, pmask,
+      inputs[None, :], targets[None, :], pmask[None, :],
       codes.T, points.T, mask.T,
       (negs.T if K > 0 else jnp.zeros((1, B), jnp.int32)),
-      # tables enter pre-cast: the kernel reads bf16 (halves their VMEM
-      # footprint and skips a per-grid-step cast); the fp32 masters stay
-      # out here where the accumulator updates are applied
-      syn0.astype(jnp.bfloat16), syn1.astype(jnp.bfloat16),
-      syn1neg.astype(jnp.bfloat16))
+      *tables)
+
+    def mean_update(acc, n_rows):
+        return acc[:n_rows, :D] / jnp.maximum(acc[:n_rows, Dp - 1:], 1.0)
 
     if use_hs:
-        syn1 = syn1 + acc1[:, :D] / jnp.maximum(acc1[:, D:], 1.0)
+        syn1 = syn1 + mean_update(acc1, syn1.shape[0])
     if K > 0:
-        syn1neg = syn1neg + accn[:, :D] / jnp.maximum(accn[:, D:], 1.0)
-    upd0 = acc0[:, :D] / jnp.maximum(acc0[:, D:D + 1], 1.0) \
-        + acc0[:, D + 1:2 * D + 1] / jnp.maximum(acc0[:, 2 * D + 1:], 1.0)
-    return syn0 + upd0, syn1, syn1neg
+        syn1neg = syn1neg + mean_update(accn, syn1neg.shape[0])
+    return (syn0 + mean_update(acc0h, V0) + mean_update(acc0n, V0),
+            syn1, syn1neg)
 
 
 _PROBE_CACHE: dict = {}
@@ -283,31 +288,25 @@ _PROBE_CACHE: dict = {}
 
 def probe_compile(block: int, use_hs: bool, negative: int,
                   vocab_size: int = 128, dim: int = 8,
-                  hs_depth: int = 4, timeout_s: float = 240.0) -> bool:
+                  hs_depth: int = 4, timeout_s: float = 240.0
+                  ) -> Optional[str]:
     """One real compile at the given statics AND the caller's actual
-    table shapes — ``auto`` selection on hardware goes through here so a
-    Mosaic rejection degrades to the XLA path instead of crashing fit()
+    table shapes: None when Mosaic took it, else its message
+    (``kernel_select.probe_mosaic``).  ``auto`` selection on hardware
+    goes through here (``kernel_select.choose_kernel``) so a refusal
+    degrades to the XLA path, and says so, instead of crashing fit()
     (explicit kernel='pallas' still surfaces the error).  Mosaic
     acceptance and VMEM fit depend on (vocab, dim, Huffman depth), not
     just the block statics, so the probe runs at the production shapes
-    and is cached per the full key.
+    and is cached per the full key."""
+    from deeplearning4j_tpu.ops.kernel_select import probe_mosaic
 
-    The compile runs in a daemon thread joined with ``timeout_s`` (the
-    same guard as pallas_glove.probe_compile, with the same caveat: a
-    timeout abandons the hung Mosaic compile thread alive, and it may
-    delay this process's next compile — but the fit proceeds on XLA
-    instead of hanging the whole bench window)."""
     key = (block, use_hs, negative, vocab_size, dim, hs_depth)
-    if key in _PROBE_CACHE:
-        return _PROBE_CACHE[key]
-
-    result = {}
-
-    def _try():
-        try:
+    if key not in _PROBE_CACHE:
+        def compile_once():
             V, D, L = vocab_size, dim, max(hs_depth, 1)
             z = jnp.zeros
-            _out = fused_chunk_update(
+            out = fused_chunk_update(
                 z((V, D)), z((V, D)) if use_hs else z((1, D)),
                 z((V, D)) if negative else z((1, D)),
                 z((block,), jnp.int32), z((block,), jnp.int32),
@@ -315,25 +314,8 @@ def probe_compile(block: int, use_hs: bool, negative: int,
                 z((block, max(negative, 1)), jnp.int32),
                 jnp.ones((block,)), jnp.float32(0.01), use_hs=use_hs,
                 negative=negative, block=block, interpret=False)
-            float(_out[0][0, 0])
-            result["ok"] = True
-        except Exception as e:            # Mosaic/compile-specific
-            result["err"] = e
-            result["ok"] = False
+            float(out[0][0, 0])
 
-    import threading
-    t = threading.Thread(target=_try, daemon=True)
-    t.start()
-    t.join(timeout_s)
-    ok = bool(result.get("ok"))
-    if not ok:
-        import logging
-        why = ("compile timed out after %.0fs — the hung Mosaic compile "
-               "thread is abandoned alive and may delay this process's "
-               "next compile" % timeout_s
-               if t.is_alive() else result.get("err"))
-        logging.getLogger(__name__).warning(
-            "word2vec Pallas kernel unavailable on this backend (%s); "
-            "using the XLA path", why)
-    _PROBE_CACHE[key] = ok
-    return ok
+        _PROBE_CACHE[key] = probe_mosaic(compile_once, "word2vec",
+                                         timeout_s)
+    return _PROBE_CACHE[key]

@@ -1,77 +1,44 @@
 """Runtime services: metrics, checkpointing, console, compile engine.
 
-Importing this package wires the OPT-IN persistent XLA compilation cache:
-set ``DL4J_TPU_COMPILATION_CACHE`` to a directory (or to ``1`` for the
-default ``~/.cache/dl4j_tpu_xla``) and every process that trains through
-the engine serializes its compiled executables there — repeated worker
-processes (``parallel/scaleout.py`` spawns N replicas of the same conf)
-then skip XLA compiles entirely and reload in seconds.  This is the
-cross-PROCESS analog of the in-process cross-network cache in
-``runtime/compile_cache.py``.
+:func:`ensure_compile_cache` is the ONE place in the tree that names a
+persistent XLA compile-cache directory.  The directory is placed from
+outside with JAX's own ``JAX_COMPILATION_CACHE_DIR``; when that is unset
+the cache lives at ``<checkout>/.jax_cache``.  The path is part of the
+cache key, so it is derived from this package's location and from
+nothing that moves between runs (home directory, temporary names, pids,
+the clock).  Entry points (``chip_smoke.py``, ``bench.py --inner``, the
+CLI) call it before their first compile; importing this package touches
+no JAX configuration.  The minimum compile time worth persisting is
+JAX's ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``.
 
-``DL4J_TPU_COMPILATION_CACHE_MIN_S`` (default 1.0) sets the minimum
-compile seconds below which executables are not worth persisting.
+This is the cross-PROCESS analog of the in-process cross-network cache
+in ``runtime/compile_cache.py``.
 """
 
 from __future__ import annotations
 
 import os
 
-PERSISTENT_CACHE_ENV = "DL4J_TPU_COMPILATION_CACHE"
-PERSISTENT_CACHE_MIN_S_ENV = "DL4J_TPU_COMPILATION_CACHE_MIN_S"
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: ``<checkout>/.jax_cache`` — the parent of the package directory
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def resolve_cache_dir(value: "str | None") -> "str | None":
-    """Resolve the env-var grammar to a concrete dir (or None=disabled):
-    empty/'0'/'false'/'off' disable; '1'/'true'/'on' mean the default
-    ``~/.cache/dl4j_tpu_xla``; anything else is the dir itself.  Shared
-    with bench.py so the parent process and its probe subprocesses can
-    never resolve the same env to different directories."""
-    v = (value or "").strip()
-    if not v or v.lower() in ("0", "false", "off"):
-        return None
-    if v.lower() in ("1", "true", "on"):
-        return os.path.join(os.path.expanduser("~"), ".cache",
-                            "dl4j_tpu_xla")
-    return v
+def ensure_compile_cache() -> str:
+    """Make sure this process has a persistent compile cache and return
+    its directory.  Call before the first compile: JAX decides once per
+    process whether the cache is in use.
 
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX has already read it, nothing
+    is touched.  Unset: ``jax_compilation_cache_dir`` is pointed at
+    :data:`DEFAULT_COMPILE_CACHE_DIR`."""
+    placed = os.environ.get(COMPILE_CACHE_ENV)
+    if placed:
+        return placed
+    import jax
 
-def setup_persistent_compilation_cache() -> str | None:
-    """Point jax at an on-disk compilation cache when the env var opts in.
-
-    Returns the cache dir in use, or None when disabled.  Never raises:
-    cache plumbing must not be able to break training (an unsupported
-    backend just logs jax's own warning and compiles normally).
-    """
-    path = resolve_cache_dir(os.environ.get(PERSISTENT_CACHE_ENV))
-    if path is None:
-        return None
-    raw_min_s = os.environ.get(PERSISTENT_CACHE_MIN_S_ENV, "1.0")
-    try:
-        min_s = float(raw_min_s)
-    except ValueError:
-        # one bad tuning knob must not silently switch the whole opted-in
-        # cache off — warn and keep the default threshold
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "%s=%r is not a float; using 1.0", PERSISTENT_CACHE_MIN_S_ENV,
-            raw_min_s)
-        min_s = 1.0
-    try:
-        # order matters: threshold BEFORE the cache dir — any failure then
-        # leaves the cache fully disabled (a dangling threshold with no
-        # dir is inert), never half-enabled behind a return value that
-        # reports it off
-        import jax
-
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_s)
-        jax.config.update("jax_compilation_cache_dir", path)
-    except Exception:
-        return None
-    return path
-
-
-#: resolved at import so any training entry point gets the cache for free
-PERSISTENT_CACHE_DIR = setup_persistent_compilation_cache()
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+    return DEFAULT_COMPILE_CACHE_DIR

@@ -728,7 +728,11 @@ checkpoint_metrics = CheckpointMetrics()
 
 #: published bf16 peak FLOP/s per chip by device_kind substring — the
 #: denominator of every MFU estimate (single source; bench.py and the
-#: autotuner both consult it here)
+#: autotuner both consult it here).  Source: Google Cloud TPU
+#: documentation, the per-version "System architecture" pages ("TPU
+#: v5e": 197 TFLOP/s bf16 per chip; v5p 459, v6e/Trillium 918, v4 275,
+#: v3 123, v2 45).  JAX reports a v5e chip as device_kind "TPU v5 lite".
+#: HBM bytes/s and int8 OP/s columns are ROADMAP S2's.
 TPU_PEAK_FLOPS = (
     ("v6", 918e12), ("trillium", 918e12),
     ("v5p", 459e12), ("v5e", 197e12), ("v5 lite", 197e12),

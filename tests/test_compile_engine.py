@@ -254,21 +254,3 @@ def test_no_stray_jit_in_hot_paths():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     assert mod.find_stray_jits(REPO_ROOT) == []
-
-
-# -- persistent on-disk cache wiring ---------------------------------------
-
-def test_persistent_cache_env_opt_in(tmp_path, monkeypatch):
-    from deeplearning4j_tpu import runtime
-
-    monkeypatch.delenv(runtime.PERSISTENT_CACHE_ENV, raising=False)
-    assert runtime.setup_persistent_compilation_cache() is None
-
-    prev = jax.config.jax_compilation_cache_dir
-    cache_dir = str(tmp_path / "xla_cache")
-    monkeypatch.setenv(runtime.PERSISTENT_CACHE_ENV, cache_dir)
-    try:
-        assert runtime.setup_persistent_compilation_cache() == cache_dir
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)

@@ -1,13 +1,10 @@
 """raw-shard-map: ``shard_map`` is only reached via ``compat.py``.
 
-The repo supports both jax 0.4.x (``jax.experimental.shard_map`` with
-``check_rep``) and current jax (``jax.shard_map`` with ``check_vma``)
-through one shim — ``deeplearning4j_tpu/compat.py`` — which translates
-the replication-check kwarg.  A direct import anywhere else either
-crashes on one jax generation or silently skips the replication check
-on the other.  ``compat.py`` itself carries a file-wide
-``# jaxlint: disable-file=raw-shard-map`` (it IS the shim) rather than
-a path exemption baked in here.
+``deeplearning4j_tpu/compat.py`` is the one import site: it spells the
+call convention (keyword placement arguments, ``check_vma``) once, so a
+change in JAX's ``shard_map`` surface is a one-file edit.  ``compat.py``
+itself carries a file-wide ``# jaxlint: disable-file=raw-shard-map``
+(it IS the import site) rather than a path exemption baked in here.
 """
 
 from __future__ import annotations
@@ -18,7 +15,7 @@ from typing import Iterable
 from tools.jaxlint.core import Finding, Rule, register
 
 _MSG = ("direct shard_map import bypasses deeplearning4j_tpu/compat.py "
-        "(the check_rep/check_vma shim); use "
+        "(the one shard_map import site); use "
         "'from deeplearning4j_tpu.compat import shard_map'")
 
 
