@@ -2379,8 +2379,8 @@ def bench_decode_serving(n_requests: int = 24, n_clients: int = 8,
         "requests_completed": snap["requests_completed"],
         "ttft_p50_ms": snap["ttft_p50_ms"],
         "ttft_p99_ms": snap["ttft_p99_ms"],
-        "tok_p50_ms": snap["tok_p50_ms"],
-        "tok_p99_ms": snap["tok_p99_ms"],
+        "advance_mean_ms": round(
+            1e3 * snap["advance_s"] / max(snap["decode_dispatches"], 1), 3),
         "slot_occupancy": snap["slot_occupancy"],
         "mid_flight_joins": snap["joins"],
         # 2 executables (prefill + step) per cache-length bucket, then 0

@@ -99,34 +99,22 @@ def say(tag: str, /, **facts: Any) -> None:
           flush=True)
 
 
-class CompileLedger:
-    """XLA-level compile counts from ``jax.monitoring``: compile requests
-    (every lowering handed to the backend, persistent-cache hits
-    included), persistent-cache hits and persistent-cache writes.  The
-    repo's own ``compile_metrics`` counts TRACES; this sits beside it so
-    a retrace-free recompile cannot hide."""
+def xla_compiles() -> Dict[str, int]:
+    """XLA-level compile counts (``compile_metrics``, from
+    ``jax.monitoring``): compile requests (every lowering handed to the
+    backend, persistent-cache hits included), persistent-cache hits and
+    misses.  They stand beside the TRACE counts so a retrace-free
+    recompile cannot hide."""
+    from deeplearning4j_tpu.runtime.metrics import compile_metrics
 
-    def __init__(self) -> None:
-        import jax.monitoring as mon
+    snap = compile_metrics.snapshot()
+    return {k: snap[k] for k in ("xla_compile_requests",
+                                 "persistent_cache_hits",
+                                 "persistent_cache_misses")}
 
-        self.requests = self.hits = self.writes = 0
-        mon.register_event_listener(self._on_event)
-        mon.register_event_duration_secs_listener(self._on_duration)
 
-    def _on_event(self, event: str, **kw: Any) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.writes += 1
-
-    def _on_duration(self, event: str, secs: float, **kw: Any) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.requests += 1
-
-    def snapshot(self) -> Dict[str, int]:
-        return {"xla_compile_requests": self.requests,
-                "persistent_cache_hits": self.hits,
-                "persistent_cache_writes": self.writes}
+def xla_requests() -> int:
+    return xla_compiles()["xla_compile_requests"]
 
 
 # ---------------------------------------------------------------------------
@@ -212,14 +200,14 @@ def fit_once(lm: Any, batches: List[Any], mesh: Any) -> Dict[str, Any]:
     log = ScoreLog()
     lm.listeners = [log]
     traces0 = compile_metrics.snapshot()["compile_count"]
-    xla0 = LEDGER.requests
+    xla0 = xla_requests()
     t0 = time.perf_counter()
     lm.fit_backprop(batches, num_epochs=1, seed=2, mesh=mesh)
     jax.block_until_ready(lm.params)
     return {"scores": log.scores,
             "wall_s": round(time.perf_counter() - t0, 3),
             "traces": compile_metrics.snapshot()["compile_count"] - traces0,
-            "xla_compile_requests": LEDGER.requests - xla0}
+            "xla_compile_requests": xla_requests() - xla0}
 
 
 def phase_train(cfg: Any, sz: Sizes, device: Dict[str, Any],
@@ -347,15 +335,15 @@ def phase_serve(cfg: Any, params: Any, sz: Sizes
                                                    DecodeEngine)
 
     eng = DecodeEngine(cfg, params, n_slots=sz.n_slots, paged=True)
-    xla0 = LEDGER.requests
+    xla0 = xla_requests()
     warm = eng.warmup()
     say("serve", buckets=eng.buckets, page_tokens=eng.page_tokens,
         kv_pages=eng.n_kv_pages, pool_MB=round(eng.pool_bytes / 1e6, 1),
-        warmup=warm, xla_compile_requests=LEDGER.requests - xla0)
+        warmup=warm, xla_compile_requests=xla_requests() - xla0)
 
     prompts = serve_prompts(cfg, sz)
     registry.mark()
-    xla0 = LEDGER.requests
+    xla0 = xla_requests()
     replayed0 = decode_metrics.snapshot()["requests_replayed"]
     t0 = time.perf_counter()
     batcher = ContinuousBatcher(eng, default_max_tokens=sz.max_tokens)
@@ -367,7 +355,7 @@ def phase_serve(cfg: Any, params: Any, sz: Sizes
         batcher.close()
     wall = time.perf_counter() - t0
     traces = registry.compile_delta_since_mark()
-    xla = LEDGER.requests - xla0
+    xla = xla_requests() - xla0
     snap = decode_metrics.snapshot()
     say("serve", requests=len(outs), prompt_lens=[len(p) for p in prompts],
         tokens_each=sz.max_tokens, wall_s=round(wall, 3),
@@ -663,11 +651,7 @@ def phase_four_chip(cfg: Any, sz: Sizes, device: Dict[str, Any],
 
 # ---------------------------------------------------------------------------
 
-LEDGER: CompileLedger
-
-
 def main(argv: Optional[List[str]] = None) -> int:
-    global LEDGER
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tiny", action="store_true",
                     help="rehearse the control flow at gpt_tiny "
@@ -679,7 +663,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     device = phase_device(args.tiny)
     from deeplearning4j_tpu.models import gpt
 
-    LEDGER = CompileLedger()
     sz = TINY if args.tiny else FULL
     cfg = gpt.gpt_tiny() if args.tiny else gpt.gpt_config()
     say("device", size="tiny" if args.tiny else "full", config=cfg)
@@ -696,7 +679,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             out = None
         say(name, phase="passed" if name not in failed else "FAILED",
             phase_wall_s=round(time.perf_counter() - t0, 1),
-            **LEDGER.snapshot())
+            **xla_compiles())
         return out
 
     run("device", lambda: phase_peaks(device))
@@ -721,7 +704,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     say("summary", failed=failed or None,
         total_wall_s=round(time.perf_counter() - t_start, 1),
-        **LEDGER.snapshot())
+        **xla_compiles())
     faulthandler.cancel_dump_traceback_later()
     if failed:
         return 1

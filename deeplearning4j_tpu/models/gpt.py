@@ -96,10 +96,11 @@ def lm_loss(cfg: TransformerConfig, params: PyTree, token_ids: Array,
     """Next-token CE: predict token_ids[:, 1:] from positions [:, :-1]."""
     hidden = tfm.encode(cfg, params, token_ids, mask, None, dropout_key,
                         attn_fn=attn_fn)
-    logits = lm_logits(cfg, params, hidden[:, :-1])
     targets = token_ids[:, 1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    with jax.named_scope("readout"):
+        logits = lm_logits(cfg, params, hidden[:, :-1])
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     if mask is not None:
         w = mask[:, 1:]
         return -jnp.sum(ll * w) / jnp.maximum(jnp.sum(w), 1.0)
@@ -325,13 +326,14 @@ def _prefill_chunk(cfg: TransformerConfig, params: PyTree, cache: KVCache,
         new_k.append(k_cache)
         new_v.append(v_cache)
 
-        scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
-        s = jnp.einsum("bqnd,bknd->bnqk", q.astype(cdt), k_read,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid[None, None, :, :], s, -1e9)
-        probs = jax.nn.softmax(s, axis=-1).astype(cdt)
-        a = jnp.einsum("bnqk,bknd->bqnd", probs, v_read,
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("attention"):
+            scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
+            s = jnp.einsum("bqnd,bknd->bnqk", q.astype(cdt), k_read,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid[None, None, :, :], s, -1e9)
+            probs = jax.nn.softmax(s, axis=-1).astype(cdt)
+            a = jnp.einsum("bnqk,bknd->bqnd", probs, v_read,
+                           preferred_element_type=jnp.float32)
         a = jnp.einsum("btnd,ndh->bth", a.astype(cdt), p["wo"].astype(cdt),
                        preferred_element_type=jnp.float32) + p["bo"]
         x = tfm.layer_norm(x + a, p["ln1_g"], p["ln1_b"], cfg.layer_norm_eps)
@@ -344,7 +346,8 @@ def _prefill_chunk(cfg: TransformerConfig, params: PyTree, cache: KVCache,
                        preferred_element_type=jnp.float32) + p["b2"]
         x = tfm.layer_norm(x + f, p["ln2_g"], p["ln2_b"], cfg.layer_norm_eps)
 
-    logits = lm_logits(cfg, params, x)                        # [B, C, V]
+    with jax.named_scope("readout"):
+        logits = lm_logits(cfg, params, x)                    # [B, C, V]
     if quant:
         return QKVCache(jnp.stack(new_k), jnp.stack(new_v),
                         jnp.stack(new_ks), jnp.stack(new_vs)), logits
@@ -608,13 +611,14 @@ def slot_decode(cfg: TransformerConfig, params: PyTree, slots: DecodeSlots,
         new_k.append(k_cache)
         new_v.append(v_cache)
 
-        scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
-        s = jnp.einsum("bqnd,bknd->bnqk", q.astype(cdt), k_read,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid[:, None, None, :], s, -1e9)
-        probs = jax.nn.softmax(s, axis=-1).astype(cdt)
-        a = jnp.einsum("bnqk,bknd->bqnd", probs, v_read,
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("attention"):
+            scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
+            s = jnp.einsum("bqnd,bknd->bnqk", q.astype(cdt), k_read,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid[:, None, None, :], s, -1e9)
+            probs = jax.nn.softmax(s, axis=-1).astype(cdt)
+            a = jnp.einsum("bnqk,bknd->bqnd", probs, v_read,
+                           preferred_element_type=jnp.float32)
         a = jnp.einsum("btnd,ndh->bth", a.astype(cdt), p["wo"].astype(cdt),
                        preferred_element_type=jnp.float32) + p["bo"]
         x = tfm.layer_norm(x + a, p["ln1_g"], p["ln1_b"], cfg.layer_norm_eps)
@@ -627,9 +631,10 @@ def slot_decode(cfg: TransformerConfig, params: PyTree, slots: DecodeSlots,
                        preferred_element_type=jnp.float32) + p["b2"]
         x = tfm.layer_norm(x + f, p["ln2_g"], p["ln2_b"], cfg.layer_norm_eps)
 
-    logits = lm_logits(cfg, params, x)[:, 0, :]               # [S, V]
-    keys = jax.vmap(_slot_key)(seeds, pos)
-    nxt = jax.vmap(sample_token)(logits, keys, temperature)
+    with jax.named_scope("readout"):
+        logits = lm_logits(cfg, params, x)[:, 0, :]           # [S, V]
+        keys = jax.vmap(_slot_key)(seeds, pos)
+        nxt = jax.vmap(sample_token)(logits, keys, temperature)
     act = active.astype(jnp.int32)
     return DecodeSlots(
         jnp.stack(new_k), jnp.stack(new_v),
@@ -752,6 +757,7 @@ def paged_specs(cfg: TransformerConfig,
     return PagedKV(k=h, v=h)
 
 
+@jax.named_scope("paged_view")
 def _paged_view(pool: PagedKV, ptab: Array, tokens: Array,
                 pos: Array) -> DecodeSlots:
     """Gather per-slot page tables into the slot-structured view
@@ -769,6 +775,7 @@ def _paged_view(pool: PagedKV, ptab: Array, tokens: Array,
                        pool.v_scale[:, ptab].reshape(L, S, TBL * C))
 
 
+@jax.named_scope("pool_write_back")
 def _pool_write_back(pool: PagedKV, view: DecodeSlots, ptab: Array,
                      posw: Array, active: Array) -> PagedKV:
     """Persist the rows a slot kernel just wrote at positions ``posw``
@@ -807,33 +814,38 @@ def paged_prefill(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
     L, Pn, C, NH, D = pool.k.shape
     TBL = ptab_s.shape[0]
     quant = pool.k_scale is not None
-    k = pool.k[:, ptab_s].reshape(L, 1, TBL * C, NH, D)
-    v = pool.v[:, ptab_s].reshape(L, 1, TBL * C, NH, D)
-    if quant:
-        cache_in = QKVCache(k, v,
-                            pool.k_scale[:, ptab_s].reshape(L, 1, TBL * C),
-                            pool.v_scale[:, ptab_s].reshape(L, 1, TBL * C))
-    else:
-        cache_in = KVCache(k, v)
+    with jax.named_scope("prefill_page_io"):
+        k = pool.k[:, ptab_s].reshape(L, 1, TBL * C, NH, D)
+        v = pool.v[:, ptab_s].reshape(L, 1, TBL * C, NH, D)
+        if quant:
+            cache_in = QKVCache(
+                k, v, pool.k_scale[:, ptab_s].reshape(L, 1, TBL * C),
+                pool.v_scale[:, ptab_s].reshape(L, 1, TBL * C))
+        else:
+            cache_in = KVCache(k, v)
     cache, logits = _prefill_chunk(cfg, params, cache_in, toks[None, :],
                                    start)
-    last = lax.dynamic_slice_in_dim(logits[0], n_valid - 1, 1, axis=0)[0]
-    first = sample_token(last, _slot_key(seed, start + n_valid - 1),
-                         temperature)
-    pid = ptab_s[start // C]
-    page_k = lax.dynamic_slice(cache.k, (0, 0, start, 0, 0),
-                               (L, 1, C, NH, D))[:, 0]
-    page_v = lax.dynamic_slice(cache.v, (0, 0, start, 0, 0),
-                               (L, 1, C, NH, D))[:, 0]
-    pool = pool._replace(k=pool.k.at[:, pid].set(page_k),
-                         v=pool.v.at[:, pid].set(page_v))
-    if quant:
-        ps_k = lax.dynamic_slice(cache.k_scale, (0, 0, start),
-                                 (L, 1, C))[:, 0]
-        ps_v = lax.dynamic_slice(cache.v_scale, (0, 0, start),
-                                 (L, 1, C))[:, 0]
-        pool = pool._replace(k_scale=pool.k_scale.at[:, pid].set(ps_k),
-                             v_scale=pool.v_scale.at[:, pid].set(ps_v))
+    with jax.named_scope("readout"):
+        last = lax.dynamic_slice_in_dim(logits[0], n_valid - 1, 1,
+                                        axis=0)[0]
+        first = sample_token(last, _slot_key(seed, start + n_valid - 1),
+                             temperature)
+    with jax.named_scope("prefill_page_io"):
+        pid = ptab_s[start // C]
+        page_k = lax.dynamic_slice(cache.k, (0, 0, start, 0, 0),
+                                   (L, 1, C, NH, D))[:, 0]
+        page_v = lax.dynamic_slice(cache.v, (0, 0, start, 0, 0),
+                                   (L, 1, C, NH, D))[:, 0]
+        pool = pool._replace(k=pool.k.at[:, pid].set(page_k),
+                             v=pool.v.at[:, pid].set(page_v))
+        if quant:
+            ps_k = lax.dynamic_slice(cache.k_scale, (0, 0, start),
+                                     (L, 1, C))[:, 0]
+            ps_v = lax.dynamic_slice(cache.v_scale, (0, 0, start),
+                                     (L, 1, C))[:, 0]
+            pool = pool._replace(
+                k_scale=pool.k_scale.at[:, pid].set(ps_k),
+                v_scale=pool.v_scale.at[:, pid].set(ps_v))
     return pool, first
 
 

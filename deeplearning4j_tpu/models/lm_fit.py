@@ -228,11 +228,12 @@ class CausalLM:
             else:
                 hidden = tfm.encode(cfg, params, ids, None, None, key,
                                     attn_fn=attn_fn)
-            logits = gpt.lm_logits(cfg, params, hidden[:, :-1])
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            ll = jnp.take_along_axis(logp, ids[:, 1:, None],
-                                     axis=-1)[..., 0]
-            nll = -jnp.sum(ll * rmask[:, None])
+            with jax.named_scope("readout"):
+                logits = gpt.lm_logits(cfg, params, hidden[:, :-1])
+                logp = jax.nn.log_softmax(logits, axis=-1)
+                ll = jnp.take_along_axis(logp, ids[:, 1:, None],
+                                         axis=-1)[..., 0]
+                nll = -jnp.sum(ll * rmask[:, None])
             if is_moe:
                 count = jnp.sum(rmask) * (ids.shape[1] - 1)
                 nll = nll + cfg.aux_loss_weight * aux * count
@@ -367,17 +368,24 @@ class CausalLM:
         stack, stage pre-sharded onto the mesh, and run the WHOLE fit
         as ONE donated dispatch (mesh=None streams the same step on one
         device, still one dispatch via the scanned builder)."""
-        from deeplearning4j_tpu.parallel import sharded_fit
-
         batches = [data] if isinstance(data, DataSet) else list(data)
         if not batches:
             return
+        with telemetry.span("lm.fit_backprop", batches=len(batches),
+                            epochs=num_epochs):
+            self._fit_backprop(batches, num_epochs, seed, mesh)
+
+    def _fit_backprop(self, batches: List[DataSet], num_epochs: int,
+                      seed: int, mesh) -> None:
+        from deeplearning4j_tpu.parallel import sharded_fit
+
         self._notify_fit_start()
         accum = max(self.conf.grad_accum, 1)
         chunk = self._pad_chunk(mesh, accum)
-        params = jax.tree.map(jnp.copy, self._require_params())
         train_step, train_epochs, _ = self._backprop_machinery(mesh)
-        ustate = train_step.init_ustate(params)
+        with telemetry.span("lm.copy_params"):
+            params = jax.tree.map(jnp.copy, self._require_params())
+            ustate = train_step.init_ustate(params)
         target = max(-(-b.features.shape[0] // chunk) * chunk
                      for b in batches)
         with telemetry.span("lm.stage", batches=len(batches),
@@ -405,9 +413,12 @@ class CausalLM:
                 steps=num_epochs * len(batches), accum=accum,
                 data_degree=(mesh.shape[DATA_AXIS]
                              if mesh is not None else 1))
+        with telemetry.span("lm.fetch"):
+            # the host's read of the skip flags, then of the scores:
+            # both wait for the program
             self._note_skips(skips)
-        if self.listeners:
-            for j, s in enumerate(np.asarray(scores).ravel()):
-                for ls in self.listeners:
-                    ls.iteration_done(self, j, float(s))
+            if self.listeners:
+                for j, s in enumerate(np.asarray(scores).ravel()):
+                    for ls in self.listeners:
+                        ls.iteration_done(self, j, float(s))
         self.params = params

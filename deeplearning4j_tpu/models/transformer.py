@@ -185,6 +185,7 @@ def layer_norm(x: Array, g: Array, b: Array, eps: float) -> Array:
     return (x - mu) * lax.rsqrt(var + eps) * g + b
 
 
+@jax.named_scope("attention")
 def attention(q: Array, k: Array, v: Array, mask: Optional[Array],
               causal: bool = False) -> Array:
     """Plain fused attention: [B, T, NH, D] -> [B, T, NH, D].

@@ -1,15 +1,14 @@
-"""Observability: per-step metrics, throughput, profiler hooks.
+"""Observability: counter families, per-step metrics, throughput.
 
 The reference's tracing story is a single ``ScoreIterationListener`` plus
 coarse YARN metrics maps (SURVEY.md §5.1/§5.5).  The TPU upgrade budgeted
-there: real per-step timing, a JSONL scalars sink (renders anywhere), and
-``jax.profiler`` trace capture around training windows (XLA op-level
-profiles in TensorBoard format).
+there: real per-step timing and a JSONL scalars sink (renders anywhere).
+Spans, and their names in a ``jax.profiler`` trace, are
+``runtime/telemetry.span``.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
@@ -35,11 +34,22 @@ class CompileMetrics:
       already-compiled executable (no trace).
     - ``traces``: per-label trace counts, e.g.
       ``{"multilayer.train_step": 1}``.
+    - ``xla_compile_requests`` / ``persistent_cache_hits`` /
+      ``persistent_cache_misses``: what XLA itself was asked for, from
+      ``jax.monitoring`` — every lowering handed to the backend
+      (persistent-cache hits included) by ANY jit in the process, and
+      the persistent cache's hits and misses.  A recompile without a
+      retrace shows here and nowhere above.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
         self.reset()
+        # once per instance, never in reset(): jax.monitoring keeps its
+        # listeners for the life of the process
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
 
     def reset(self) -> None:
         with self._lock:
@@ -49,6 +59,22 @@ class CompileMetrics:
             self.engine_hits = 0
             self.cached_dispatches = 0
             self.traces: Dict[str, int] = {}
+            self.xla_compile_requests = 0
+            self.persistent_cache_hits = 0
+            self.persistent_cache_misses = 0
+
+    def _on_event(self, event: str, **kw: Any) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            with self._lock:
+                self.persistent_cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            with self._lock:
+                self.persistent_cache_misses += 1
+
+    def _on_duration(self, event: str, secs: float, **kw: Any) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            with self._lock:
+                self.xla_compile_requests += 1
 
     def note_trace(self, label: str) -> None:
         with self._lock:
@@ -79,6 +105,9 @@ class CompileMetrics:
                 "engine_hits": self.engine_hits,
                 "cached_dispatches": self.cached_dispatches,
                 "traces": dict(self.traces),
+                "xla_compile_requests": self.xla_compile_requests,
+                "persistent_cache_hits": self.persistent_cache_hits,
+                "persistent_cache_misses": self.persistent_cache_misses,
             }
 
 
@@ -264,8 +293,8 @@ class DecodeMetrics:
       PER-BATCHER pending depth (each batcher reports its own count;
       with multiple router replicas this is a replica-level gauge, not
       a fleet total — ``Router.depths()`` is the fleet view);
-    - time-to-first-token and per-token latency reservoirs (bounded) ->
-      ``ttft_p50_ms``/``ttft_p99_ms`` and ``tok_p50_ms``/``tok_p99_ms``;
+    - a time-to-first-token reservoir (bounded) -> ``ttft_p50_ms``/
+      ``ttft_p99_ms``;
     - ``mark_compiles()`` / ``compile_delta_since_mark``: same
       steady-state zero-compile assertion primitive as ServingMetrics.
 
@@ -323,9 +352,28 @@ class DecodeMetrics:
     - ``pages_leaked``: gauge — allocator page references not accounted
       for by any live slot or the resident-prefix registry after the
       last release (nonzero means a reclaim path missed pages).
+
+    Where the worker thread's time goes — cumulative seconds, each the
+    sum of the durations of the ``telemetry.span`` named beside it
+    (``add_seconds`` is what a span's ``counter=`` calls on exit), and
+    the counts their means are taken over:
+
+    - ``rounds`` / ``round_s`` (``decode.round``): passes of the
+      batcher's loop that admitted or advanced anything;
+    - ``admissions`` / ``queue_wait_s``: requests taken off the queue
+      and the time each had spent since its submit, booked where the
+      wait ends; ``prefill_s`` (``decode.prefill``): their joins, every
+      chunk's dispatch and the wait for the first token, and
+      ``prefill_sync_s`` (``decode.prefill.sync``) that wait alone;
+    - ``advance_s`` (``decode.advance``): ``engine.advance`` calls, one
+      per ``decode_dispatches``; ``fetch_s`` (``decode.fetch``): the
+      part of them spent waiting for the step's tokens.
     """
 
     MAX_SAMPLES = 8192
+    #: the cumulative-seconds counters a span may name
+    SECONDS = ("round_s", "queue_wait_s", "prefill_s", "prefill_sync_s",
+               "advance_s", "fetch_s")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -366,9 +414,27 @@ class DecodeMetrics:
             self.brownout_transitions = 0
             self.brownout_level = 0
             self.pages_leaked = 0
+            self.rounds = 0
+            self.admissions = 0
+            for key in self.SECONDS:
+                setattr(self, key, 0.0)
             self._ttft_ms: List[float] = []
-            self._tok_ms: List[float] = []
             self._compile_mark: Optional[int] = None
+
+    def add_seconds(self, key: str, seconds: float) -> None:
+        if key not in self.SECONDS:
+            raise KeyError(f"no cumulative-seconds counter {key!r}")
+        with self._lock:
+            setattr(self, key, getattr(self, key) + seconds)
+
+    def note_round(self) -> None:
+        with self._lock:
+            self.rounds += 1
+
+    def note_admission(self, queue_wait_s: float) -> None:
+        with self._lock:
+            self.admissions += 1
+            self.queue_wait_s += queue_wait_s
 
     def note_request(self, prompt_tokens: int) -> None:
         with self._lock:
@@ -474,10 +540,6 @@ class DecodeMetrics:
         with self._lock:
             self._push(self._ttft_ms, ms)
 
-    def note_token_ms(self, ms: float) -> None:
-        with self._lock:
-            self._push(self._tok_ms, ms)
-
     def mark_compiles(self) -> None:
         with self._lock:
             self._compile_mark = compile_metrics.snapshot()["compile_count"]
@@ -485,7 +547,6 @@ class DecodeMetrics:
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
             ttft = sorted(self._ttft_ms)
-            tok = sorted(self._tok_ms)
             occ = (self.slot_steps / self.slot_capacity_steps
                    if self.slot_capacity_steps else 0.0)
             out = {
@@ -527,8 +588,9 @@ class DecodeMetrics:
                 "pages_leaked": self.pages_leaked,
                 "ttft_p50_ms": ServingMetrics._pct(ttft, 0.50),
                 "ttft_p99_ms": ServingMetrics._pct(ttft, 0.99),
-                "tok_p50_ms": ServingMetrics._pct(tok, 0.50),
-                "tok_p99_ms": ServingMetrics._pct(tok, 0.99),
+                "rounds": self.rounds,
+                "admissions": self.admissions,
+                **{key: getattr(self, key) for key in self.SECONDS},
                 "compile_mark": self._compile_mark,
             }
         if out["compile_mark"] is not None:
@@ -1084,67 +1146,3 @@ class ThroughputMeter:
         dt = self._events[-1][0] - self._events[0][0]
         n = sum(s for _, s in self._events[1:])
         return n / dt if dt > 0 else None
-
-
-@contextlib.contextmanager
-def profile_trace(logdir: str):
-    """Capture an XLA profiler trace (TensorBoard-viewable) for the
-    enclosed training window."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region in profiler timelines (TraceAnnotation)."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-class Profiler:
-    """Profiling hooks (SURVEY.md §5.1: the reference has none — only
-    score-logging listeners; jax.profiler + XLA dumps are the TPU-native
-    upgrade slot).
-
-    - ``trace(logdir)``: context manager capturing a jax.profiler trace
-      viewable in TensorBoard/Perfetto.
-    - ``annotate(name)``: TraceAnnotation for custom spans inside a step.
-    - ``step_timer()``: lightweight wall-clock step timing when a full
-      trace is too heavy (host-side; device sync is the caller's job).
-    """
-
-    @staticmethod
-    def trace(logdir: str):
-        import jax
-        return jax.profiler.trace(logdir)
-
-    @staticmethod
-    def annotate(name: str):
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-
-    @staticmethod
-    def step_timer():
-        import time
-
-        class _Timer:
-            def __init__(self):
-                self.times = []
-                self._t0 = None
-
-            def __enter__(self):
-                self._t0 = time.perf_counter()
-                return self
-
-            def __exit__(self, *exc):
-                self.times.append(time.perf_counter() - self._t0)
-                return False
-
-            @property
-            def mean_s(self):
-                return sum(self.times) / len(self.times) if self.times else 0.0
-
-        return _Timer()

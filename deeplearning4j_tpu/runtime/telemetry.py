@@ -13,12 +13,25 @@ elastic re-meshing) all need a trustworthy event record to be verifiable.
 Three pieces, all HOST-side (nothing here ever runs inside a jitted
 region, touches a tracer value, or forces a device sync):
 
-- :class:`Tracer` — a run-scoped, thread-safe span tracer.  Spans nest
+- :func:`span` — the ONE way program code opens a span.  One pair of
+  ``time.perf_counter()`` reads per span serves three readers: the span
+  always enters a ``jax.profiler.TraceAnnotation(name)`` (a no-op in
+  C++ while no profiler session runs), so any session shows it on
+  ``/host:CPU`` beside PJRT's events and on one clock with the device
+  lines; with a :class:`Tracer` enabled it lands in the ring buffer;
+  and where the call site names a ``counter`` its duration is added to
+  that cumulative-seconds counter on exit, so a span and the counter
+  read from it cannot disagree and no interval is timed twice.
+- :class:`Tracer` — a run-scoped, thread-safe span recorder.  Spans nest
   via a thread-local stack (context manager or :func:`traced` decorator),
   carry per-span attributes, and land in a bounded ring buffer (oldest
   records drop first; ``dropped`` counts the loss so a truncated journal
-  is self-announcing).  Clocks are monotonic; one ``time.time()`` anchor
-  at tracer creation gives absolute wall alignment.
+  is self-announcing).  ``ts`` is monotonic seconds since tracer
+  creation; ``t_unix_ns`` is the same instant on the clock the profiler
+  stamps host events with (``time.time_ns()``: an ``.xplane.pb`` event
+  starts at the ``Task Environment`` plane's ``profile_start_time`` plus
+  its ``start_ns``), taken through one anchor at tracer creation, so a
+  journal and a trace can be laid over each other.
 - Two exporters over the same record stream: an append-only JSONL
   **event journal** (one object per line, machine-greppable, the
   ``cli.py telemetry`` input) and a ``chrome://tracing``/Perfetto
@@ -31,11 +44,11 @@ region, touches a tracer value, or forces a device sync):
   telemetry-on run must show delta == 0 against a telemetry-off run.
 
 Overhead contract (the reason instrumentation can stay in hot host
-loops): the tracer is DISABLED by default, and the disabled fast path is
-a module-global ``None`` check returning a shared no-op span — no
-allocation, no lock, no clock read.  Call sites that would build an
-attribute dict guard on :func:`get_tracer` first.  Enabling the tracer
-changes no jitted program (asserted by the CI overhead gate via
+loops): the tracer is DISABLED by default.  A span then costs one small
+object, the annotation's enter and exit and two clock reads — about a
+microsecond, at sites that run a few times per device dispatch — and
+nothing is recorded, locked or kept.  Enabling the tracer changes no
+jitted program (asserted by the CI overhead gate via
 ``compile_delta_since_mark``).
 """
 
@@ -47,7 +60,9 @@ import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from deeplearning4j_tpu.runtime.metrics import (checkpoint_metrics,
                                                 compile_metrics,
@@ -77,20 +92,25 @@ def _new_run_id() -> str:
 
 
 class Span:
-    """One live span: opened by ``Tracer.span(...)`` as a context
-    manager; ``set(**attrs)`` adds attributes mid-flight (e.g. byte
-    counts known only after the work ran)."""
+    """One live span, opened by :func:`span` (or ``Tracer.span``) as a
+    context manager.  ``set(**attrs)`` adds attributes mid-flight (e.g.
+    byte counts known only after the work ran); ``discard()`` says the
+    interval turned out not to be what the name says (a loop pass that
+    found nothing to do): no record is kept and no counter moves."""
 
-    __slots__ = ("_tracer", "name", "sid", "parent", "tid", "t0", "dur_s",
-                 "attrs")
+    __slots__ = ("_tracer", "_ann", "_kept", "name", "counter", "sid",
+                 "parent", "tid", "t0", "dur_s", "attrs")
 
-    def __init__(self, tracer: "Tracer", name: str, parent: Optional[int],
+    def __init__(self, tracer: "Optional[Tracer]", name: str,
+                 counter: Optional[Tuple[Any, str]],
                  attrs: Dict[str, Any]):
         self._tracer = tracer
+        self._ann = None
+        self._kept = True
         self.name = name
-        self.sid = next(tracer._sids)
-        self.parent = parent
-        self.tid = threading.get_ident()
+        self.counter = counter
+        self.sid = self.parent = None
+        self.tid = 0
         self.t0 = 0.0
         self.dur_s = 0.0
         self.attrs = attrs
@@ -99,22 +119,36 @@ class Span:
         self.attrs.update(attrs)
         return self
 
+    def discard(self) -> None:
+        self._kept = False
+
     def __enter__(self) -> "Span":
-        self._tracer._push(self)
-        self.t0 = time.monotonic()
+        if self._tracer is not None:
+            self._tracer._push(self)
+        # the annotation stamps its start when it is made, not when it
+        # is entered: make it here, beside the clock read
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self.dur_s = time.monotonic() - self.t0
-        if exc_type is not None:
-            self.attrs.setdefault("error", exc_type.__name__)
-        self._tracer._pop(self)
+        self.dur_s = time.perf_counter() - self.t0
+        self._ann.__exit__(exc_type, exc, tb)
+        if self.counter is not None and self._kept:
+            source, key = self.counter
+            source.add_seconds(key, self.dur_s)
+        if self._tracer is not None:
+            if exc_type is not None:
+                self.attrs.setdefault("error", exc_type.__name__)
+            self._tracer._pop(self)
         return False
 
 
 class _NoopSpan:
-    """The disabled-tracer fast path: one shared, allocation-free span
-    that absorbs the context-manager protocol and ``set``."""
+    """What a ``tr.span(...) if tr is not None else NOOP_SPAN`` site
+    takes while the tracer is off: nothing at all, the profiler
+    annotation included.  :func:`span` has no use for it."""
 
     __slots__ = ()
 
@@ -135,8 +169,12 @@ NOOP_SPAN = _NoopSpan()
 class Tracer:
     """Run-scoped span/event recorder.  Thread-safe: spans nest per
     thread (thread-local stack), records append under a lock into a
-    bounded ring buffer.  All timestamps are monotonic seconds relative
-    to tracer creation; ``wall0`` anchors them to absolute time."""
+    bounded ring buffer.  ``ts`` is ``time.perf_counter()`` seconds
+    since tracer creation; ``t_unix_ns`` the same instant through the
+    ``wall0_ns`` anchor, the clock a profiler trace is stamped with (the
+    two clocks part by what the system slews its wall clock, parts in a
+    million: take a fresh tracer for a journal that is laid over a
+    trace)."""
 
     def __init__(self, run_id: Optional[str] = None,
                  capacity: int = DEFAULT_CAPACITY):
@@ -147,26 +185,43 @@ class Tracer:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._sids = itertools.count(1)
-        self._t0 = time.monotonic()
-        self.wall0 = time.time()
+        self._t0 = time.perf_counter()
+        self.wall0_ns = time.time_ns()
+        self.wall0 = self.wall0_ns / 1e9
         self.dropped = 0
 
     # -- span / event API --------------------------------------------------
-    def span(self, name: str, **attrs: Any) -> Span:
-        """Open a span (use as ``with tracer.span("fit") as sp:``).
-        Nesting is automatic: the parent is whatever span this THREAD
-        currently has open."""
-        stack = getattr(self._local, "stack", None)
-        parent = stack[-1].sid if stack else None
-        return Span(self, name, parent, attrs)
+    def span(self, name: str, *, counter: Optional[Tuple[Any, str]] = None,
+             **attrs: Any) -> Span:
+        """Open a span bound to THIS tracer (use as ``with
+        tracer.span("fit") as sp:``); :func:`span` is the form program
+        code uses.  Nesting is automatic: the parent is whatever span
+        this THREAD has open when the span is entered."""
+        return Span(self, name, counter, attrs)
 
     def event(self, name: str, **attrs: Any) -> None:
         """Record a point event (no duration): worker joins, rejections,
         checkpoint markers, ..."""
+        self._record("event", name, time.perf_counter(), attrs)
+
+    def completed(self, name: str, t_start: float, t_end: float,
+                  **attrs: Any) -> None:
+        """Record a span that is already over, from its two
+        ``time.perf_counter()`` stamps: a wait whose start was another
+        thread's (a request's time in the queue, known when it ends)."""
+        self._record("span", name, t_start, attrs, sid=next(self._sids),
+                     dur_ms=(t_end - t_start) * 1e3)
+
+    def _at(self, t: float) -> Dict[str, Any]:
+        """A ``perf_counter`` reading on the journal's two clocks."""
+        ts = t - self._t0
+        return {"ts": ts, "t_unix_ns": self.wall0_ns + int(ts * 1e9)}
+
+    def _record(self, kind: str, name: str, t: float,
+                attrs: Dict[str, Any], **more: Any) -> None:
         stack = getattr(self._local, "stack", None)
         self._append({
-            "type": "event", "name": name,
-            "ts": time.monotonic() - self._t0,
+            "type": kind, "name": name, **more, **self._at(t),
             "tid": threading.get_ident(),
             "parent": stack[-1].sid if stack else None,
             "attrs": attrs,
@@ -191,6 +246,9 @@ class Tracer:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
+        span.sid = next(self._sids)
+        span.parent = stack[-1].sid if stack else None
+        span.tid = threading.get_ident()
         stack.append(span)
 
     def _pop(self, span: Span) -> None:
@@ -199,10 +257,11 @@ class Tracer:
             stack.pop()
         elif stack and span in stack:       # mis-nested exit: heal
             stack.remove(span)
+        if not span._kept:
+            return
         self._append({
             "type": "span", "name": span.name, "sid": span.sid,
-            "parent": span.parent, "tid": span.tid,
-            "ts": span.t0 - self._t0,
+            "parent": span.parent, "tid": span.tid, **self._at(span.t0),
             "dur_ms": span.dur_s * 1e3,
             "attrs": span.attrs,
         })
@@ -336,9 +395,7 @@ _TRACER: Optional[Tracer] = None
 
 
 def get_tracer() -> Optional[Tracer]:
-    """The active tracer, or None when telemetry is off.  Call sites
-    that build attribute dicts should guard on this so a disabled run
-    allocates nothing."""
+    """The active tracer, or None when telemetry is off."""
     return _TRACER
 
 
@@ -363,14 +420,14 @@ def disable() -> Optional[Tracer]:
     return t
 
 
-def span(name: str, **attrs: Any):
-    """Module-level span: ``with telemetry.span("fit"):`` — the shared
-    no-op span when disabled (no allocation beyond the kwargs dict;
-    kwarg-heavy per-request sites should guard on :func:`get_tracer`)."""
-    t = _TRACER
-    if t is None:
-        return NOOP_SPAN
-    return t.span(name, **attrs)
+def span(name: str, *, counter: Optional[Tuple[Any, str]] = None,
+         **attrs: Any) -> Span:
+    """The span primitive: ``with telemetry.span("decode.fetch",
+    counter=(decode_metrics, "fetch_s")):``.  Always a profiler
+    annotation; a journal record while a tracer is enabled; and where
+    ``counter`` names ``(source, key)`` the span's seconds go to
+    ``source.add_seconds(key, seconds)`` on exit."""
+    return Span(_TRACER, name, counter, attrs)
 
 
 def event(name: str, **attrs: Any) -> None:
@@ -379,18 +436,22 @@ def event(name: str, **attrs: Any) -> None:
         t.event(name, **attrs)
 
 
+def completed(name: str, t_start: float, t_end: float,
+              **attrs: Any) -> None:
+    """A span already over (``Tracer.completed``); nothing while off."""
+    t = _TRACER
+    if t is not None:
+        t.completed(name, t_start, t_end, **attrs)
+
+
 def traced(name: Optional[str] = None) -> Callable:
-    """Decorator: span the call when telemetry is enabled, plain call
-    when not — resolved PER CALL, so functions decorated at import time
-    honor a tracer enabled later."""
+    """Decorator: :func:`span` around the call — resolved PER CALL, so
+    functions decorated at import time honor a tracer enabled later."""
     def deco(fn: Callable) -> Callable:
         label = name or getattr(fn, "__name__", "span")
 
         def wrapper(*args, **kwargs):
-            t = _TRACER
-            if t is None:
-                return fn(*args, **kwargs)
-            with t.span(label):
+            with span(label):
                 return fn(*args, **kwargs)
         wrapper.__name__ = getattr(fn, "__name__", label)
         wrapper.__doc__ = fn.__doc__

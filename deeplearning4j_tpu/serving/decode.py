@@ -75,6 +75,7 @@ arXiv:2309.08918):
 from __future__ import annotations
 
 import hashlib
+import itertools
 import queue
 import threading
 import time
@@ -1343,12 +1344,9 @@ class DecodeEngine:
         slot = b.free_slot()
         if slot is None:
             raise RuntimeError(f"no free slot in bucket {bucket}")
-        if self.paged:
-            first_tok = self._start_paged(prompt, b, bucket, slot,
-                                          temperature, seed)
-        else:
-            first_tok = self._start_pinned(prompt, b, bucket, slot,
-                                           temperature, seed)
+        start = self._start_paged if self.paged else self._start_pinned
+        first_tok = start(prompt, b, bucket, slot, temperature, seed,
+                          getattr(owner, "rid", None))
         b.tokens_h[slot] = first_tok
         b.pos_h[slot] = prompt.size
         b.active[slot] = True
@@ -1358,7 +1356,8 @@ class DecodeEngine:
         return bucket, slot, first_tok
 
     def _start_pinned(self, prompt: np.ndarray, b: _Bucket, bucket: int,
-                      slot: int, temperature: float, seed: int) -> int:
+                      slot: int, temperature: float, seed: int,
+                      rid: Optional[int]) -> int:
         params = self.current_params()
         slots = self._state(b)
         C = self.prefill_chunk
@@ -1368,12 +1367,11 @@ class DecodeEngine:
             hit = self._prefix.lookup(prompt, C, self._prefix_space)
             if hit is not None:
                 hit_len, pages = hit
-        tr = telemetry.get_tracer()
-        sp = tr.span("decode.prefill", bucket=bucket, slot=slot,
-                     prompt_tokens=int(prompt.size), chunks=n_chunks,
-                     prefix_hit_tokens=hit_len) \
-            if tr is not None else telemetry.NOOP_SPAN
-        with sp:
+        with telemetry.span("decode.prefill",
+                            counter=(decode_metrics, "prefill_s"),
+                            rid=rid, bucket=bucket, slot=slot,
+                            prompt_tokens=int(prompt.size),
+                            chunks=n_chunks, prefix_hit_tokens=hit_len):
             first = None
             try:
                 if hit_len:
@@ -1419,14 +1417,15 @@ class DecodeEngine:
                     self._dslots.pop(b.t_max, None)
                     raise
                 self._dslots[b.t_max] = dsl
-            first_tok = int(first)              # join-time sync, once
+            with telemetry.span("decode.prefill.sync",
+                                counter=(decode_metrics, "prefill_sync_s")):
+                first_tok = int(first)          # join-time sync, once
         decode_metrics.note_prefill(n_chunks - hit_len // C)
         if self._prefix is not None:
             if hit_len:
                 decode_metrics.note_prefix_hit(hit_len)
-                if tr is not None:
-                    tr.event("decode.prefix_hit", bucket=bucket,
-                             slot=slot, tokens_saved=hit_len)
+                telemetry.event("decode.prefix_hit", bucket=bucket,
+                                slot=slot, tokens_saved=hit_len)
             else:
                 decode_metrics.note_prefix_miss()
             m_store = C * ((prompt.size - 1) // C)
@@ -1451,7 +1450,8 @@ class DecodeEngine:
         return first_tok
 
     def _start_paged(self, prompt: np.ndarray, b: _Bucket, bucket: int,
-                     slot: int, temperature: float, seed: int) -> int:
+                     slot: int, temperature: float, seed: int,
+                     rid: Optional[int]) -> int:
         self.check_capacity(prompt.size)
         params = self.current_params()
         pool = self._pool_state()
@@ -1470,12 +1470,11 @@ class DecodeEngine:
             if hit is not None:
                 hit_len, host_pages = hit
         h = hit_len // C
-        tr = telemetry.get_tracer()
-        sp = tr.span("decode.prefill", bucket=bucket, slot=slot,
-                     prompt_tokens=int(prompt.size), chunks=n_chunks,
-                     prefix_hit_tokens=hit_len) \
-            if tr is not None else telemetry.NOOP_SPAN
-        with sp:
+        with telemetry.span("decode.prefill",
+                            counter=(decode_metrics, "prefill_s"),
+                            rid=rid, bucket=bucket, slot=slot,
+                            prompt_tokens=int(prompt.size),
+                            chunks=n_chunks, prefix_hit_tokens=hit_len):
             if resident_hit:
                 self._alloc.share(hit_ids)
                 b.ptab[slot, :h] = hit_ids
@@ -1535,14 +1534,15 @@ class DecodeEngine:
                 self._release_pages(b, slot)
                 self._drop_pool()
                 raise
-            first_tok = int(first)              # join-time sync, once
+            with telemetry.span("decode.prefill.sync",
+                                counter=(decode_metrics, "prefill_sync_s")):
+                first_tok = int(first)          # join-time sync, once
         decode_metrics.note_prefill(n_chunks - h)
         if hit_len:
             decode_metrics.note_prefix_hit(hit_len)
-            if tr is not None:
-                tr.event("decode.prefix_hit", bucket=bucket, slot=slot,
-                         tokens_saved=hit_len,
-                         resident=bool(resident_hit))
+            telemetry.event("decode.prefix_hit", bucket=bucket, slot=slot,
+                            tokens_saved=hit_len,
+                            resident=bool(resident_hit))
         else:
             decode_metrics.note_prefix_miss()
         m_store = C * ((prompt.size - 1) // C)
@@ -1570,54 +1570,62 @@ class DecodeEngine:
         inactive slots are stale and must be ignored via the caller's
         ownership map)."""
         b = self._buckets[bucket]
-        params = self.current_params()
         n_act = b.n_active()
-        tr = telemetry.get_tracer()
-        sp = tr.span("decode.dispatch", bucket=bucket, active=n_act) \
-            if tr is not None else telemetry.NOOP_SPAN
-        if self.paged:
+        with telemetry.span("decode.advance",
+                            counter=(decode_metrics, "advance_s"),
+                            bucket=bucket, active=n_act):
+            params = self.current_params()
+            if self.paged:
+                return self._advance_paged(b, params)
+            with telemetry.span("decode.stage"):
+                slots = self._state(b)
+                b.ran = b.active.copy()
+                active = b.active.copy()
+            with telemetry.span("decode.dispatch"):
+                try:
+                    slots, out = self._decode(params, slots, active,
+                                              b.temps, b.seeds)
+                except Exception:
+                    b.slots = None              # donated into the failure
+                    raise
+                b.slots = slots
+            toks = self._fetch(out)
+            b.tokens_h[b.ran] = toks[b.ran]
+            b.pos_h[b.ran] += 1
+            decode_metrics.note_decode_dispatch(n_act, self.n_slots)
+            return toks
+
+    def _advance_paged(self, b: _Bucket, params: Any) -> np.ndarray:
+        with telemetry.span("decode.stage"):
             run = self._ensure_pages(b, 0)
             b.ran = run
             pool = self._pool_state()
-            with sp:
-                try:
-                    pool, out = self._decode(
-                        params, pool, b.ptab.copy(), b.tokens_h.copy(),
-                        b.pos_h.copy(), run, b.temps, b.seeds)
-                except Exception:
-                    self._drop_pool()       # donated into the failure
-                    raise
-                self._pool = pool
-                # the per-step stream sync: each active request's next
-                # token must land on host to stream — this ONE [S]-int
-                # fetch per dispatch is the product, not a stall
-                toks = np.asarray(out)  # jaxlint: disable=host-sync-on-serving-worker — the per-step token fetch IS the stream
-            b.tokens_h[run] = toks[run]
-            b.pos_h[run] += 1
-            decode_metrics.note_decode_dispatch(int(run.sum()),
-                                                self.n_slots)
-            decode_metrics.note_pages(self._alloc.in_use(),
-                                      self._live_rows(),
-                                      self.page_tokens)
-            return toks
-        slots = self._state(b)
-        b.ran = b.active.copy()
-        with sp:
+            ptab, tokens, pos = (b.ptab.copy(), b.tokens_h.copy(),
+                                 b.pos_h.copy())
+        with telemetry.span("decode.dispatch"):
             try:
-                slots, out = self._decode(params, slots, b.active.copy(),
-                                          b.temps, b.seeds)
+                pool, out = self._decode(params, pool, ptab, tokens, pos,
+                                         run, b.temps, b.seeds)
             except Exception:
-                b.slots = None                  # donated into the failure
+                self._drop_pool()               # donated into the failure
                 raise
-            b.slots = slots
-            # the per-step stream sync: each active request's next token
-            # must land on host to stream — this ONE [S]-int fetch per
-            # dispatch is the product, not a stall
-            toks = np.asarray(out)  # jaxlint: disable=host-sync-on-serving-worker — the per-step token fetch IS the stream
-        b.tokens_h[b.ran] = toks[b.ran]
-        b.pos_h[b.ran] += 1
-        decode_metrics.note_decode_dispatch(n_act, self.n_slots)
+            self._pool = pool
+        toks = self._fetch(out)
+        b.tokens_h[run] = toks[run]
+        b.pos_h[run] += 1
+        decode_metrics.note_decode_dispatch(int(run.sum()), self.n_slots)
+        decode_metrics.note_pages(self._alloc.in_use(), self._live_rows(),
+                                  self.page_tokens)
         return toks
+
+    @staticmethod
+    def _fetch(out: Any) -> np.ndarray:
+        """The per-step stream sync: each active request's next token
+        must land on host to stream — this ONE [S]-int fetch per
+        dispatch is the product, not a stall."""
+        with telemetry.span("decode.fetch",
+                            counter=(decode_metrics, "fetch_s")):
+            return np.asarray(out)  # jaxlint: disable=host-sync-on-serving-worker — the per-step token fetch IS the stream
 
     def advance_spec(self, bucket: int) -> Tuple[np.ndarray, np.ndarray]:
         """One SPECULATIVE round for ``bucket``: the draft proposes
@@ -1633,70 +1641,71 @@ class DecodeEngine:
         if self._draft_fn is None:
             raise RuntimeError("engine built without draft=")
         b = self._buckets[bucket]
-        params = self.current_params()
         k = self.draft_k
-        tr = telemetry.get_tracer()
-        sp = tr.span("decode.spec_round", bucket=bucket,
-                     active=b.n_active(), k=k) \
-            if tr is not None else telemetry.NOOP_SPAN
-        if self.paged:
-            run = self._ensure_pages(b, k)
-            b.ran = run
-            pool = self._pool_state()
-            with sp:
-                try:
-                    self._dpool, props = self._draft_fn(
-                        self._draft_params, self._dpool, b.ptab.copy(),
-                        b.tokens_h.copy(), b.pos_h.copy(), run)
-                    pool, out, n_commit = self._verify(
-                        params, pool, b.ptab.copy(), b.tokens_h.copy(),
-                        b.pos_h.copy(), run, b.temps, b.seeds, props)
-                except Exception:
-                    self._drop_pool()
-                    raise
-                self._pool = pool
-                # the ONE host round-trip of the round: the committed
-                # tokens and their counts (the proposals never land)
-                toks = np.asarray(out)  # jaxlint: disable=host-sync-on-serving-worker — the per-round committed-token fetch IS the stream
-                n_c = np.asarray(n_commit)  # jaxlint: disable=host-sync-on-serving-worker — rides the same round-trip as the committed tokens
-        else:
-            run = b.active.copy()
-            b.ran = run
-            slots = self._state(b)
-            dsl = self._dslots_state(b)
-            # the draft's device tokens/pos are overwritten with the
-            # verified frontier: rows below it hold exactly the
-            # committed tokens' KV (accepted proposals consumed them),
-            # so no re-sync dispatch is ever needed
-            dsl = dsl._replace(tokens=b.tokens_h.copy(),
-                               pos=b.pos_h.copy())
-            with sp:
-                try:
-                    dsl, props = self._draft_fn(self._draft_params, dsl,
-                                                run)
-                    self._dslots[b.t_max] = dsl
-                    slots, out, n_commit = self._verify(
-                        params, slots, run, b.temps, b.seeds, props)
-                except Exception:
-                    b.slots = None
-                    self._dslots.pop(b.t_max, None)
-                    raise
-                b.slots = slots
-                toks = np.asarray(out)  # jaxlint: disable=host-sync-on-serving-worker — the per-round committed-token fetch IS the stream
-                n_c = np.asarray(n_commit)  # jaxlint: disable=host-sync-on-serving-worker — rides the same round-trip as the committed tokens
-        n_c = n_c.astype(np.int64)
-        idx = np.flatnonzero(n_c)
-        b.tokens_h[idx] = toks[idx, n_c[idx] - 1]
-        b.pos_h += n_c.astype(np.int32)
-        n_run = int(run.sum())
-        decode_metrics.note_decode_dispatch(n_run, self.n_slots)
-        decode_metrics.note_spec(k * n_run,
-                                 int(np.maximum(n_c - 1, 0).sum()))
-        if self.paged:
-            decode_metrics.note_pages(self._alloc.in_use(),
-                                      self._live_rows(),
-                                      self.page_tokens)
-        return toks, n_c
+        with telemetry.span("decode.advance",
+                            counter=(decode_metrics, "advance_s"),
+                            bucket=bucket, active=b.n_active(), k=k):
+            params = self.current_params()
+            if self.paged:
+                with telemetry.span("decode.stage"):
+                    run = self._ensure_pages(b, k)
+                    b.ran = run
+                    pool = self._pool_state()
+                    ptab, tokens, pos = (b.ptab.copy(), b.tokens_h.copy(),
+                                         b.pos_h.copy())
+                with telemetry.span("decode.dispatch"):
+                    try:
+                        self._dpool, props = self._draft_fn(
+                            self._draft_params, self._dpool, ptab, tokens,
+                            pos, run)
+                        pool, out, n_commit = self._verify(
+                            params, pool, ptab, tokens, pos, run,
+                            b.temps, b.seeds, props)
+                    except Exception:
+                        self._drop_pool()
+                        raise
+                    self._pool = pool
+            else:
+                with telemetry.span("decode.stage"):
+                    run = b.active.copy()
+                    b.ran = run
+                    slots = self._state(b)
+                    dsl = self._dslots_state(b)
+                    # the draft's device tokens/pos are overwritten with
+                    # the verified frontier: rows below it hold exactly
+                    # the committed tokens' KV (accepted proposals
+                    # consumed them), so no re-sync dispatch is ever
+                    # needed
+                    dsl = dsl._replace(tokens=b.tokens_h.copy(),
+                                       pos=b.pos_h.copy())
+                with telemetry.span("decode.dispatch"):
+                    try:
+                        dsl, props = self._draft_fn(self._draft_params,
+                                                    dsl, run)
+                        self._dslots[b.t_max] = dsl
+                        slots, out, n_commit = self._verify(
+                            params, slots, run, b.temps, b.seeds, props)
+                    except Exception:
+                        b.slots = None
+                        self._dslots.pop(b.t_max, None)
+                        raise
+                    b.slots = slots
+            # the committed tokens, and their counts on the same
+            # round-trip (the proposals never land)
+            toks = self._fetch(out)
+            n_c = self._fetch(n_commit).astype(np.int64)
+            idx = np.flatnonzero(n_c)
+            b.tokens_h[idx] = toks[idx, n_c[idx] - 1]
+            b.pos_h += n_c.astype(np.int32)
+            n_run = int(run.sum())
+            decode_metrics.note_decode_dispatch(n_run, self.n_slots)
+            decode_metrics.note_spec(k * n_run,
+                                     int(np.maximum(n_c - 1, 0).sum()))
+            if self.paged:
+                decode_metrics.note_pages(self._alloc.in_use(),
+                                          self._live_rows(),
+                                          self.page_tokens)
+            return toks, n_c
 
     def release(self, bucket: int, slot: int) -> None:
         """Free a finished slot — the cache rows need no scrubbing: a
@@ -1726,13 +1735,18 @@ class DecodeRequest:
     the request on another replica and continue BIT-identically —
     sampling keys fold (seed, position), not step count, so the token
     at each absolute position is the same no matter which replica (or
-    how many prefill/decode boundaries) produced it."""
+    how many prefill/decode boundaries) produced it.
+
+    ``rid`` numbers the requests of a process in the order they were
+    made; every journal record of one request carries it."""
 
     _DONE = object()
+    _rids = itertools.count(1)
 
     def __init__(self, prompt: np.ndarray, max_tokens: int,
                  temperature: float, seed: int, eos_id: Optional[int],
                  deadline_ms: Optional[float] = None):
+        self.rid = next(DecodeRequest._rids)
         self.prompt = prompt
         self.max_tokens = max_tokens
         self.temperature = temperature
@@ -1881,6 +1895,7 @@ class _ReplayRequest(DecodeRequest):
         super().__init__(orig.prompt, orig.max_tokens, orig.temperature,
                          orig.seed, orig.eos_id)
         self._orig = orig
+        self.rid = orig.rid             # one request, one id
         # inherit the ABSOLUTE deadline: migration must not extend a
         # request's budget (clients sized it end-to-end)
         self.deadline_ms = orig.deadline_ms
@@ -2063,6 +2078,12 @@ class ContinuousBatcher:
                 if req is None:
                     decode_metrics.note_queue_depth(len(self._pending))
                     return admitted
+            # the wait ends here, so it is counted here; a replayed
+            # request's runs from its first submit, as its caller's does
+            now = time.perf_counter()
+            decode_metrics.note_admission(now - req._t_submit)
+            telemetry.completed("decode.queue_wait", req._t_submit, now,
+                                rid=req.rid)
             joined = self.engine.n_active() > 0
             emitted = req._snapshot_tokens()
             eff_prompt = (np.concatenate([req.prompt, emitted])
@@ -2086,11 +2107,9 @@ class ContinuousBatcher:
                 continue
             if joined:
                 decode_metrics.note_join()
-            tr = telemetry.get_tracer()
-            if tr is not None:
-                tr.event("decode.join", bucket=bucket, slot=slot,
-                         prompt_tokens=int(eff_prompt.size),
-                         mid_flight=joined, replayed=bool(emitted.size))
+            telemetry.event("decode.join", rid=req.rid, bucket=bucket,
+                            slot=slot, prompt_tokens=int(eff_prompt.size),
+                            mid_flight=joined, replayed=bool(emitted.size))
             admitted += 1
             with self._cv:
                 self._last_progress = time.perf_counter()
@@ -2110,18 +2129,17 @@ class ContinuousBatcher:
                 self._placed.pop((bucket, slot), None)
             decode_metrics.note_complete(n_out)
             req._finish()
-            tr = telemetry.get_tracer()
-            if tr is not None:
-                tr.event("decode.complete", bucket=bucket, slot=slot,
-                         tokens=n_out,
-                         ttft_ms=round(req.ttft_ms or 0.0, 3))
+            telemetry.event("decode.complete", rid=req.rid, bucket=bucket,
+                            slot=slot, tokens=n_out,
+                            ttft_ms=round(req.ttft_ms or 0.0, 3))
             return True
         return False
 
-    def _advance_all(self) -> None:
+    def _advance_all(self) -> int:
+        """One dispatch per active bucket; returns how many ran."""
         spec = self.engine.draft is not None and self.engine.spec_enabled
+        advanced = 0
         for bucket in self.engine.active_buckets():
-            t0 = time.perf_counter()
             try:
                 if spec:
                     out, n_c = self.engine.advance_spec(bucket)
@@ -2169,29 +2187,30 @@ class ContinuousBatcher:
                     with self._cv:
                         self._pending[:0] = replay
                 continue
-            decode_metrics.note_token_ms(
-                (time.perf_counter() - t0) * 1e3)
+            advanced += 1
             self.dispatch_error_streak = 0
-            ran = self.engine.last_ran(bucket)
-            with self._cv:
-                self._last_progress = time.perf_counter()
-                owned = [(k, r) for k, r in self._placed.items()
-                         if k[0] == bucket]
-            for (bk, slot), r in owned:
-                if not ran[slot]:
-                    continue        # stalled on pages; retried next pass
-                if spec:
-                    for j in range(int(n_c[slot])):
-                        tok = int(out[slot, j])
+            with telemetry.span("decode.deliver"):
+                ran = self.engine.last_ran(bucket)
+                with self._cv:
+                    self._last_progress = time.perf_counter()
+                    owned = [(k, r) for k, r in self._placed.items()
+                             if k[0] == bucket]
+                for (bk, slot), r in owned:
+                    if not ran[slot]:
+                        continue    # stalled on pages; retried next pass
+                    if spec:
+                        for j in range(int(n_c[slot])):
+                            tok = int(out[slot, j])
+                            r._push(tok)
+                            if self._maybe_finish(bk, slot, r, tok,
+                                                  n_out=len(r._tokens)):
+                                break
+                    else:
+                        tok = int(toks[slot])
                         r._push(tok)
-                        if self._maybe_finish(bk, slot, r, tok,
-                                              n_out=len(r._tokens)):
-                            break
-                else:
-                    tok = int(toks[slot])
-                    r._push(tok)
-                    self._maybe_finish(bk, slot, r, tok,
-                                       n_out=len(r._tokens))
+                        self._maybe_finish(bk, slot, r, tok,
+                                           n_out=len(r._tokens))
+        return advanced
 
     def _expire(self) -> None:
         """Free every deadline-expired request (worker thread): queued
@@ -2215,24 +2234,31 @@ class ContinuousBatcher:
             r._finish(DeadlineExceeded(
                 r.deadline_ms, (now - r._t_submit) * 1e3,
                 len(r._tokens)))
-            tr = telemetry.get_tracer()
-            if tr is not None:
-                tr.event("decode.deadline_exceeded",
-                         deadline_ms=r.deadline_ms,
-                         tokens=len(r._tokens))
+            telemetry.event("decode.deadline_exceeded", rid=r.rid,
+                            deadline_ms=r.deadline_ms,
+                            tokens=len(r._tokens))
 
     def _loop(self) -> None:
         while True:
             with self._cv:
                 while self._open and not self._pending \
                         and not self._placed:
-                    self._cv.wait()
+                    with telemetry.span("decode.wait"):
+                        self._cv.wait()
                 if not self._open and not self._pending \
                         and not self._placed:
                     return
-            self._expire()
-            admitted = self._admit()
-            self._advance_all()
+            with telemetry.span("decode.round",
+                                counter=(decode_metrics, "round_s")) as sp:
+                with telemetry.span("decode.expire"):
+                    self._expire()
+                with telemetry.span("decode.admit"):
+                    admitted = self._admit()
+                advanced = self._advance_all()
+                if admitted or advanced:
+                    decode_metrics.note_round()
+                else:
+                    sp.discard()        # a pass that found nothing to do
             with self._cv:
                 if self._open and not admitted and not self._placed \
                         and self._pending:
@@ -2240,7 +2266,8 @@ class ContinuousBatcher:
                     # and nothing pending fits — a timed wait instead
                     # of a hot spin (submit/close notifies early; the
                     # timeout keeps deadline expiry ticking)
-                    self._cv.wait(0.005)
+                    with telemetry.span("decode.wait"):
+                        self._cv.wait(0.005)
 
     # -- lifecycle ---------------------------------------------------------
     def close(self, timeout: float = 120.0) -> None:

@@ -115,25 +115,6 @@ def test_throughput_meter():
     assert r is not None and r > 0
 
 
-def test_profiler_helpers(tmp_path):
-    import jax.numpy as jnp
-    from deeplearning4j_tpu.runtime.metrics import Profiler
-
-    t = Profiler.step_timer()
-    for _ in range(3):
-        with t:
-            jnp.ones(8).sum().block_until_ready()
-    assert len(t.times) == 3 and t.mean_s > 0
-
-    with Profiler.annotate("test-span"):
-        jnp.ones(4).sum().block_until_ready()
-
-    with Profiler.trace(str(tmp_path / "prof")):
-        jnp.ones(16).sum().block_until_ready()
-    import os
-    assert os.path.isdir(str(tmp_path / "prof"))
-
-
 def test_orbax_manager_roundtrip(tmp_path):
     pytest.importorskip("orbax.checkpoint")
     from deeplearning4j_tpu.runtime.checkpoint import (

@@ -146,18 +146,119 @@ def test_decorator_form():
     assert tr.records()[0]["name"] == "mul"
 
 
-def test_disabled_module_api_is_noop():
+def test_disabled_module_api_records_nothing():
+    """Off means: a span is still a span (it times itself, feeds its
+    counter and is a profiler annotation), but no tracer exists, none is
+    made, and nothing is kept anywhere."""
     assert telemetry.get_tracer() is None
     assert not telemetry.enabled()
     sp = telemetry.span("anything", k=1)
-    assert sp is telemetry.NOOP_SPAN      # the SHARED no-op span
     with sp:
         sp.set(more=2)
+    assert sp.dur_s >= 0.0 and sp.sid is None and sp.parent is None
     telemetry.event("nothing", x=1)       # no tracer: swallowed
-    tr = telemetry.enable("on")
-    assert telemetry.span("real") is not telemetry.NOOP_SPAN
-    assert telemetry.disable() is tr
+    telemetry.completed("nothing", 0.0, 1.0)
     assert telemetry.get_tracer() is None
+    tr = telemetry.enable("on")
+    assert tr.count() == 0                # nothing from before it was on
+    with telemetry.span("real"):
+        pass
+    assert [r["name"] for r in tr.records()] == ["real"]
+    assert telemetry.disable() is tr
+    with telemetry.span("after"):
+        pass
+    assert tr.count() == 1 and telemetry.get_tracer() is None
+
+
+class _Seconds:
+    def __init__(self):
+        self.got = []
+
+    def add_seconds(self, key, seconds):
+        self.got.append((key, seconds))
+
+
+def test_span_counter_gets_the_spans_own_duration():
+    """One pair of clock reads: what the counter is given IS the
+    journal's duration, tracer on or off; a discarded span feeds
+    neither."""
+    sink = _Seconds()
+    with telemetry.span("off", counter=(sink, "a_s")) as off:
+        time.sleep(0.002)
+    assert sink.got == [("a_s", off.dur_s)] and off.dur_s >= 0.002
+    tr = telemetry.enable("counted")
+    with telemetry.span("on", counter=(sink, "b_s")):
+        pass
+    with telemetry.span("idle", counter=(sink, "b_s")) as idle:
+        idle.discard()
+    (rec,) = tr.records()
+    assert rec["name"] == "on"
+    assert sink.got[1:] == [("b_s", rec["dur_ms"] / 1e3)]
+    # the real family refuses a name it does not have
+    from deeplearning4j_tpu.runtime.metrics import decode_metrics
+    with pytest.raises(KeyError):
+        with telemetry.span("typo", counter=(decode_metrics, "fetchs")):
+            pass
+
+
+def test_completed_record_starts_where_it_is_told():
+    tr = telemetry.enable("done")
+    t1 = time.perf_counter()
+    with telemetry.span("outer") as outer:
+        telemetry.completed("waited", t1 - 0.25, t1, rid=7)
+    waited, _ = tr.records()
+    assert waited["type"] == "span" and waited["parent"] == outer.sid
+    assert waited["attrs"] == {"rid": 7}
+    assert waited["dur_ms"] == pytest.approx(250.0)
+    assert waited["ts"] == pytest.approx(t1 - 0.25 - tr._t0)
+    assert waited["t_unix_ns"] == tr.wall0_ns + int(waited["ts"] * 1e9)
+
+
+# -- the profiler's clock ---------------------------------------------------
+
+def _profiled(tmp_path, body):
+    """Run ``body()`` under a ``jax.profiler`` session; returns {name:
+    start in unix ns} of the ``/host:CPU`` plane's events."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    planes = {p.name: p for p in ProfileData.from_file(path).planes}
+    t0 = dict(planes["Task Environment"].stats)["profile_start_time"]
+    return {e.name: t0 + e.start_ns
+            for line in planes["/host:CPU"].lines for e in line.events}
+
+
+def test_span_reaches_the_profiler_with_the_tracer_off(tmp_path):
+    def body():
+        with telemetry.span("probe.off", k=1):
+            time.sleep(0.001)
+
+    assert telemetry.get_tracer() is None
+    assert "probe.off" in _profiled(tmp_path, body)
+
+
+def test_journal_and_profiler_agree_on_a_spans_start(tmp_path):
+    tr = telemetry.enable("clocks")
+
+    def body():
+        with telemetry.span("probe.on"):
+            time.sleep(0.001)
+
+    in_trace = _profiled(tmp_path, body)["probe.on"]
+    (rec,) = [r for r in tr.records() if r["name"] == "probe.on"]
+    assert abs(rec["t_unix_ns"] - in_trace) < 1_000_000
 
 
 # -- exporters --------------------------------------------------------------
@@ -487,6 +588,130 @@ def test_batcher_journal_has_request_lifecycle(tmp_path):
     payload = json.loads(json.dumps(chrome_trace(read_journal(path))))
     assert any(e.get("ph") == "X" and e["name"] == "serving.cohort"
                for e in payload["traceEvents"])
+
+
+def test_decode_loop_counts_its_phases_where_they_happen():
+    """A warmed tiny paged batcher: every phase counter moves, the
+    enclosing ones are the larger, and one request's records share its
+    ``rid``."""
+    import jax
+
+    from deeplearning4j_tpu.models import gpt
+    from deeplearning4j_tpu.models.transformer import TransformerConfig
+    from deeplearning4j_tpu.runtime.metrics import decode_metrics
+    from deeplearning4j_tpu.serving.decode import (ContinuousBatcher,
+                                                   DecodeEngine)
+
+    cfg = TransformerConfig(vocab_size=64, max_len=64, hidden=32,
+                            n_layers=2, n_heads=2, ffn_dim=64, dropout=0.0,
+                            compute_dtype="float32", causal=True,
+                            type_vocab_size=1)
+    eng = DecodeEngine(cfg, gpt.init_params(jax.random.key(7), cfg),
+                       n_slots=2, buckets=(32,), prefill_chunk=8,
+                       paged=True)
+    eng.warmup()
+    decode_metrics.reset()
+    tr = telemetry.enable("decode-loop")
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, 64, size=n) for n in (5, 9, 12, 17, 7)]
+    with ContinuousBatcher(eng, default_max_tokens=6) as b:
+        reqs = [b.submit(p, seed=i) for i, p in enumerate(prompts)]
+        outs = [r.result(timeout=120) for r in reqs]
+    assert all(len(o) == 6 for o in outs)
+
+    snap = decode_metrics.snapshot()
+    for key in ("rounds", "admissions", "queue_wait_s", "prefill_s",
+                "prefill_sync_s", "advance_s", "fetch_s", "round_s"):
+        assert snap[key] > 0, key
+    assert snap["round_s"] >= snap["advance_s"] >= snap["fetch_s"]
+    assert snap["prefill_s"] >= snap["prefill_sync_s"]
+    assert snap["admissions"] == len(prompts) == snap["requests_completed"]
+    assert 1 <= snap["decode_dispatches"] / snap["rounds"] <= 1 + 1e-9
+    decode_metrics.reset()
+    assert decode_metrics.snapshot()["round_s"] == 0.0
+
+    recs = tr.records()
+    spans = [r for r in recs if r["type"] == "span"]
+    by_sid = {r["sid"]: r for r in spans}
+    counted = {n: sum(r["dur_ms"] for r in spans if r["name"] == n) / 1e3
+               for n in ("decode.round", "decode.advance", "decode.fetch",
+                         "decode.prefill", "decode.prefill.sync",
+                         "decode.queue_wait")}
+    # the counter and the journal are one measurement
+    for name, key in (("decode.round", "round_s"),
+                      ("decode.advance", "advance_s"),
+                      ("decode.fetch", "fetch_s"),
+                      ("decode.prefill", "prefill_s"),
+                      ("decode.prefill.sync", "prefill_sync_s"),
+                      ("decode.queue_wait", "queue_wait_s")):
+        assert counted[name] == pytest.approx(snap[key], rel=1e-6), name
+    # nesting on the worker thread
+    parent = {r["name"]: by_sid[r["parent"]]["name"] for r in spans
+              if r["parent"] is not None}
+    assert parent["decode.fetch"] == "decode.advance"
+    assert parent["decode.dispatch"] == "decode.advance"
+    assert parent["decode.stage"] == "decode.advance"
+    assert parent["decode.advance"] == parent["decode.admit"] \
+        == parent["decode.deliver"] == "decode.round"
+    assert parent["decode.prefill"] == parent["decode.queue_wait"] \
+        == "decode.admit"
+    assert any(r["name"] == "decode.wait" for r in spans)
+    # one request, one rid, on every record of its life
+    for req in reqs:
+        mine = [r["name"] for r in recs
+                if r["attrs"].get("rid") == req.rid]
+        assert sorted(mine) == ["decode.complete", "decode.join",
+                                "decode.prefill", "decode.queue_wait"]
+    assert len({r.rid for r in reqs}) == len(reqs)
+
+
+def test_paged_decode_names_its_scopes_in_the_lowered_program():
+    """Metadata only: what asks for an op is readable from its
+    ``op_name`` (``compiled.as_text()`` / a trace's device ops)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import gpt
+
+    cfg = gpt.gpt_tiny()
+    params = jax.eval_shape(lambda: gpt.init_params(jax.random.key(0), cfg))
+    pool = jax.eval_shape(lambda: gpt.init_pages(cfg, 9, 32))
+    S, TBL = 2, 4
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    text = jax.jit(lambda *a: gpt.paged_decode(cfg, *a)).lower(
+        params, pool, i32(S, TBL), i32(S), i32(S),
+        jax.ShapeDtypeStruct((S,), jnp.bool_),
+        jax.ShapeDtypeStruct((S,), jnp.float32),
+        jax.ShapeDtypeStruct((S,), jnp.uint32)).as_text(debug_info=True)
+    for scope in ("paged_view", "pool_write_back", "attention", "readout"):
+        assert f"/{scope}/" in text, scope
+    text = jax.jit(lambda *a: gpt.paged_prefill(cfg, *a)).lower(
+        params, pool, i32(TBL), i32(32), i32(), i32(),
+        jax.ShapeDtypeStruct((), jnp.float32),
+        jax.ShapeDtypeStruct((), jnp.uint32)).as_text(debug_info=True)
+    for scope in ("prefill_page_io", "attention", "readout"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_compile_metrics_counts_xla_compile_requests():
+    """``compile_count`` counts traces through the engine; a plain
+    ``jax.jit`` — or a recompile without a retrace — shows only in what
+    XLA was asked for."""
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: jnp.tanh(x) * 3.0 + 0.4321)
+    x = jnp.arange(7, dtype=jnp.float32)
+    before = compile_metrics.snapshot()
+    f(x).block_until_ready()
+    first = compile_metrics.snapshot()
+    f(x).block_until_ready()
+    second = compile_metrics.snapshot()
+    assert first["xla_compile_requests"] > before["xla_compile_requests"]
+    assert second["xla_compile_requests"] == first["xla_compile_requests"]
+    assert first["compile_count"] == before["compile_count"]
+    for key in ("persistent_cache_hits", "persistent_cache_misses"):
+        assert isinstance(second[key], int)
 
 
 # -- journal summarizer + CLI -----------------------------------------------

@@ -21,8 +21,10 @@ Machine-checks the tentpole's overhead contract on a real (tiny) fit:
    decode loop (serving/decode.py): after ``DecodeEngine.warmup()``, a
    concurrent request mix — joins, EOS recycling, varied prompt
    lengths — must dispatch only cached programs with the tracer off AND
-   on (the decode path's prefill/dispatch spans and join/complete
-   events are host-side only);
+   on (the decode path's spans are host-side only: each is also a
+   ``jax.profiler.TraceAnnotation``, so a profiler session shows them
+   beside the device lines), and the tracer-on journal must hold the
+   loop's ``decode.round`` span;
 6b. the same off/on zero-compile contract for the SERVING TIER 2
    decode loop: a warmed int8-weight + int8-KV engine with a prefix
    store must serve a mix of prefix MISSES (which read + store pages)
@@ -365,14 +367,19 @@ def _decode_gate(registry, telemetry) -> int:
         registry.mark()
         _decode_requests(cb, np, 6, seed=1)
         delta_on = registry.compile_delta_since_mark()
-        telemetry.disable()
+        journal = telemetry.disable().records()
         if delta_on != 0:
             print(f"[telemetry-gate] FAIL: tracer-on decode loop "
                   f"compiled {delta_on} new program(s) — decode "
                   "instrumentation leaked into a jitted region")
             return 1
+        rounds = sum(r["name"] == "decode.round" for r in journal)
+        if not rounds:
+            print("[telemetry-gate] FAIL: no decode.round span in the "
+                  "tracer-on decode loop's journal")
+            return 1
     print(f"[telemetry-gate] ok: decode loop compile_delta "
-          f"off={delta_off} on={delta_on}")
+          f"off={delta_off} on={delta_on}, {rounds} decode.round spans")
     return 0
 
 
