@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -200,9 +201,11 @@ def _kv_quant(x: Array) -> Tuple[Array, Array]:
 
 
 def _kv_load(q: Array, scale: Array, cdt) -> Array:
-    """Dequantize cache rows back to the compute dtype (fused into the
+    """Dequantize cache rows — [..., NH, D], or [..., NH*D] as a page
+    pool stores them — back to the compute dtype (fused into the
     consuming attention matmul under jit)."""
-    return (q.astype(jnp.float32) * scale[..., None, None]).astype(cdt)
+    scale = scale.reshape(scale.shape + (1,) * (q.ndim - scale.ndim))
+    return (q.astype(jnp.float32) * scale).astype(cdt)
 
 
 def init_cache(cfg: TransformerConfig, batch: int,
@@ -707,11 +710,18 @@ def make_slot_fns(cfg: TransformerConfig):
 # ---------------------------------------------------------------------------
 
 class PagedKV(NamedTuple):
-    """Pool of fixed-size KV pages [L, P, C, NH, D] (C tokens per page).
+    """Pool of fixed-size KV pages [L, P, C, NH*D] (C tokens per page;
+    a token row's heads and head width stored as ONE minor dimension,
+    so a page is C contiguous lane-dense rows whatever NH and D are —
+    [.., 20, 64] minors pad, and the TPU then lays the pool out pages
+    -minor, which no page gather or row scatter can use in place).
     A slot's cache row is no longer a pinned [T_max] slab: a host-side
     page table maps its chunk-aligned position ranges onto pool pages,
     so HBM holds only the pages live tokens occupy — 'slots per chip'
-    is bounded by live tokens, not bucket length.  Page 0 is the
+    is bounded by live tokens, not bucket length.  A dispatch never
+    holds more of it than one layer's pages of the slots it serves
+    (:func:`_read_pages`), and writes only the fresh rows, into the
+    donated pool in place (:func:`_write_rows`).  Page 0 is the
     reserved TRASH page: unused page-table entries point at it and
     inactive-slot writes are redirected into it, so a freed page can be
     handed to another slot without scrubbing.  int8 pools carry per-
@@ -724,7 +734,7 @@ class PagedKV(NamedTuple):
 
 def init_pages(cfg: TransformerConfig, n_pages: int, page_tokens: int,
                kv_dtype: Optional[str] = None) -> PagedKV:
-    shape = (cfg.n_layers, n_pages, page_tokens, cfg.n_heads, cfg.head_dim)
+    shape = (cfg.n_layers, n_pages, page_tokens, cfg.n_heads * cfg.head_dim)
     if kv_dtype is None:
         cdt = jnp.dtype(cfg.compute_dtype)
         return PagedKV(jnp.zeros(shape, cdt), jnp.zeros(shape, cdt))
@@ -738,8 +748,9 @@ def init_pages(cfg: TransformerConfig, n_pages: int, page_tokens: int,
 
 def pages_bytes(cfg: TransformerConfig, n_pages: int, page_tokens: int,
                 kv_dtype: Optional[str] = None) -> int:
-    """Persistent pool bytes — the paged engine's HBM denominator (the
-    gathered attention views are dispatch-transient)."""
+    """Persistent pool bytes — the paged engine's HBM denominator (what
+    a dispatch gathers for attention is one layer's pages of its slots
+    at a time, dispatch-transient)."""
     elems = cfg.n_layers * n_pages * page_tokens * cfg.n_heads * cfg.head_dim
     if kv_dtype == "int8":
         return 2 * elems + 2 * cfg.n_layers * n_pages * page_tokens * 4
@@ -748,58 +759,167 @@ def pages_bytes(cfg: TransformerConfig, n_pages: int, page_tokens: int,
 
 def paged_specs(cfg: TransformerConfig,
                 kv_dtype: Optional[str] = None) -> "PagedKV":  # jaxlint: disable=spec-without-divisibility-guard — degree-independent; DecodeEngine validates n_heads % model_degree before pinning these specs
-    """PartitionSpecs for a model-sharded page pool: heads over
-    ``model`` (same axis the pinned slot cache shards), scales
-    replicated."""
-    h = P(None, None, None, MODEL_AXIS, None)
+    """PartitionSpecs for a model-sharded page pool: the NH*D minor
+    dimension over ``model`` in whole heads (same heads the pinned slot
+    cache shards), scales replicated.  Page gathers and row scatters
+    index the layer, page and offset axes only, so both stay
+    shard-local."""
+    h = P(None, None, None, MODEL_AXIS)
     if kv_dtype == "int8":
         return PagedKV(k=h, v=h, k_scale=P(), v_scale=P())
     return PagedKV(k=h, v=h)
 
 
-@jax.named_scope("paged_view")
-def _paged_view(pool: PagedKV, ptab: Array, tokens: Array,
-                pos: Array) -> DecodeSlots:
-    """Gather per-slot page tables into the slot-structured view
-    [L, S, TBL*C, NH, D] the existing slot kernels consume.  Transient:
-    it exists only inside a jitted dispatch; the pool is the only
-    persistent cache state."""
-    L, Pn, C, NH, D = pool.k.shape
-    S, TBL = ptab.shape
-    k = pool.k[:, ptab].reshape(L, S, TBL * C, NH, D)
-    v = pool.v[:, ptab].reshape(L, S, TBL * C, NH, D)
-    if pool.k_scale is None:
-        return DecodeSlots(k, v, tokens, pos)
-    return DecodeSlots(k, v, tokens, pos,
-                       pool.k_scale[:, ptab].reshape(L, S, TBL * C),
-                       pool.v_scale[:, ptab].reshape(L, S, TBL * C))
+def _read_pages(a: Array, lp: Array) -> Array:
+    """``a[layer, page]`` for (layer, page) pairs ``lp`` [..., 2]: whole
+    pages gathered on the two major axes of a pool array ([L, P, C, F]
+    rows or [L, P, C] scales) -> [..., C, F] / [..., C].  One
+    ``lax.gather``: ``a[layer, ptab]`` traces a dozen index
+    -normalizing ops around the same gather, 72 times a program."""
+    n = lp.ndim - 1
+    return lax.gather(
+        a, lp, lax.GatherDimensionNumbers(
+            offset_dims=tuple(range(n, n + a.ndim - 2)),
+            collapsed_slice_dims=(0, 1), start_index_map=(0, 1)),
+        slice_sizes=(1, 1) + a.shape[2:], mode="clip")
 
 
-@jax.named_scope("pool_write_back")
-def _pool_write_back(pool: PagedKV, view: DecodeSlots, ptab: Array,
-                     posw: Array, active: Array) -> PagedKV:
-    """Persist the rows a slot kernel just wrote at positions ``posw``
-    [S, W] from the updated view back into the pool.  Writes from
-    inactive slots and out-of-range positions land in the trash page
-    (a freed page may ALREADY belong to another live slot — unlike the
-    pinned cache, a stale write is not harmless here)."""
-    L, Pn, C, NH, D = pool.k.shape
+def _every_layer(n_layers: int, pids: Array) -> Array:
+    """(layer, page) pairs [L, n, 2] naming pages ``pids`` [n] of every
+    layer — what :func:`_read_pages` takes.  A gather that indexes the
+    page axis alone (``a[:, pids]``) is compiled as slices of the WHOLE
+    pool."""
+    return jnp.stack(jnp.broadcast_arrays(
+        jnp.arange(n_layers, dtype=pids.dtype)[:, None], pids[None, :]),
+        axis=-1)
+
+
+def _write_rows(a: Array, lpo: Array, rows: Array) -> Array:
+    """``a.at[layer, page, offset].set(rows)`` for (layer, page, offset)
+    triples ``lpo`` [S, W, 3] and ``rows`` [S, W, F] (or [S, W] scales):
+    the one ``lax.scatter``, in place on a donated ``a``."""
+    return lax.scatter(
+        a, lpo, rows, lax.ScatterDimensionNumbers(
+            update_window_dims=tuple(range(2, rows.ndim)),
+            inserted_window_dims=(0, 1, 2),
+            scatter_dims_to_operand_dims=(0, 1, 2)), mode="clip")
+
+
+def _rows_attention(q: Array, k: Array, v: Array, valid: Array) -> Array:
+    """Attention of ``q`` [S, W, NH, D] over K/V rows as the pool
+    stores them, [S, T, NH*D], masked by ``valid`` [S, W, T]; returns
+    [S, W, NH, D] fp32.  The rows are never re-tiled per head (a
+    [.., NH, D] view of them pads D to the lanes and costs a pass over
+    the view a layer): q is laid out block-diagonally, [S, NH*D, NH*W]
+    with head n's lanes meeting only head n's columns, so ONE matmul
+    over all 1280 lanes gives every head's scores — the terms it adds
+    are exact zeros — and the value product's [NH*W, NH*D] result
+    keeps its diagonal blocks.  Operands, accumulation and softmax are
+    those of :func:`slot_decode`; the NH-fold redundant MXU work is
+    free beside the bytes."""
+    S, W, NH, D = q.shape
+    T = k.shape[1]
+    same = np.eye(NH, dtype=np.bool_)[None, :, None, :, None]
+    qbd = jnp.where(same, jnp.moveaxis(q, 1, 3)[:, :, :, None, :], 0)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    s = jnp.einsum("btf,bfc->bct", k, qbd.reshape(S, NH * D, NH * W),
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(valid[:, None, :, :], s.reshape(S, NH, W, T), -1e9)
+    probs = jax.nn.softmax(s, axis=-1).astype(k.dtype)
+    full = jnp.einsum("bct,btf->bcf", probs.reshape(S, NH * W, T), v,
+                      preferred_element_type=jnp.float32)
+    a = jnp.where(same, full.reshape(S, NH, W, NH, D), 0.0).sum(axis=3)
+    return jnp.moveaxis(a, 1, 2)
+
+
+def _paged_stack(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
+                 ptab: Array, toks_w: Array, posw: Array, active: Array
+                 ) -> Tuple[PagedKV, Array]:
+    """The block stack over a paged pool, ``W`` rows a slot (decode:
+    ``W = 1``; verify: ``W = k + 1``): row w of slot s feeds
+    ``toks_w[s, w]`` at position ``posw[s, w]``.  Returns (pool',
+    hidden [S, W, H]).
+
+    Layer by layer, a strict chain on the one pool: the W fresh rows of
+    a layer are written at ``(layer, page, offset)`` — nothing else of
+    the pool is — and then the slots' pages of that layer are read back
+    through the table, the fresh rows among them (:func:`_read_pages`:
+    ``pool.k[layer, ptab]`` → [S, TBL*C, NH*D], S x TBL whole pages
+    gathered on the pool's two major axes, never a copy of it), and
+    attention runs over ``<= posw`` (:func:`_rows_attention`) — the
+    arithmetic of :func:`slot_decode` / :func:`slot_verify`, row for
+    row.  Every read is of the pool as the write before it left it and
+    feeds the write after it, so a donated pool is updated in place.
+    Writes from inactive slots and out-of-range positions land in the
+    trash page (a freed page may ALREADY belong to another live slot —
+    unlike the pinned cache, a stale write is not harmless here); such
+    a slot then attends without its fresh row, and its token is never
+    taken."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    quant = pool.k_scale is not None
+    NH, D = cfg.n_heads, cfg.head_dim
     S, TBL = ptab.shape
-    W = posw.shape[1]
-    pw = jnp.clip(posw, 0, TBL * C - 1)
-    ok = (posw >= 0) & (posw < TBL * C) & active[:, None]
+    C = pool.k.shape[2]
+    T = TBL * C
+    e = params["embed"]
+    pos_c = jnp.clip(posw, 0, cfg.max_len - 1)
+    x = e["tok"][toks_w] + e["pos"][pos_c]                    # [S, W, H]
+    x = tfm.layer_norm(x, e["ln_g"], e["ln_b"], cfg.layer_norm_eps)
+
+    pw = jnp.clip(posw, 0, T - 1)
+    ok = (posw >= 0) & (posw < T) & active[:, None]
     pids = jnp.where(ok, jnp.take_along_axis(ptab, pw // C, axis=1), 0)
-    offs = pw % C
-    rows = jnp.arange(S)[:, None]
-    k_rows = view.k[:, rows, pw]                   # [L, S, W, NH, D]
-    v_rows = view.v[:, rows, pw]
-    out = pool._replace(k=pool.k.at[:, pids, offs].set(k_rows),
-                        v=pool.v.at[:, pids, offs].set(v_rows))
-    if pool.k_scale is None:
-        return out
-    return out._replace(
-        k_scale=pool.k_scale.at[:, pids, offs].set(view.k_scale[:, rows, pw]),
-        v_scale=pool.v_scale.at[:, pids, offs].set(view.v_scale[:, rows, pw]))
+    # index columns (layer, page[, offset]); the layer is added per layer
+    lp0 = jnp.stack([jnp.zeros_like(ptab), ptab], axis=-1)
+    lpo0 = jnp.stack([jnp.zeros_like(pids), pids, pw % C], axis=-1)
+    valid = jnp.arange(T)[None, None, :] <= posw[:, :, None]  # [S, W, T]
+    blocks = params["blocks"]
+    for layer in range(cfg.n_layers):
+        p = jax.tree.map(lambda a, l=layer: a[l], blocks)
+        h = x.astype(cdt)
+        q = jnp.einsum("bth,hnd->btnd", h, p["wq"].astype(cdt),
+                       preferred_element_type=jnp.float32) + p["bq"]
+        k1 = jnp.einsum("bth,hnd->btnd", h, p["wk"].astype(cdt),
+                        preferred_element_type=jnp.float32) + p["bk"]
+        v1 = jnp.einsum("bth,hnd->btnd", h, p["wv"].astype(cdt),
+                        preferred_element_type=jnp.float32) + p["bv"]
+        if quant:
+            kq, ks = _kv_quant(k1)                  # [S,W,NH,D]i8, [S,W]
+            vq, vs = _kv_quant(v1)
+            fresh = (kq.reshape(S, -1, NH * D), vq.reshape(S, -1, NH * D),
+                     ks, vs)
+        else:
+            fresh = (k1.astype(cdt).reshape(S, -1, NH * D),
+                     v1.astype(cdt).reshape(S, -1, NH * D))
+        with jax.named_scope("row_write"):
+            lpo = lpo0 + jnp.array([layer, 0, 0], jnp.int32)
+            pool = PagedKV(*(_write_rows(a, lpo, r)
+                             for a, r in zip(pool, fresh)))
+        with jax.named_scope("page_read"):
+            lp = lp0 + jnp.array([layer, 0], jnp.int32)
+            k_read = _read_pages(pool.k, lp).reshape(S, T, NH * D)
+            v_read = _read_pages(pool.v, lp).reshape(S, T, NH * D)
+            if quant:
+                k_read = _kv_load(
+                    k_read, _read_pages(pool.k_scale, lp).reshape(S, T),
+                    cdt)
+                v_read = _kv_load(
+                    v_read, _read_pages(pool.v_scale, lp).reshape(S, T),
+                    cdt)
+        with jax.named_scope("attention"):
+            a = _rows_attention(q.astype(cdt), k_read, v_read, valid)
+        a = jnp.einsum("btnd,ndh->bth", a.astype(cdt), p["wo"].astype(cdt),
+                       preferred_element_type=jnp.float32) + p["bo"]
+        x = tfm.layer_norm(x + a, p["ln1_g"], p["ln1_b"], cfg.layer_norm_eps)
+
+        h = x.astype(cdt)
+        f = jnp.einsum("bth,hf->btf", h, p["w1"].astype(cdt),
+                       preferred_element_type=jnp.float32) + p["b1"]
+        f = jax.nn.gelu(f).astype(cdt)
+        f = jnp.einsum("btf,fh->bth", f, p["w2"].astype(cdt),
+                       preferred_element_type=jnp.float32) + p["b2"]
+        x = tfm.layer_norm(x + f, p["ln2_g"], p["ln2_b"], cfg.layer_norm_eps)
+    return pool, x
 
 
 def paged_prefill(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
@@ -811,16 +931,18 @@ def paged_prefill(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
     [TBL], at chunk-aligned ``start``.  The chunk is exactly one page,
     so persisting it is a single page write at ``ptab_s[start//C]``.
     Returns (pool', first_token)."""
-    L, Pn, C, NH, D = pool.k.shape
+    L, Pn, C, F = pool.k.shape
+    NH, D = cfg.n_heads, cfg.head_dim
     TBL = ptab_s.shape[0]
     quant = pool.k_scale is not None
     with jax.named_scope("prefill_page_io"):
-        k = pool.k[:, ptab_s].reshape(L, 1, TBL * C, NH, D)
-        v = pool.v[:, ptab_s].reshape(L, 1, TBL * C, NH, D)
+        lp = _every_layer(L, ptab_s)
+        k = _read_pages(pool.k, lp).reshape(L, 1, TBL * C, NH, D)
+        v = _read_pages(pool.v, lp).reshape(L, 1, TBL * C, NH, D)
         if quant:
             cache_in = QKVCache(
-                k, v, pool.k_scale[:, ptab_s].reshape(L, 1, TBL * C),
-                pool.v_scale[:, ptab_s].reshape(L, 1, TBL * C))
+                k, v, _read_pages(pool.k_scale, lp).reshape(L, 1, TBL * C),
+                _read_pages(pool.v_scale, lp).reshape(L, 1, TBL * C))
         else:
             cache_in = KVCache(k, v)
     cache, logits = _prefill_chunk(cfg, params, cache_in, toks[None, :],
@@ -833,9 +955,9 @@ def paged_prefill(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
     with jax.named_scope("prefill_page_io"):
         pid = ptab_s[start // C]
         page_k = lax.dynamic_slice(cache.k, (0, 0, start, 0, 0),
-                                   (L, 1, C, NH, D))[:, 0]
+                                   (L, 1, C, NH, D)).reshape(L, C, F)
         page_v = lax.dynamic_slice(cache.v, (0, 0, start, 0, 0),
-                                   (L, 1, C, NH, D))[:, 0]
+                                   (L, 1, C, NH, D)).reshape(L, C, F)
         pool = pool._replace(k=pool.k.at[:, pid].set(page_k),
                              v=pool.v.at[:, pid].set(page_v))
         if quant:
@@ -853,39 +975,52 @@ def paged_decode(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
                  ptab: Array, tokens: Array, pos: Array, active: Array,
                  temperature: Array, seeds: Array
                  ) -> Tuple[PagedKV, Array]:
-    """Paged analog of :func:`slot_decode`: gather the view, run the
-    pinned step on it, persist each active slot's one new row.
+    """Paged analog of :func:`slot_decode`, token for token.  A
+    dispatch READS, layer by layer, the pages the table names (S x TBL
+    pages of one layer at a time: one rung's rows, never the pool) and
+    WRITES each active slot's one new row of every layer into the
+    donated pool in place — an inactive slot's into the trash page.
     ``tokens``/``pos`` are HOST-tracked in paged mode (the host knows
     them deterministically from the fetched stream), so only the pool
     is device state."""
-    view = _paged_view(pool, ptab, tokens, pos)
-    view2, out = slot_decode(cfg, params, view, active, temperature, seeds)
-    pool = _pool_write_back(pool, view2, ptab, pos[:, None], active)
-    return pool, out
+    pool, x = _paged_stack(cfg, params, pool, ptab, tokens[:, None],
+                           pos[:, None], active)
+    with jax.named_scope("readout"):
+        logits = lm_logits(cfg, params, x)[:, 0, :]           # [S, V]
+        keys = jax.vmap(_slot_key)(seeds, pos)
+        nxt = jax.vmap(sample_token)(logits, keys, temperature)
+    return pool, jnp.where(active, nxt, tokens)
 
 
-def paged_read_pages(pool: PagedKV, pids: Array):
+def paged_read_pages(cfg: TransformerConfig, pool: PagedKV, pids: Array):
     """Gather pages ``pids`` [TBL] out of the pool (padded with trash
     ids to the bucket's fixed table width — one traced shape per
-    bucket) for the host prefix store.  Pure read."""
+    bucket) for the host prefix store, in the store's own row format
+    [L, TBL, C, NH, D].  Pure read."""
+    L, Pn, C, F = pool.k.shape
+    lp = _every_layer(L, pids)
+    shape = (L,) + pids.shape + (C, cfg.n_heads, cfg.head_dim)
+    k = _read_pages(pool.k, lp).reshape(shape)
+    v = _read_pages(pool.v, lp).reshape(shape)
     if pool.k_scale is None:
-        return pool.k[:, pids], pool.v[:, pids]
-    return (pool.k[:, pids], pool.v[:, pids],
-            pool.k_scale[:, pids], pool.v_scale[:, pids])
+        return k, v
+    return k, v, _read_pages(pool.k_scale, lp), _read_pages(pool.v_scale, lp)
 
 
-def paged_write_pages(pool: PagedKV, pids: Array, k: Array, v: Array,
-                      k_scale: Optional[Array] = None,
+def paged_write_pages(cfg: TransformerConfig, pool: PagedKV, pids: Array,
+                      k: Array, v: Array, k_scale: Optional[Array] = None,
                       v_scale: Optional[Array] = None) -> PagedKV:
-    """Scatter host prefix pages into pool pages ``pids`` [TBL] — the
-    host-store HIT path when the prefix is not pool-resident.  Pad
-    entries point at the trash page."""
-    out = pool._replace(k=pool.k.at[:, pids].set(k),
-                        v=pool.v.at[:, pids].set(v))
+    """Scatter host prefix pages [L, TBL, C, NH, D] into pool pages
+    ``pids`` [TBL] — the host-store HIT path when the prefix is not
+    pool-resident.  Pad entries point at the trash page."""
+    lidx = jnp.arange(pool.k.shape[0])[:, None]
+    flat = k.shape[:3] + pool.k.shape[3:]
+    out = pool._replace(k=pool.k.at[lidx, pids].set(k.reshape(flat)),
+                        v=pool.v.at[lidx, pids].set(v.reshape(flat)))
     if pool.k_scale is None:
         return out
-    return out._replace(k_scale=pool.k_scale.at[:, pids].set(k_scale),
-                        v_scale=pool.v_scale.at[:, pids].set(v_scale))
+    return out._replace(k_scale=pool.k_scale.at[lidx, pids].set(k_scale),
+                        v_scale=pool.v_scale.at[lidx, pids].set(v_scale))
 
 
 # ---------------------------------------------------------------------------
@@ -1001,16 +1136,24 @@ def paged_verify(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
                  ptab: Array, tokens: Array, pos: Array, active: Array,
                  temperature: Array, seeds: Array, drafts: Array
                  ) -> Tuple[PagedKV, Array, Array]:
-    """:func:`slot_verify` over a paged pool: gather view, verify,
-    persist the W written rows per slot (the engine pre-allocates pages
-    through ``pos + k`` so rejected rows stay within the slot's own
-    pages)."""
-    view = _paged_view(pool, ptab, tokens, pos)
-    view2, t, n_commit = slot_verify(cfg, params, view, active,
-                                     temperature, seeds, drafts)
-    posw = pos[:, None] + jnp.arange(drafts.shape[1] + 1)
-    pool = _pool_write_back(pool, view2, ptab, posw, active)
-    return pool, t, n_commit
+    """:func:`slot_verify` over a paged pool, token for token: the same
+    per-layer row write and page read as :func:`paged_decode` with
+    ``W = k + 1`` rows a slot (the engine pre-allocates pages through
+    ``pos + k`` so rejected rows stay within the slot's own pages).
+    Returns (pool', t [S, W], n_commit [S])."""
+    k_spec = drafts.shape[1]
+    toks_w = jnp.concatenate([tokens[:, None], drafts], axis=1)
+    posw = pos[:, None] + jnp.arange(k_spec + 1)              # [S, W]
+    pool, x = _paged_stack(cfg, params, pool, ptab, toks_w, posw, active)
+    with jax.named_scope("readout"):
+        logits = lm_logits(cfg, params, x)                    # [S, W, V]
+        keys = jax.vmap(lambda sd, pw: jax.vmap(
+            lambda pp: _slot_key(sd, pp))(pw))(seeds, posw)   # [S, W]
+        t = jax.vmap(jax.vmap(sample_token, in_axes=(0, 0, None)))(
+            logits, keys, temperature)                        # [S, W]
+    matches = (t[:, :k_spec] == drafts).astype(jnp.int32)
+    n_acc = jnp.sum(jnp.cumprod(matches, axis=1), axis=1)     # [S]
+    return pool, t, jnp.where(active, n_acc + 1, 0)
 
 
 def draft_propose(cfg_d: TransformerConfig, params_d: PyTree,
@@ -1043,19 +1186,17 @@ def paged_draft_propose(cfg_d: TransformerConfig, params_d: PyTree,
                         ) -> Tuple[PagedKV, Array]:
     """:func:`draft_propose` over a paged draft pool sharing the
     TARGET's page table (same positions, same page ids — one allocator
-    covers both pools)."""
+    covers both pools): a lax.scan of k greedy :func:`paged_decode`
+    steps, each reading the pages the step before it wrote."""
     S = tokens.shape[0]
     zt = jnp.zeros((S,), jnp.float32)
     zs = jnp.zeros((S,), jnp.uint32)
 
     def body(carry, _):
         pool, toks, ps = carry
-        view = _paged_view(pool, ptab, toks, ps)
-        view2, t = slot_decode(cfg_d, params_d, view, active, zt, zs)
-        pool = _pool_write_back(pool, view2, ptab, ps[:, None], active)
-        return (pool,
-                jnp.where(active, t, toks),
-                ps + active.astype(jnp.int32)), t
+        pool, t = paged_decode(cfg_d, params_d, pool, ptab, toks, ps,
+                               active, zt, zs)
+        return (pool, t, ps + active.astype(jnp.int32)), t
 
     (dpool, _, _), props = lax.scan(body, (dpool, tokens, pos), None,
                                     length=n_steps)

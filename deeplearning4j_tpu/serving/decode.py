@@ -70,6 +70,20 @@ arXiv:2309.08918):
   never trace: the page read/write executables are pre-traced by
   ``warmup()`` like everything else.  The store assumes frozen params
   (the serving contract) — call ``clear()`` after a weight swap.
+
+PAGED KV (``paged=True``; ``gpt.PagedKV``): the slabs give way to ONE
+pool of ``KV_PAGE_TOKENS``-token pages, [L, P, C, NH*D], shared by
+every rung and donated to every dispatch, and a host-side page table a
+rung.  A decode (or verify, or draft) dispatch of a rung reads, layer
+by layer, the S x TBL pages its table names — that rung's rows of one
+layer at a time, never the pool, never an all-layer view — and writes
+each active slot's fresh rows of that layer at (layer, page, offset),
+in place; an inactive or stalled slot's rows go to the trash page 0.
+A prefill dispatch reads one slot's pages and writes one page.  The
+pinned engine (``paged=False``: ``gpt.slot_*`` on ``DecodeSlots``)
+shares none of this plumbing: a slab a slot owns and pages behind a
+table are two storage schemes, chosen by ``paged`` and visible in the
+type of the state.
 """
 
 from __future__ import annotations
@@ -781,11 +795,17 @@ class DecodeEngine:
         self._read = self._write = None
         if self._prefix is not None:
             if self.paged:
+                def read_fn(pool, pids):
+                    return gpt.paged_read_pages(cfg, pool, pids)
+
+                def write_fn(pool, pids, *pages):
+                    return gpt.paged_write_pages(cfg, pool, pids, *pages)
+
                 self._read = compile_cache.cached_jit(
-                    gpt.paged_read_pages, key=(key, geo, "prefix_read"),
+                    read_fn, key=(key, geo, "prefix_read"),
                     label=f"{label}.prefix_read", **shard_kw_read)
                 self._write = compile_cache.cached_jit(
-                    gpt.paged_write_pages, key=(key, geo, "prefix_write"),
+                    write_fn, key=(key, geo, "prefix_write"),
                     label=f"{label}.prefix_write", donate_argnums=(0,),
                     **shard_kw_write)
             else:

@@ -22,6 +22,7 @@ The load-bearing properties:
 - every tier-3 path preserves the zero-steady-state-compile contract.
 """
 
+import dataclasses
 import threading
 import time
 
@@ -67,8 +68,8 @@ def dparams():
     return gpt.init_params(jax.random.key(3), DCFG)
 
 
-def _solo(p, prompt, n_tokens):
-    out = gpt.generate(CFG, p, np.asarray(prompt, np.int32)[None, :],
+def _solo(p, prompt, n_tokens, cfg=CFG):
+    out = gpt.generate(cfg, p, np.asarray(prompt, np.int32)[None, :],
                        n_tokens, jax.random.key(0), temperature=0.0)
     return list(np.asarray(out)[0])
 
@@ -199,6 +200,157 @@ def test_paged_sampled_matches_pinned(params):
     a = _engine_tokens(paged, prompt, 12, temperature=0.8, seed=5)
     b = _engine_tokens(pinned, prompt, 12, temperature=0.8, seed=5)
     assert a == b
+
+
+def _batched_streams(eng, prompts, budgets, temperature):
+    """Every request through one ContinuousBatcher: more requests than
+    slots, so streams join and leave the running rungs mid-flight."""
+    bat = ContinuousBatcher(eng)
+    try:
+        reqs = [bat.submit(p, max_tokens=n, temperature=temperature, seed=i)
+                for i, (p, n) in enumerate(zip(prompts, budgets))]
+        return [list(r.result(180.0)) for r in reqs]
+    finally:
+        bat.close()
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8],
+                         ids=["greedy", "sampled"])
+def test_paged_multi_rung_joins_and_leaves_match_pinned(params,
+                                                        temperature):
+    """Three rungs in flight, two slots a rung, seven requests: a round
+    dispatches several rungs' programs against the ONE pool, slots fill
+    and free between them, and every stream still equals the pinned
+    engine's (and, greedy, the unbatched ``generate``) token for
+    token."""
+    # a config of its own: engines of one config and geometry share
+    # their jitted programs, and other tests count their own traces
+    cfg = dataclasses.replace(CFG, layer_norm_eps=2e-5)
+    kw = dict(n_slots=2, buckets=(16, 32, 64), prefill_chunk=8)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, 64, size=n).astype(np.int32)
+               for n in (3, 20, 9, 40, 14, 27, 5)]
+    budgets = [6, 10, 20, 12, 4, 30, 9]
+    paged = DecodeEngine(cfg, params, paged=True, **kw)
+    pinned = DecodeEngine(cfg, params, **kw)
+    assert {paged.pick_bucket(len(p) + n)
+            for p, n in zip(prompts, budgets)} == {16, 32, 64}
+    paged.warmup()
+    pinned.warmup()
+    got = _batched_streams(paged, prompts, budgets, temperature)
+    assert got == _batched_streams(pinned, prompts, budgets, temperature)
+    assert [len(g) for g in got] == budgets
+    if temperature == 0.0:
+        assert got == [_solo(params, p, n, cfg)
+                       for p, n in zip(prompts, budgets)]
+    paged.drop_residents()
+    assert paged._alloc.in_use() == 0
+
+
+def _random_pool(kv_dtype, n_pages=6, page_tokens=8):
+    """A pool with no two rows alike, so a row that was written shows."""
+    shapes = jax.eval_shape(
+        lambda: gpt.init_pages(CFG, n_pages, page_tokens, kv_dtype))
+    keys = iter(jax.random.split(jax.random.key(21), 4))
+
+    def fill(a):
+        x = jax.random.normal(next(keys), a.shape)
+        return (x * 40).astype(a.dtype) if a.dtype == np.int8 \
+            else (abs(x) + 0.1).astype(a.dtype)
+
+    return gpt.PagedKV(*(None if a is None else fill(a) for a in shapes))
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp32", "int8"])
+@pytest.mark.parametrize("fn", ["decode", "verify"])
+def test_stale_and_inactive_writes_land_in_the_trash_page(params, fn,
+                                                          kv_dtype):
+    """Slot 1 is inactive and its stale table still names page 2, which
+    was freed and handed to live slot 0; slot 2 is live but runs past
+    its table.  Only slot 0's fresh rows (and slot 2's one in-range row)
+    change the pool outside page 0 — every other row of every array,
+    page 2's among them, stays bit-identical."""
+    C = 8
+    pool = _random_pool(kv_dtype, page_tokens=C)
+    ptab = np.array([[1, 2], [2, 0], [3, 4]], np.int32)
+    tokens = np.array([5, 6, 7], np.int32)
+    active = np.array([True, False, True])
+    temps = np.zeros((3,), np.float32)
+    seeds = np.zeros((3,), np.uint32)
+    if fn == "decode":
+        pos = np.array([9, 5, 16], np.int32)
+        new, _ = jax.jit(lambda *a: gpt.paged_decode(CFG, *a))(
+            params, pool, ptab, tokens, pos, active, temps, seeds)
+        want = {(2, 1), (0, 5), (0, 7)}
+    else:
+        pos = np.array([9, 5, 15], np.int32)
+        drafts = np.array([[1, 2], [3, 4], [5, 6]], np.int32)
+        new, _, n_commit = jax.jit(lambda *a: gpt.paged_verify(CFG, *a))(
+            params, pool, ptab, tokens, pos, active, temps, seeds, drafts)
+        assert int(n_commit[1]) == 0
+        want = {(2, 1), (2, 2), (2, 3), (0, 5), (0, 6), (0, 7), (4, 7)}
+    for old, got in zip(pool, new):
+        if old is None:
+            assert got is None
+            continue
+        diff = np.asarray(old != got)
+        diff = diff.reshape(diff.shape[:3] + (-1,)).any(axis=(0, 3))
+        assert {(int(p), int(o)) for p, o in zip(*np.nonzero(diff))} \
+            == want
+
+
+@pytest.mark.parametrize("fn", ["decode", "verify", "draft"])
+def test_paged_dispatch_updates_the_donated_pool_in_place(fn):
+    """Many pages, two slots, a two-page table: the pool dwarfs what a
+    dispatch reads of it.  The compiled program aliases every pool
+    array input -> output and its temporaries stay under ONE pool
+    array's bytes — a whole-pool copy, view or re-layout on any backend
+    fails this."""
+    params = jax.eval_shape(lambda: gpt.init_params(jax.random.key(0), CFG))
+    pool = jax.eval_shape(lambda: gpt.init_pages(CFG, 2049, 8))
+    S, TBL = 2, 2
+    sd = jax.ShapeDtypeStruct
+    i32 = lambda *shape: sd(shape, np.int32)  # noqa: E731
+    args = [params, pool, i32(S, TBL), i32(S), i32(S), sd((S,), np.bool_)]
+    if fn == "draft":
+        def f(*a):
+            return gpt.paged_draft_propose(CFG, *a, 3)
+    else:
+        f = {"decode": gpt.paged_decode, "verify": gpt.paged_verify}[fn]
+        f = (lambda g: lambda *a: g(CFG, *a))(f)
+        args += [sd((S,), np.float32), sd((S,), np.uint32)]
+        if fn == "verify":
+            args.append(i32(S, 3))
+    compiled = jax.jit(f, donate_argnums=(1,)).lower(*args).compile()
+    one_array = int(np.prod(pool.k.shape)) * pool.k.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * one_array
+    assert mem.temp_size_in_bytes < one_array, (
+        mem.temp_size_in_bytes, one_array)
+    head = compiled.as_text().split("\n", 1)[0]
+    assert head.count("-alias)") == 2, head[:300]   # pool.k, pool.v
+
+
+def test_paged_engine_on_a_model_mesh_matches_replicated(params):
+    """Heads over ``model``: the pool's NH*D rows split in whole heads,
+    page gathers and row scatters stay shard-local, and the tokens are
+    the replicated paged engine's."""
+    from deeplearning4j_tpu.parallel.mesh import (MODEL_AXIS, MeshSpec,
+                                                  make_mesh)
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs >= 2 devices")
+    mesh = make_mesh(MeshSpec(data=1, model=2), devices=jax.devices()[:2])
+    cfg = dataclasses.replace(CFG, layer_norm_eps=3e-5)     # own programs
+    kw = dict(n_slots=2, buckets=(16, 32), prefill_chunk=8, paged=True)
+    eng_r = DecodeEngine(cfg, params, label="t3-pg-repl", **kw)
+    eng_s = DecodeEngine(cfg, params, mesh=mesh, label="t3-pg-shard", **kw)
+    eng_r.warmup()
+    eng_s.warmup()
+    prompt = np.arange(1, 14, dtype=np.int32)
+    assert _engine_tokens(eng_s, prompt, 10) \
+        == _engine_tokens(eng_r, prompt, 10)
+    assert MODEL_AXIS in eng_s._pool_state().k.sharding.spec
 
 
 def test_resident_prefix_mounts_by_reference(params):

@@ -683,7 +683,7 @@ def test_paged_decode_names_its_scopes_in_the_lowered_program():
         jax.ShapeDtypeStruct((S,), jnp.bool_),
         jax.ShapeDtypeStruct((S,), jnp.float32),
         jax.ShapeDtypeStruct((S,), jnp.uint32)).as_text(debug_info=True)
-    for scope in ("paged_view", "pool_write_back", "attention", "readout"):
+    for scope in ("page_read", "row_write", "attention", "readout"):
         assert f"/{scope}/" in text, scope
     text = jax.jit(lambda *a: gpt.paged_prefill(cfg, *a)).lower(
         params, pool, i32(TBL), i32(32), i32(), i32(),
