@@ -236,3 +236,78 @@ def make_gspmd_moe_ffn(mesh: Optional[Mesh], cfg: MoEConfig):
 
     return shard_map(inner, mesh=mesh, in_specs=(pspec, tok_spec),
                      out_specs=(tok_spec, P()), check_vma=False)
+
+
+# ---------------------------------------------------------------------------
+# One expert-parallel rank on the decode path: group-limited routing over
+# every expert, no capacity, and the part of the result the experts HELD
+# HERE give.  What the absent ranks' experts would add is left out (it
+# would arrive by the all-to-all this rank is not part of); nothing here
+# stands in for them.
+# ---------------------------------------------------------------------------
+
+def route_group_limited(scores: Array, n_group: int, topk_group: int,
+                        top_k: int, scale: float
+                        ) -> Tuple[Array, Array]:
+    """DeepSeek-V2's device-limited routing (``group_limited_greedy``).
+
+    ``scores`` [N, E] float32 router probabilities over ALL experts, E
+    in ``n_group`` consecutive groups (one group a device).  A group's
+    score is its largest; the ``topk_group`` best groups stay, and among
+    their experts the ``top_k`` best are taken, each weighted
+    ``scale * score`` (``norm_topk_prob`` false: no renormalising, no
+    capacity, no token dropped).  Returns (weights [N, E] float32, zero
+    off the chosen experts; chosen [N, E] bool)."""
+    N, E = scores.shape
+    g = scores.reshape(N, n_group, E // n_group)
+    _, top_groups = lax.top_k(g.max(axis=-1), topk_group)       # [N, kg]
+    keep = jnp.zeros((N, n_group), jnp.bool_).at[
+        jnp.arange(N)[:, None], top_groups].set(True)
+    kept = jnp.where(keep[:, :, None], g, 0.0).reshape(N, E)
+    _, top_experts = lax.top_k(kept, top_k)                     # [N, k]
+    chosen = jnp.zeros((N, E), jnp.bool_).at[
+        jnp.arange(N)[:, None], top_experts].set(True)
+    return jnp.where(chosen, scores * scale, 0.0), chosen
+
+
+def gated_ffn(x: Array, w_gate: Array, w_up: Array, w_down: Array) -> Array:
+    """``W_down(silu(W_gate x) * W_up x)``: operands in ``x``'s type,
+    products accumulated and returned in float32."""
+    g = jnp.einsum("nh,hf->nf", x, w_gate,
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("nh,hf->nf", x, w_up,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("nf,fh->nh", (jax.nn.silu(g) * u).astype(x.dtype),
+                      w_down, preferred_element_type=jnp.float32)
+
+
+def held_experts_ffn(x: Array, weights: Array, chosen: Array,
+                     experts: dict) -> Tuple[Array, Array]:
+    """The routed part of an expert layer that the experts held here
+    give.  ``x`` [N, H]; ``weights``/``chosen`` [N, E_held]: the held
+    columns of :func:`route_group_limited`'s result (rows of tokens that
+    do not count — an idle slot, a chunk's padding — all zero/False);
+    ``experts`` ``{"w_gate": [E_held, H, F], "w_up": [E_held, H, F],
+    "w_down": [E_held, F, H]}``.  Returns (y [N, H] float32, hits: how
+    many held experts some token chose).
+
+    Only the experts HIT are touched: they are put first and a loop of
+    ``hits`` steps runs each over all N rows (a handful, on the decode
+    path), the rows that did not choose it weighted 0.  An expert's
+    47 MB are then read once a dispatch, and an expert nobody chose
+    costs nothing, which is what makes a decode step's bytes follow the
+    routing and not the layer's size."""
+    hit = chosen.any(axis=0)                                   # [E_held]
+    hits = hit.sum().astype(jnp.int32)
+    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)
+
+    def one(i, acc):
+        e = order[i]
+        w = [lax.dynamic_index_in_dim(experts[k], e, keepdims=False)
+             for k in ("w_gate", "w_up", "w_down")]
+        mine = lax.dynamic_index_in_dim(weights, e, axis=1, keepdims=True)
+        return acc + mine * gated_ffn(x, *w)
+
+    y = lax.fori_loop(0, hits, one,
+                      jnp.zeros(x.shape, jnp.float32))
+    return y, hits

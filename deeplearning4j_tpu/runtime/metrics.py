@@ -368,12 +368,27 @@ class DecodeMetrics:
     - ``advance_s`` (``decode.advance``): ``engine.advance`` calls, one
       per ``decode_dispatches``; ``fetch_s`` (``decode.fetch``): the
       part of them spent waiting for the step's tokens.
+
+    Expert layers on the decode path (a model family whose decode step
+    returns them behind its tokens, ``models/deepseek_v2.py``), summed
+    over decode dispatches and their expert layers:
+
+    - ``moe_assignments``: (token, expert) choices the router made for
+      active slots — tokens x layers x experts per token;
+      ``moe_assignments_held``: those that fell on an expert this rank
+      holds; ``moe_expert_hits``: distinct held experts some token
+      chose, a layer a dispatch (what the step read of the experts);
+      ``moe_layer_dispatches``: expert layers run.
     """
 
     MAX_SAMPLES = 8192
     #: the cumulative-seconds counters a span may name
     SECONDS = ("round_s", "queue_wait_s", "prefill_s", "prefill_sync_s",
                "advance_s", "fetch_s")
+    #: counts a model family's decode step returns behind its tokens
+    #: (``note_family_counts``)
+    FAMILY_COUNTS = ("moe_assignments", "moe_assignments_held",
+                     "moe_expert_hits", "moe_layer_dispatches")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -418,6 +433,8 @@ class DecodeMetrics:
             self.admissions = 0
             for key in self.SECONDS:
                 setattr(self, key, 0.0)
+            for key in self.FAMILY_COUNTS:
+                setattr(self, key, 0)
             self._ttft_ms: List[float] = []
             self._compile_mark: Optional[int] = None
 
@@ -426,6 +443,17 @@ class DecodeMetrics:
             raise KeyError(f"no cumulative-seconds counter {key!r}")
         with self._lock:
             setattr(self, key, getattr(self, key) + seconds)
+
+    def note_family_counts(self, names, counts) -> None:
+        """Add what one decode dispatch counted on the device: ``names``
+        from ``FAMILY_COUNTS``, ``counts`` the integers fetched with the
+        step's tokens."""
+        for key in names:
+            if key not in self.FAMILY_COUNTS:
+                raise KeyError(f"no family counter {key!r}")
+        with self._lock:
+            for key, n in zip(names, counts):
+                setattr(self, key, getattr(self, key) + int(n))
 
     def note_round(self) -> None:
         with self._lock:
@@ -591,6 +619,7 @@ class DecodeMetrics:
                 "rounds": self.rounds,
                 "admissions": self.admissions,
                 **{key: getattr(self, key) for key in self.SECONDS},
+                **{key: getattr(self, key) for key in self.FAMILY_COUNTS},
                 "compile_mark": self._compile_mark,
             }
         if out["compile_mark"] is not None:

@@ -89,6 +89,7 @@ type of the state.
 from __future__ import annotations
 
 import hashlib
+import importlib
 import itertools
 import queue
 import threading
@@ -106,6 +107,20 @@ from deeplearning4j_tpu.parallel.mesh import (MODEL_AXIS, mesh_signature,
                                               model_degree)
 from deeplearning4j_tpu.runtime import compile_cache, quantize as qz, telemetry
 from deeplearning4j_tpu.runtime.metrics import decode_metrics
+
+
+def model_family(cfg):
+    """The module under ``models/`` whose paged functions serve ``cfg``
+    (``init_pages``, ``pages_bytes``, ``slots_bytes_per_slot``,
+    ``paged_specs``, ``paged_prefill``, ``paged_decode``,
+    ``paged_read_pages``, ``paged_write_pages``): the one a config names
+    in its ``family``, ``models/gpt.py`` for a config that names none.
+    A family may also state ``UNSUPPORTED_ENGINE_OPTIONS`` (engine
+    options it has no code for: the engine raises instead of running
+    another family's) and ``DECODE_COUNTERS`` (names of the counts its
+    ``paged_decode`` appends to the step's tokens)."""
+    name = getattr(cfg, "family", "gpt")
+    return importlib.import_module(f"deeplearning4j_tpu.models.{name}")
 
 
 #: tokens per KV page — ONE constant shared by the paged allocator and
@@ -442,8 +457,14 @@ class _Bucket:
 
 
 class DecodeEngine:
-    """Slot-structured KV-cache decode engine for a causal LM
-    (models/gpt.py).  NOT thread-safe: exactly one thread (normally the
+    """Slot-structured KV-cache decode engine for a causal LM.  The
+    model family is an argument, not an import: a paged engine takes
+    its pool and its two dispatches from the family of ``cfg``
+    (:func:`model_family`: ``models/gpt.py``, ``models/deepseek_v2.py``)
+    and holds ``params`` in the type they are given in.  The pinned
+    engine, the mesh, quantization, int8 pools, speculative decoding and
+    the prefix store are ``models/gpt.py``'s; a family that has none of
+    them says so and the engine raises.  NOT thread-safe: exactly one thread (normally the
     ``ContinuousBatcher`` worker) may drive ``start``/``advance``/
     ``release``; construction and ``warmup()`` happen before serving.
 
@@ -480,6 +501,22 @@ class DecodeEngine:
         self.mesh = mesh
         self.n_slots = int(n_slots)
         self.paged = bool(paged) or n_pages is not None
+        fam = self._family = model_family(cfg)
+        fam_name = fam.__name__.rsplit(".", 1)[-1]
+        asked = {"pinned": not self.paged, "mesh": mesh is not None,
+                 "kv_dtype": kv_dtype is not None,
+                 "quantize": quantize is not None,
+                 "draft": draft is not None,
+                 "prefix_cache": bool(prefix_cache)}
+        for option in getattr(fam, "UNSUPPORTED_ENGINE_OPTIONS", ()):
+            if asked[option]:
+                raise ValueError(
+                    f"DecodeEngine option {option!r} is not supported "
+                    f"for the {fam_name} family "
+                    f"({type(cfg).__name__})")
+        #: names of the counts this family's decode step returns behind
+        #: its tokens (``decode_metrics.note_family_counts``)
+        self._decode_counters = tuple(getattr(fam, "DECODE_COUNTERS", ()))
         self.draft = draft
         self.draft_k = int(draft_k)
         if draft is not None and self.draft_k < 1:
@@ -571,16 +608,16 @@ class DecodeEngine:
             for t in self.buckets}
         verify_fn = None
         if self.paged:
-            key = ("gpt_slots", repr(cfg))
+            key = (f"{fam_name}_slots", repr(cfg))
 
             def prefill_fn(params, pool, ptab_s, toks, start, n_valid,
                            temperature, seed):
-                return gpt.paged_prefill(cfg, params, pool, ptab_s, toks,
+                return fam.paged_prefill(cfg, params, pool, ptab_s, toks,
                                          start, n_valid, temperature, seed)
 
             def decode_fn(params, pool, ptab, tokens, pos, active,
                           temperature, seeds):
-                return gpt.paged_decode(cfg, params, pool, ptab, tokens,
+                return fam.paged_decode(cfg, params, pool, ptab, tokens,
                                         pos, active, temperature, seeds)
 
             if draft is not None:
@@ -662,7 +699,7 @@ class DecodeEngine:
             self._param_shardings = psh
             if self.paged:
                 poolsh = named_shardings(
-                    mesh, gpt.paged_specs(cfg, self.kv_dtype))
+                    mesh, fam.paged_specs(cfg, self.kv_dtype))
                 self._pool_shardings = poolsh
                 # paged_prefill(params, pool, ptab_s, toks, start,
                 # n_valid, temp, seed) / paged_decode(params, pool,
@@ -796,10 +833,10 @@ class DecodeEngine:
         if self._prefix is not None:
             if self.paged:
                 def read_fn(pool, pids):
-                    return gpt.paged_read_pages(cfg, pool, pids)
+                    return fam.paged_read_pages(cfg, pool, pids)
 
                 def write_fn(pool, pids, *pages):
-                    return gpt.paged_write_pages(cfg, pool, pids, *pages)
+                    return fam.paged_write_pages(cfg, pool, pids, *pages)
 
                 self._read = compile_cache.cached_jit(
                     read_fn, key=(key, geo, "prefix_read"),
@@ -818,7 +855,7 @@ class DecodeEngine:
                     **shard_kw_write)
         #: KV bytes one slot of the largest bucket costs — the 'slots
         #: per chip' capacity denominator (int8 KV is the ~4x/2x lever)
-        self.kv_bytes_per_slot = int(gpt.slots_bytes_per_slot(
+        self.kv_bytes_per_slot = int(fam.slots_bytes_per_slot(
             cfg, self.buckets[-1], self.kv_dtype))
         decode_metrics.note_kv_bytes_per_slot(self.kv_bytes_per_slot)
         #: total paged-pool HBM (target + draft pools) — the paged
@@ -826,7 +863,7 @@ class DecodeEngine:
         #: bounded by live tokens, not bucket length
         self.pool_bytes = 0
         if self.paged:
-            self.pool_bytes = int(gpt.pages_bytes(
+            self.pool_bytes = int(fam.pages_bytes(
                 cfg, self.n_kv_pages, self.page_tokens, self.kv_dtype))
             if draft is not None:
                 self.pool_bytes += int(gpt.pages_bytes(
@@ -919,8 +956,8 @@ class DecodeEngine:
         every bucket (page shape is bucket-independent; only the page
         TABLE width differs per bucket)."""
         if self._pool is None:
-            pool = gpt.init_pages(self.cfg, self.n_kv_pages,
-                                  self.page_tokens, self.kv_dtype)
+            pool = self._family.init_pages(self.cfg, self.n_kv_pages,
+                                           self.page_tokens, self.kv_dtype)
             if self._pool_shardings is not None:
                 pool = jax.device_put(pool, self._pool_shardings)
             self._pool = pool
@@ -1344,6 +1381,22 @@ class DecodeEngine:
         return {"buckets": len(self.buckets), "compiles": compiles,
                 "warmup_ms": round(wall_ms, 1)}
 
+    def decode_hlo(self, bucket: int) -> str:
+        """Optimized HLO text of ``bucket``'s decode step as compiled
+        for this engine, every instruction with XLA's ``op_name`` (the
+        ``jax.named_scope`` path it was traced under).  A device trace
+        names its ops by instruction and carries no ``op_name``; a
+        reader that wants device time by scope joins the two on the
+        instruction.  Traces and lowers the step again (the executable
+        comes from the compile cache): for set-up, never for the
+        serving thread.  Paged engines only."""
+        if not self.paged:
+            raise ValueError("decode_hlo reads a paged engine's step")
+        b = self._buckets[bucket]
+        return self._decode.jitted.lower(
+            self.current_params(), self._pool_state(), b.ptab, b.tokens_h,
+            b.pos_h, b.active, b.temps, b.seeds).compile().as_text()
+
     # -- serving -----------------------------------------------------------
     def start(self, prompt: np.ndarray, *, max_tokens: int,
               temperature: float = 0.0, seed: int = 0,
@@ -1631,6 +1684,11 @@ class DecodeEngine:
                 raise
             self._pool = pool
         toks = self._fetch(out)
+        if self._decode_counters:
+            # the family's counts came back behind the S tokens, in the
+            # one fetch a step makes
+            toks, counts = toks[:self.n_slots], toks[self.n_slots:]
+            decode_metrics.note_family_counts(self._decode_counters, counts)
         b.tokens_h[run] = toks[run]
         b.pos_h[run] += 1
         decode_metrics.note_decode_dispatch(int(run.sum()), self.n_slots)
