@@ -373,7 +373,9 @@ def phase_serve(cfg: Any, params: Any, sz: Sizes
         tokens_each=sz.max_tokens, wall_s=round(wall, 3),
         compile_delta_since_mark=traces, xla_compile_requests=xla,
         prefix_hits=snap["prefix_hits"], joins=snap["joins"],
-        pages_in_use_hw=snap["pages_in_use_hw"])
+        pages_in_use_hw=snap["pages_in_use_hw"],
+        params_held_casts=snap["params_held_casts"],
+        params_held_bytes=snap["params_held_bytes"])
 
     check_streams(cfg, sz, outs)
     check(traces == 0, f"{traces} trace(s) in the marked decode window")

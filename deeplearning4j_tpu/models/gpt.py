@@ -362,6 +362,17 @@ def _prefill_chunk(cfg: TransformerConfig, params: PyTree, cache: KVCache,
 #: shape is ONE regardless of prompt length
 PREFILL_CHUNK = 32
 
+#: the leaves every serving step of this family (pinned, paged, verify,
+#: draft) reads ONLY through ``.astype(cfg.compute_dtype)``, by their
+#: keys from the root: ``DecodeEngine`` may hold them in that type, cast
+#: once per tree, and each step's cast is then no operation.  NOT
+#: ``embed.tok``: the readout casts it, but the embedding look-up reads
+#: it as stored (float32 into the LayerNorm), so casting it would change
+#: the mathematics; biases, LayerNorm leaves and ``embed.pos`` are added
+#: in float32.
+COMPUTE_DTYPE_LEAVES = tuple(("blocks", name) for name in
+                             ("wq", "wk", "wv", "wo", "w1", "w2"))
+
 
 def prefill_cache(cfg: TransformerConfig, params: PyTree, cache: KVCache,
                   prompt: Array, chunk: int = PREFILL_CHUNK

@@ -369,6 +369,17 @@ class DecodeMetrics:
       per ``decode_dispatches``; ``fetch_s`` (``decode.fetch``): the
       part of them spent waiting for the step's tokens.
 
+    The tree a ``DecodeEngine`` holds for its executables
+    (``serving.decode.hold_in_compute_dtype``, span
+    ``decode.hold_params``):
+
+    - ``params_held_casts``: times a held tree was made by casting the
+      leaves a family names to the compute type — once per params tree
+      an engine is given (construction, ``rebind_params``, a
+      live-params callable's new tree), never inside a dispatch; 0 for
+      a tree that arrives in the compute type;
+      ``params_held_bytes``: bytes of the leaves so cast, as held.
+
     Expert layers on the decode path (a model family whose decode step
     returns them behind its tokens, ``models/deepseek_v2.py``), summed
     over decode dispatches and their expert layers:
@@ -431,6 +442,8 @@ class DecodeMetrics:
             self.pages_leaked = 0
             self.rounds = 0
             self.admissions = 0
+            self.params_held_casts = 0
+            self.params_held_bytes = 0
             for key in self.SECONDS:
                 setattr(self, key, 0.0)
             for key in self.FAMILY_COUNTS:
@@ -454,6 +467,11 @@ class DecodeMetrics:
         with self._lock:
             for key, n in zip(names, counts):
                 setattr(self, key, getattr(self, key) + int(n))
+
+    def note_params_held(self, nbytes: int) -> None:
+        with self._lock:
+            self.params_held_casts += 1
+            self.params_held_bytes += int(nbytes)
 
     def note_round(self) -> None:
         with self._lock:
@@ -618,6 +636,8 @@ class DecodeMetrics:
                 "ttft_p99_ms": ServingMetrics._pct(ttft, 0.99),
                 "rounds": self.rounds,
                 "admissions": self.admissions,
+                "params_held_casts": self.params_held_casts,
+                "params_held_bytes": self.params_held_bytes,
                 **{key: getattr(self, key) for key in self.SECONDS},
                 **{key: getattr(self, key) for key in self.FAMILY_COUNTS},
                 "compile_mark": self._compile_mark,

@@ -117,10 +117,76 @@ def model_family(cfg):
     in its ``family``, ``models/gpt.py`` for a config that names none.
     A family may also state ``UNSUPPORTED_ENGINE_OPTIONS`` (engine
     options it has no code for: the engine raises instead of running
-    another family's) and ``DECODE_COUNTERS`` (names of the counts its
-    ``paged_decode`` appends to the step's tokens)."""
+    another family's), ``DECODE_COUNTERS`` (names of the counts its
+    ``paged_decode`` appends to the step's tokens) and
+    ``COMPUTE_DTYPE_LEAVES`` (the leaves its steps read only through
+    ``.astype(cfg.compute_dtype)``, each by its keys from the root:
+    :func:`hold_in_compute_dtype`)."""
     name = getattr(cfg, "family", "gpt")
     return importlib.import_module(f"deeplearning4j_tpu.models.{name}")
+
+
+def _leaf_at(tree: Any, keys: Sequence[Any]) -> Any:
+    for k in keys:
+        tree = tree[k]
+    return tree
+
+
+def _with_leaf(tree: Any, keys: Sequence[Any], leaf: Any) -> Any:
+    """``tree`` (nested dicts) with the leaf at ``keys`` replaced: the
+    dicts on the way copied, everything else the same objects."""
+    if not keys:
+        return leaf
+    return {**tree, keys[0]: _with_leaf(tree[keys[0]], keys[1:], leaf)}
+
+
+def hold_in_compute_dtype(cfg, tree: Any, shardings: Any = None,
+                          label: str = "decode") -> Any:
+    """``tree`` as a serving engine holds it: every leaf the family of
+    ``cfg`` names in ``COMPUTE_DTYPE_LEAVES`` cast to
+    ``cfg.compute_dtype`` by ONE jitted call on the device, so the
+    steps' own ``.astype`` of it is no operation and no dispatch
+    converts a weight again.  The values are the ones the steps would
+    have computed (the same rounding of the same number, made once and
+    kept), so every token is the one the raw tree gives.
+
+    What the engine can observe decides, not an option: a named leaf
+    already of that type (or not a floating array: a ``QTensor``),
+    every leaf the family does not name, and the whole tree of a family
+    that names none or of a float32 compute type are returned as the
+    SAME objects, never copied.  ``shardings`` (the engine's
+    ``NamedSharding`` tree under a mesh) lays the cast leaves out as
+    their float32 originals.  Counted in
+    ``decode_metrics.params_held_casts`` / ``params_held_bytes``."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    todo = {}
+    for keys in getattr(model_family(cfg), "COMPUTE_DTYPE_LEAVES", ()):
+        leaf = _leaf_at(tree, keys)
+        if (hasattr(leaf, "dtype") and leaf.dtype != cdt
+                and jnp.issubdtype(leaf.dtype, jnp.floating)):
+            todo[keys] = leaf
+    if not todo:
+        return tree
+    names = tuple(todo)
+    lay: Dict[str, Any] = {}
+    if shardings is not None:
+        sh = [_leaf_at(shardings, keys) for keys in names]
+        lay = dict(in_shardings=(sh,), out_shardings=sh)
+
+    def cast(leaves):
+        return [x.astype(cdt) for x in leaves]
+
+    fn = compile_cache.cached_jit(
+        cast, key=("hold_params", str(cdt), names,
+                   tuple((mesh_signature(s.mesh), str(s.spec))
+                         for s in lay.get("out_shardings", ()))),
+        label=f"{label}.hold_params", **lay)
+    with telemetry.span("decode.hold_params", leaves=len(names)):
+        held = fn(list(todo.values()))
+    decode_metrics.note_params_held(qz.tree_bytes(held))
+    for keys, leaf in zip(names, held):
+        tree = _with_leaf(tree, keys, leaf)
+    return tree
 
 
 #: tokens per KV page — ONE constant shared by the paged allocator and
@@ -461,10 +527,23 @@ class DecodeEngine:
     model family is an argument, not an import: a paged engine takes
     its pool and its two dispatches from the family of ``cfg``
     (:func:`model_family`: ``models/gpt.py``, ``models/deepseek_v2.py``)
-    and holds ``params`` in the type they are given in.  The pinned
-    engine, the mesh, quantization, int8 pools, speculative decoding and
-    the prefix store are ``models/gpt.py``'s; a family that has none of
-    them says so and the engine raises.  NOT thread-safe: exactly one thread (normally the
+    and holds, for each ``params`` tree it is given, the tree its
+    executables take (``current_params()``), made once per tree: the
+    leaves the family names in ``COMPUTE_DTYPE_LEAVES`` (those its steps
+    read only through ``.astype(cfg.compute_dtype)``: GPT's six block
+    matrices, none of DeepSeek-V2's) cast to that type by one jitted
+    call (:func:`hold_in_compute_dtype`), every other leaf the given
+    array.  A leaf already of the compute type is never copied, so a
+    tree that arrives in it, or a float32 compute type, is held as
+    given; float32 masters under a bfloat16 compute type cost half
+    their named leaves' bytes again on the device for as long as the
+    caller keeps its own tree (the engine drops its reference to a
+    static one).  The draft's tree is held the same way by its own
+    config.  ``quantize`` takes this step's place where it is set.  The
+    pinned engine, the mesh, quantization, int8 pools, speculative
+    decoding and the prefix store are ``models/gpt.py``'s; a family
+    that has none of them says so and the engine raises.  NOT
+    thread-safe: exactly one thread (normally the
     ``ContinuousBatcher`` worker) may drive ``start``/``advance``/
     ``release``; construction and ``warmup()`` happen before serving.
 
@@ -545,8 +624,8 @@ class DecodeEngine:
         # pinned engines interop because the space is mode-free)
         self._params_gen = 0
         self._prefix_space = (repr(cfg), quantize, kv_dtype, 0)
-        self._qmemo = qz.QuantMemo()
-        self._static_quantized = False
+        self._held_memo = qz.QuantMemo()
+        self._static_held = False
         self.prefill_chunk = int(prefill_chunk)
         self.buckets = tuple(sorted(set(
             buckets if buckets is not None
@@ -761,8 +840,6 @@ class DecodeEngine:
                 dpsh = named_shardings(
                     mesh, gpt.shard_specs(cfg_d, model_degree=m_deg))
                 self._draft_shardings = dpsh
-                self._draft_params = jax.device_put(
-                    self._draft_params, dpsh)
                 if self.paged:
                     dpoolsh = named_shardings(
                         mesh, gpt.paged_specs(cfg_d, self.kv_dtype))
@@ -783,6 +860,8 @@ class DecodeEngine:
                     shard_kw_dprefill = dict(
                         in_shardings=(dpsh, dssh) + (repl,) * 4,
                         out_shardings=dssh)
+        if draft is not None:
+            self._draft_params = self._hold_draft(self._draft_params)
         self._prefill = compile_cache.cached_jit(
             prefill_fn, key=(key, geo, "prefill"),
             label=f"{label}.prefill", donate_argnums=(1,),
@@ -900,25 +979,43 @@ class DecodeEngine:
             q = jax.device_put(q, self._param_shardings)
         return q
 
+    def _hold_draft(self, tree):
+        """The draft's tree as its executables take it: laid out under
+        a mesh, its family's ``COMPUTE_DTYPE_LEAVES`` in the draft
+        config's compute type.  The draft is never quantized."""
+        if self._draft_shardings is not None:
+            tree = jax.device_put(tree, self._draft_shardings)
+        return hold_in_compute_dtype(self._draft_cfg, tree,
+                                     self._draft_shardings, self.label)
+
+    def _hold(self, raw_tree):
+        """The tree the executables take for ``raw_tree``: quantized
+        where ``quantize`` is set, else with the family's
+        ``COMPUTE_DTYPE_LEAVES`` in the compute type."""
+        if self.quantize is not None:
+            return self._quantize_and_place(raw_tree)
+        return hold_in_compute_dtype(self.cfg, raw_tree,
+                                     self._param_shardings, self.label)
+
     def current_params(self) -> Any:
-        """The params tree the executables take — quantized (and, under
-        a mesh, laid out) when ``quantize`` is set.  STATIC params are
-        quantized once and the engine's reference to the raw fp32 tree
-        is DROPPED (device memory then holds only int8 + scales once
-        the caller releases theirs — the HBM point of the knob).
-        Live-params callables are memoized per raw-tree IDENTITY and
-        re-pay quantization only when they return a new tree object
-        (the post-training contract: weights are frozen while serving;
-        a swap should also ``clear()`` any prefix cache)."""
-        if self.quantize is None:
-            return self._raw_params()
+        """The params tree the executables take, made ONCE per raw tree
+        (:meth:`_hold`): quantized (and, under a mesh, laid out) when
+        ``quantize`` is set, else the given tree with the leaves its
+        family names cast to the compute type — the given tree itself
+        where there is nothing to cast.  STATIC params are transformed
+        once and the engine's reference to the raw tree is DROPPED
+        (device memory then holds only the held tree once the caller
+        releases theirs).  Live-params callables are memoized per
+        raw-tree IDENTITY and re-pay the transform only when they
+        return a new tree object (the post-training contract: weights
+        are frozen while serving; a swap should also ``clear()`` any
+        prefix cache)."""
         if not callable(self._params):
-            if not self._static_quantized:
-                self._params = self._quantize_and_place(self._params)
-                self._static_quantized = True
+            if not self._static_held:
+                self._params = self._hold(self._params)
+                self._static_held = True
             return self._params
-        return self._qmemo.get(self._raw_params(),
-                               self._quantize_and_place)
+        return self._held_memo.get(self._raw_params(), self._hold)
 
     # -- geometry ----------------------------------------------------------
     def pick_bucket(self, total_len: int) -> int:
@@ -1159,29 +1256,26 @@ class DecodeEngine:
         while IDLE (the router drains this replica first; a busy rebind
         raises).  Same shapes/dtypes → the executables and their
         compile-cache entries are reused untouched: ZERO new compiles.
-        Quantization re-runs lazily on the next ``current_params()`` —
-        call that on the swap thread to keep the requantize cost off
-        the serving worker.  The engine-local resident page registry is
-        invalidated (pages are only valid for the params that wrote
-        them); clearing a SHARED host :class:`PrefixCache` is the
-        router's job, once per store."""
+        The held tree (quantized, or cast to the compute type) is made
+        again lazily on the next ``current_params()`` — call that on
+        the swap thread to keep its cost off the serving worker.  The
+        engine-local resident page registry is invalidated (pages are
+        only valid for the params that wrote them); clearing a SHARED
+        host :class:`PrefixCache` is the router's job, once per store."""
         if self.n_active():
             raise RuntimeError(
                 f"rebind_params on a busy engine ({self.n_active()} "
                 f"active slot(s)): drain first")
         self._params = params
-        self._static_quantized = False
-        self._qmemo = qz.QuantMemo()
+        self._static_held = False
+        self._held_memo = qz.QuantMemo()
         self._params_gen += 1
         self._prefix_space = (repr(self.cfg), self.quantize,
                               self.kv_dtype, self._params_gen)
         if draft_params is not None:
             if self._draft_cfg is None:
                 raise ValueError("engine built without draft=")
-            if self._draft_shardings is not None:
-                draft_params = jax.device_put(draft_params,
-                                              self._draft_shardings)
-            self._draft_params = draft_params
+            self._draft_params = self._hold_draft(draft_params)
         if self.paged:
             for _, (_, ids) in self._resident.items():
                 self._alloc.free(ids)
