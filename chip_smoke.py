@@ -10,7 +10,7 @@ weights made from a seed:
                ``JAX_PLATFORMS`` and never falls back.
 1. train     — ``CausalLM.fit_backprop`` (``sharded_fit``'s scanned
                epoch), a cold call then a warmed call with equal shapes.
-2. serve     — the trained parameters through ``DecodeEngine(paged=True)``
+2. serve     — the trained parameters through ``DecodeEngine``
                and ``ContinuousBatcher``: warm-up, then requests spread
                over the bucket ladder, one checked against the unbatched
                ``gpt.generate``.
@@ -73,7 +73,7 @@ class Sizes(NamedTuple):
 
 #: GPT-2 small at full width, depth and length; batch is what fits one
 #: v5e chip beside the fp32 [8, 1023, 50257] logits.  The word2vec/GloVe
-#: shapes are bench.py's own (vocab 2000, dim 100, batch 16384 / 4096).
+#: shapes: vocab 2000, dim 100, batch 16384 / 4096.
 FULL = Sizes(batch=8, n_batches=4,
              prompt_lens=(16, 40, 100, 230, 480, 900, 300),
              ref_request=2, max_tokens=32, n_slots=8, flash_T=4096,
@@ -334,7 +334,7 @@ def phase_serve(cfg: Any, params: Any, sz: Sizes
     from deeplearning4j_tpu.serving.decode import (ContinuousBatcher,
                                                    DecodeEngine)
 
-    eng = DecodeEngine(cfg, params, n_slots=sz.n_slots, paged=True)
+    eng = DecodeEngine(cfg, params, n_slots=sz.n_slots)
     xla0 = xla_requests()
     warm = eng.warmup()
     say("serve", buckets=eng.buckets, page_tokens=eng.page_tokens,

@@ -72,8 +72,8 @@ DECODE_COUNTERS = ("moe_assignments", "moe_assignments_held",
 
 #: ``DecodeEngine`` options this family has no code for yet; the engine
 #: raises at construction rather than fall through to another family's
-UNSUPPORTED_ENGINE_OPTIONS = ("pinned", "mesh", "kv_dtype", "quantize",
-                              "draft", "prefix_cache")
+UNSUPPORTED_ENGINE_OPTIONS = ("mesh", "kv_dtype", "quantize", "draft",
+                              "prefix_cache")
 
 
 @dataclasses.dataclass(frozen=True)

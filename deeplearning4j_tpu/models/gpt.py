@@ -66,22 +66,6 @@ def shard_specs(cfg: TransformerConfig, model_degree: int = 1,
     return tfm.shard_specs(cfg, model_degree, pipe_degree)
 
 
-def slot_specs(cfg: TransformerConfig,
-               kv_dtype: Optional[str] = None) -> "DecodeSlots":  # jaxlint: disable=spec-without-divisibility-guard — degree-independent; DecodeEngine validates n_heads % model_degree before pinning these specs
-    """PartitionSpecs for ``DecodeSlots`` under a model-sharded decode
-    engine: the KV cache [L, S, T_max, NH, D] shards its HEAD axis over
-    ``model`` (each chip holds only its heads' cache — the serving-side
-    HBM win that lets a model bigger than one chip serve), tokens and
-    positions replicated (tiny, and every shard needs them).  int8 KV
-    adds replicated per-token-row scale specs (scales [L, S, T_max]
-    carry no head axis and cost 8 bytes per row)."""
-    h = P(None, None, None, MODEL_AXIS, None)
-    if kv_dtype == "int8":
-        return DecodeSlots(k=h, v=h, tokens=P(), pos=P(),
-                           k_scale=P(), v_scale=P())
-    return DecodeSlots(k=h, v=h, tokens=P(), pos=P())
-
-
 def lm_logits(cfg: TransformerConfig, params: PyTree, hidden: Array) -> Array:
     """Tied-embedding readout [B, T, H] -> [B, T, vocab]."""
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -362,7 +346,7 @@ def _prefill_chunk(cfg: TransformerConfig, params: PyTree, cache: KVCache,
 #: shape is ONE regardless of prompt length
 PREFILL_CHUNK = 32
 
-#: the leaves every serving step of this family (pinned, paged, verify,
+#: the leaves every serving step of this family (prefill, decode, verify,
 #: draft) reads ONLY through ``.astype(cfg.compute_dtype)``, by their
 #: keys from the root: ``DecodeEngine`` may hold them in that type, cast
 #: once per tree, and each step's cast is then no operation.  NOT
@@ -414,6 +398,14 @@ def sample_token(logits: Array, key: Array, temperature: Array) -> Array:
                      greedy).astype(jnp.int32)
 
 
+def _slot_key(seed: Array, pos: Array) -> Array:
+    """Per-(request, position) sampling key: deterministic for a given
+    request seed regardless of which slot or step the token lands on —
+    the property the continuous batcher's reproducibility rests on."""
+    return jax.random.fold_in(jax.random.fold_in(jax.random.key(0),
+                                                 seed), pos)
+
+
 def generate(cfg: TransformerConfig, params: PyTree, prompt: Array,
              n_tokens: int, key: Array, temperature: float = 1.0,
              max_len: Optional[int] = None,
@@ -453,270 +445,6 @@ def forward_logits(cfg: TransformerConfig, params: PyTree,
 
 
 # ---------------------------------------------------------------------------
-# Slot-structured decoding (continuous-batching serving substrate)
-# ---------------------------------------------------------------------------
-
-class DecodeSlots(NamedTuple):
-    """Persistent decode state for S concurrent sequences sharing one
-    fixed-shape executable (serving/decode.DecodeEngine owns one per
-    cache-length bucket and donates it to every dispatch):
-
-    - ``k``/``v``: slot-structured KV cache [L, S, T_max, NH, D];
-    - ``tokens`` [S] int32: each slot's CURRENT token — sampled last
-      step (or at prefill), not yet written to the cache;
-    - ``pos`` [S] int32: the position that token will occupy;
-    - ``k_scale``/``v_scale``: ``None`` for a full-precision cache, or
-      fp32 [L, S, T_max] per-token-row scales when ``k``/``v`` are int8
-      (``init_slots(kv_dtype="int8")``) — 4x the slots per byte vs
-      fp32, ~2x vs bf16, which is the per-chip concurrency the serving
-      tier buys with them.
-    """
-    k: Array
-    v: Array
-    tokens: Array
-    pos: Array
-    k_scale: Optional[Array] = None
-    v_scale: Optional[Array] = None
-
-
-def init_slots(cfg: TransformerConfig, n_slots: int,
-               max_len: Optional[int] = None,
-               kv_dtype: Optional[str] = None) -> DecodeSlots:
-    T = max_len or cfg.max_len
-    shape = (cfg.n_layers, n_slots, T, cfg.n_heads, cfg.head_dim)
-    idx = (jnp.zeros((n_slots,), jnp.int32), jnp.zeros((n_slots,), jnp.int32))
-    if kv_dtype is None:
-        cdt = jnp.dtype(cfg.compute_dtype)
-        return DecodeSlots(jnp.zeros(shape, cdt), jnp.zeros(shape, cdt),
-                           *idx)
-    if kv_dtype != "int8":
-        raise ValueError(f"kv_dtype must be None or 'int8': {kv_dtype!r}")
-    sshape = (cfg.n_layers, n_slots, T)
-    return DecodeSlots(jnp.zeros(shape, jnp.int8),
-                       jnp.zeros(shape, jnp.int8), *idx,
-                       k_scale=jnp.zeros(sshape, jnp.float32),
-                       v_scale=jnp.zeros(sshape, jnp.float32))
-
-
-def slots_bytes_per_slot(cfg: TransformerConfig, t_max: int,
-                         kv_dtype: Optional[str] = None) -> int:
-    """KV-cache bytes one slot of a ``t_max`` bucket costs — the
-    denominator of 'slots per chip' capacity planning (bench row
-    ``kv_bytes_per_slot``)."""
-    elems = cfg.n_layers * t_max * cfg.n_heads * cfg.head_dim
-    if kv_dtype == "int8":
-        return 2 * elems + 2 * cfg.n_layers * t_max * 4   # + scale rows
-    return 2 * elems * jnp.dtype(cfg.compute_dtype).itemsize
-
-
-def _slot_key(seed: Array, pos: Array) -> Array:
-    """Per-(request, position) sampling key: deterministic for a given
-    request seed regardless of which slot or step the token lands on —
-    the property the continuous batcher's reproducibility rests on."""
-    return jax.random.fold_in(jax.random.fold_in(jax.random.key(0),
-                                                 seed), pos)
-
-
-def slot_prefill(cfg: TransformerConfig, params: PyTree, slots: DecodeSlots,
-                 toks: Array, slot: Array, start: Array, n_valid: Array,
-                 temperature: Array, seed: Array
-                 ) -> Tuple[DecodeSlots, Array]:
-    """Prefill one chunk ``toks`` [C] of a prompt into ``slot`` at
-    positions ``start + [0, n_valid)`` (rows past ``n_valid`` are
-    padding) while the other slots' state rides along untouched — how a
-    new request joins a RUNNING batch without a barrier.  Returns
-    (slots', first_token): ``first_token`` is sampled from the logits at
-    the last valid position and is only meaningful for the final chunk
-    of a prompt (the caller then activates the slot with
-    ``tokens[slot]=first_token, pos[slot]=start+n_valid``, which this
-    function records)."""
-    L = cfg.n_layers
-    T_max = slots.k.shape[2]
-    quant = slots.k_scale is not None
-    k_slot = lax.dynamic_slice(
-        slots.k, (0, slot, 0, 0, 0),
-        (L, 1, T_max, cfg.n_heads, cfg.head_dim))
-    v_slot = lax.dynamic_slice(
-        slots.v, (0, slot, 0, 0, 0),
-        (L, 1, T_max, cfg.n_heads, cfg.head_dim))
-    if quant:
-        ks_slot = lax.dynamic_slice(slots.k_scale, (0, slot, 0),
-                                    (L, 1, T_max))
-        vs_slot = lax.dynamic_slice(slots.v_scale, (0, slot, 0),
-                                    (L, 1, T_max))
-        cache_in = QKVCache(k_slot, v_slot, ks_slot, vs_slot)
-    else:
-        cache_in = KVCache(k_slot, v_slot)
-    cache, logits = _prefill_chunk(cfg, params, cache_in,
-                                   toks[None, :], start)
-    last = lax.dynamic_slice_in_dim(logits[0], n_valid - 1, 1, axis=0)[0]
-    end = start + n_valid
-    first = sample_token(last, _slot_key(seed, end - 1), temperature)
-    return DecodeSlots(
-        lax.dynamic_update_slice(slots.k, cache.k, (0, slot, 0, 0, 0)),
-        lax.dynamic_update_slice(slots.v, cache.v, (0, slot, 0, 0, 0)),
-        slots.tokens.at[slot].set(first),
-        slots.pos.at[slot].set(end),
-        k_scale=lax.dynamic_update_slice(
-            slots.k_scale, cache.k_scale, (0, slot, 0)) if quant else None,
-        v_scale=lax.dynamic_update_slice(
-            slots.v_scale, cache.v_scale, (0, slot, 0)) if quant else None,
-    ), first
-
-
-def slot_decode(cfg: TransformerConfig, params: PyTree, slots: DecodeSlots,
-                active: Array, temperature: Array, seeds: Array
-                ) -> Tuple[DecodeSlots, Array]:
-    """Advance every ACTIVE slot by one token in ONE dispatch.
-
-    Each slot s feeds its current token at its own position ``pos[s]``:
-    K/V scatter at (s, pos[s]), attention over its prefix ``<= pos[s]``,
-    per-slot sampling (``temperature[s]``, key folded from ``seeds[s]``
-    and the position).  Inactive slots compute alongside (fixed shapes)
-    but neither their token nor their position changes; their cache
-    writes land at a position that is overwritten before it is ever
-    attended.  Returns (slots', tokens [S]) where ``tokens[s]`` is the
-    newly sampled token for active slots and the unchanged current token
-    for inactive ones."""
-    cdt = jnp.dtype(cfg.compute_dtype)
-    quant = slots.k_scale is not None
-    S = slots.tokens.shape[0]
-    T_max = slots.k.shape[2]
-    pos = slots.pos
-    e = params["embed"]
-    pos_c = jnp.clip(pos, 0, cfg.max_len - 1)
-    x = e["tok"][slots.tokens] + e["pos"][pos_c]              # [S, H]
-    x = tfm.layer_norm(x, e["ln_g"], e["ln_b"],
-                       cfg.layer_norm_eps)[:, None, :]        # [S, 1, H]
-
-    rows = jnp.arange(S)
-    valid = jnp.arange(T_max)[None, :] <= pos[:, None]        # [S, T_max]
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    blocks = params["blocks"]
-    for layer in range(cfg.n_layers):
-        p = jax.tree.map(lambda a, l=layer: a[l], blocks)
-        h = x.astype(cdt)
-        q = jnp.einsum("bth,hnd->btnd", h, p["wq"].astype(cdt),
-                       preferred_element_type=jnp.float32) + p["bq"]
-        k1 = jnp.einsum("bth,hnd->btnd", h, p["wk"].astype(cdt),
-                        preferred_element_type=jnp.float32) + p["bk"]
-        v1 = jnp.einsum("bth,hnd->btnd", h, p["wv"].astype(cdt),
-                        preferred_element_type=jnp.float32) + p["bv"]
-        # per-slot-position scatter (out-of-range positions drop)
-        if quant:
-            kq, ks = _kv_quant(k1[:, 0])            # [S,NH,D]i8, [S]
-            vq, vs = _kv_quant(v1[:, 0])
-            k_cache = slots.k[layer].at[rows, pos].set(kq, mode="drop")
-            v_cache = slots.v[layer].at[rows, pos].set(vq, mode="drop")
-            ks_cache = slots.k_scale[layer].at[rows, pos].set(
-                ks, mode="drop")
-            vs_cache = slots.v_scale[layer].at[rows, pos].set(
-                vs, mode="drop")
-            new_ks.append(ks_cache)
-            new_vs.append(vs_cache)
-            k_read = _kv_load(k_cache, ks_cache, cdt)
-            v_read = _kv_load(v_cache, vs_cache, cdt)
-        else:
-            k_cache = slots.k[layer].at[rows, pos].set(
-                k1[:, 0].astype(cdt), mode="drop")
-            v_cache = slots.v[layer].at[rows, pos].set(
-                v1[:, 0].astype(cdt), mode="drop")
-            k_read, v_read = k_cache, v_cache
-        new_k.append(k_cache)
-        new_v.append(v_cache)
-
-        with jax.named_scope("attention"):
-            scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
-            s = jnp.einsum("bqnd,bknd->bnqk", q.astype(cdt), k_read,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid[:, None, None, :], s, -1e9)
-            probs = jax.nn.softmax(s, axis=-1).astype(cdt)
-            a = jnp.einsum("bnqk,bknd->bqnd", probs, v_read,
-                           preferred_element_type=jnp.float32)
-        a = jnp.einsum("btnd,ndh->bth", a.astype(cdt), p["wo"].astype(cdt),
-                       preferred_element_type=jnp.float32) + p["bo"]
-        x = tfm.layer_norm(x + a, p["ln1_g"], p["ln1_b"], cfg.layer_norm_eps)
-
-        h = x.astype(cdt)
-        f = jnp.einsum("bth,hf->btf", h, p["w1"].astype(cdt),
-                       preferred_element_type=jnp.float32) + p["b1"]
-        f = jax.nn.gelu(f).astype(cdt)
-        f = jnp.einsum("btf,fh->bth", f, p["w2"].astype(cdt),
-                       preferred_element_type=jnp.float32) + p["b2"]
-        x = tfm.layer_norm(x + f, p["ln2_g"], p["ln2_b"], cfg.layer_norm_eps)
-
-    with jax.named_scope("readout"):
-        logits = lm_logits(cfg, params, x)[:, 0, :]           # [S, V]
-        keys = jax.vmap(_slot_key)(seeds, pos)
-        nxt = jax.vmap(sample_token)(logits, keys, temperature)
-    act = active.astype(jnp.int32)
-    return DecodeSlots(
-        jnp.stack(new_k), jnp.stack(new_v),
-        jnp.where(active, nxt, slots.tokens),
-        pos + act,
-        k_scale=jnp.stack(new_ks) if quant else None,
-        v_scale=jnp.stack(new_vs) if quant else None,
-    ), jnp.where(active, nxt, slots.tokens)
-
-
-def slot_read_pages(slots: DecodeSlots, slot: Array):
-    """Read one slot's full KV rows — ``(k, v)`` [L, T_max, NH, D]
-    (plus ``(k_scale, v_scale)`` [L, T_max] for an int8 cache) — for
-    the serving prefix store.  Pure read: the caller must NOT donate
-    ``slots`` into this one."""
-    L, S, T, NH, D = slots.k.shape
-    k = lax.dynamic_slice(slots.k, (0, slot, 0, 0, 0),
-                          (L, 1, T, NH, D))[:, 0]
-    v = lax.dynamic_slice(slots.v, (0, slot, 0, 0, 0),
-                          (L, 1, T, NH, D))[:, 0]
-    if slots.k_scale is None:
-        return k, v
-    ks = lax.dynamic_slice(slots.k_scale, (0, slot, 0), (L, 1, T))[:, 0]
-    vs = lax.dynamic_slice(slots.v_scale, (0, slot, 0), (L, 1, T))[:, 0]
-    return k, v, ks, vs
-
-
-def slot_write_pages(slots: DecodeSlots, slot: Array, k: Array, v: Array,
-                     k_scale: Optional[Array] = None,
-                     v_scale: Optional[Array] = None) -> DecodeSlots:
-    """Copy cached prefix KV pages (full-row [L, T_max, NH, D] arrays;
-    rows past the cached prefix are zeros) over ``slot`` — the prefix
-    HIT path.  Zero tail rows are safe for the same reason ``release``
-    needs no scrubbing: a row is only ever attended at positions ``<=
-    pos``, and every position up to ``pos`` is (re)written by the
-    remaining prefill chunks / decode steps before it is reached.
-    ``tokens``/``pos`` are untouched (the final prefill chunk sets
-    them)."""
-    sk = lax.dynamic_update_slice(slots.k, k[:, None], (0, slot, 0, 0, 0))
-    sv = lax.dynamic_update_slice(slots.v, v[:, None], (0, slot, 0, 0, 0))
-    if slots.k_scale is None:
-        return slots._replace(k=sk, v=sv)
-    return slots._replace(
-        k=sk, v=sv,
-        k_scale=lax.dynamic_update_slice(slots.k_scale, k_scale[:, None],
-                                         (0, slot, 0)),
-        v_scale=lax.dynamic_update_slice(slots.v_scale, v_scale[:, None],
-                                         (0, slot, 0)))
-
-
-def make_slot_fns(cfg: TransformerConfig):
-    """(prefill_fn, decode_fn, cache_key) for serving/decode.DecodeEngine:
-    positional signatures suitable for ``cached_jit`` with the slot
-    state donated.  The key captures everything that determines the
-    traced programs besides input shapes (the engine extends it with
-    its slot/bucket geometry)."""
-    def prefill_fn(params, slots, toks, slot, start, n_valid,
-                   temperature, seed):
-        return slot_prefill(cfg, params, slots, toks, slot, start,
-                            n_valid, temperature, seed)
-
-    def decode_fn(params, slots, active, temperature, seeds):
-        return slot_decode(cfg, params, slots, active, temperature, seeds)
-
-    return prefill_fn, decode_fn, ("gpt_slots", repr(cfg))
-
-
-# ---------------------------------------------------------------------------
 # Paged KV storage (serving tier 3)
 # ---------------------------------------------------------------------------
 
@@ -726,7 +454,7 @@ class PagedKV(NamedTuple):
     so a page is C contiguous lane-dense rows whatever NH and D are —
     [.., 20, 64] minors pad, and the TPU then lays the pool out pages
     -minor, which no page gather or row scatter can use in place).
-    A slot's cache row is no longer a pinned [T_max] slab: a host-side
+    A slot's cache row is not a [T_max] slab of its own: a host-side
     page table maps its chunk-aligned position ranges onto pool pages,
     so HBM holds only the pages live tokens occupy — 'slots per chip'
     is bounded by live tokens, not bucket length.  A dispatch never
@@ -759,22 +487,30 @@ def init_pages(cfg: TransformerConfig, n_pages: int, page_tokens: int,
 
 def pages_bytes(cfg: TransformerConfig, n_pages: int, page_tokens: int,
                 kv_dtype: Optional[str] = None) -> int:
-    """Persistent pool bytes — the paged engine's HBM denominator (what
-    a dispatch gathers for attention is one layer's pages of its slots
-    at a time, dispatch-transient)."""
+    """Persistent pool bytes, an int8 pool's scale rows included — the
+    engine's HBM denominator (what a dispatch gathers for attention is
+    one layer's pages of its slots at a time, dispatch-transient)."""
     elems = cfg.n_layers * n_pages * page_tokens * cfg.n_heads * cfg.head_dim
     if kv_dtype == "int8":
         return 2 * elems + 2 * cfg.n_layers * n_pages * page_tokens * 4
     return 2 * elems * jnp.dtype(cfg.compute_dtype).itemsize
 
 
+def slots_bytes_per_slot(cfg: TransformerConfig, t_max: int,
+                         kv_dtype: Optional[str] = None) -> int:
+    """KV-cache bytes one slot of a ``t_max`` bucket costs when every
+    page of it is live — the denominator of 'slots per chip' capacity
+    planning (``DecodeEngine.kv_bytes_per_slot``)."""
+    return pages_bytes(cfg, 1, t_max, kv_dtype)
+
+
 def paged_specs(cfg: TransformerConfig,
                 kv_dtype: Optional[str] = None) -> "PagedKV":  # jaxlint: disable=spec-without-divisibility-guard — degree-independent; DecodeEngine validates n_heads % model_degree before pinning these specs
     """PartitionSpecs for a model-sharded page pool: the NH*D minor
-    dimension over ``model`` in whole heads (same heads the pinned slot
-    cache shards), scales replicated.  Page gathers and row scatters
-    index the layer, page and offset axes only, so both stay
-    shard-local."""
+    dimension over ``model`` in whole heads (each chip holds only its
+    heads' cache, as it holds only their weights), scales replicated.
+    Page gathers and row scatters index the layer, page and offset axes
+    only, so both stay shard-local."""
     h = P(None, None, None, MODEL_AXIS)
     if kv_dtype == "int8":
         return PagedKV(k=h, v=h, k_scale=P(), v_scale=P())
@@ -826,7 +562,7 @@ def _rows_attention(q: Array, k: Array, v: Array, valid: Array) -> Array:
     over all 1280 lanes gives every head's scores — the terms it adds
     are exact zeros — and the value product's [NH*W, NH*D] result
     keeps its diagonal blocks.  Operands, accumulation and softmax are
-    those of :func:`slot_decode`; the NH-fold redundant MXU work is
+    those of :func:`_decode_step`; the NH-fold redundant MXU work is
     free beside the bytes."""
     S, W, NH, D = q.shape
     T = k.shape[1]
@@ -858,12 +594,12 @@ def _paged_stack(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
     ``pool.k[layer, ptab]`` → [S, TBL*C, NH*D], S x TBL whole pages
     gathered on the pool's two major axes, never a copy of it), and
     attention runs over ``<= posw`` (:func:`_rows_attention`) — the
-    arithmetic of :func:`slot_decode` / :func:`slot_verify`, row for
-    row.  Every read is of the pool as the write before it left it and
+    arithmetic of :func:`_decode_step`, row for row.  Every read is of
+    the pool as the write before it left it and
     feeds the write after it, so a donated pool is updated in place.
     Writes from inactive slots and out-of-range positions land in the
-    trash page (a freed page may ALREADY belong to another live slot —
-    unlike the pinned cache, a stale write is not harmless here); such
+    trash page (a freed page may ALREADY belong to another live slot,
+    so a stale write is not harmless); such
     a slot then attends without its fresh row, and its token is never
     taken."""
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -936,12 +672,14 @@ def _paged_stack(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
 def paged_prefill(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
                   ptab_s: Array, toks: Array, start: Array, n_valid: Array,
                   temperature: Array, seed: Array) -> Tuple[PagedKV, Array]:
-    """Paged analog of :func:`slot_prefill`: one chunk ``toks`` [C]
-    (C == the pool's page width — the engine aligns its prefill chunk
-    to the page size) into the slot whose page table is ``ptab_s``
-    [TBL], at chunk-aligned ``start``.  The chunk is exactly one page,
-    so persisting it is a single page write at ``ptab_s[start//C]``.
-    Returns (pool', first_token)."""
+    """Prefill one chunk ``toks`` [C] of a prompt (C == the pool's page
+    width — the engine aligns its prefill chunk to the page size) into
+    the slot whose page table is ``ptab_s`` [TBL], at chunk-aligned
+    ``start`` (rows past ``n_valid`` are padding); the other slots'
+    pages ride along untouched — how a request joins a RUNNING batch
+    without a barrier.  The chunk is exactly one page, so persisting it
+    is a single page write at ``ptab_s[start//C]``.  Returns (pool',
+    first_token sampled at the last valid row: the final chunk's counts)."""
     L, Pn, C, F = pool.k.shape
     NH, D = cfg.n_heads, cfg.head_dim
     TBL = ptab_s.shape[0]
@@ -986,14 +724,16 @@ def paged_decode(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
                  ptab: Array, tokens: Array, pos: Array, active: Array,
                  temperature: Array, seeds: Array
                  ) -> Tuple[PagedKV, Array]:
-    """Paged analog of :func:`slot_decode`, token for token.  A
-    dispatch READS, layer by layer, the pages the table names (S x TBL
-    pages of one layer at a time: one rung's rows, never the pool) and
-    WRITES each active slot's one new row of every layer into the
-    donated pool in place — an inactive slot's into the trash page.
-    ``tokens``/``pos`` are HOST-tracked in paged mode (the host knows
-    them deterministically from the fetched stream), so only the pool
-    is device state."""
+    """Advance every ACTIVE slot by one token in ONE dispatch: slot s feeds
+    ``tokens[s]`` at its own ``pos[s]``, attends over ``<= pos[s]`` and
+    samples at ``temperature[s]`` with a key folded from ``seeds[s]`` and
+    the position.  A dispatch READS, layer by layer, the pages the table
+    names (S x TBL pages of one layer at a time: one rung's rows, never
+    the pool) and WRITES each active slot's one new row of every layer
+    into the donated pool in place — an inactive slot's into the trash
+    page (it computes alongside, fixed shapes, and keeps its token).
+    ``tokens``/``pos`` are HOST-tracked (known from the fetched stream),
+    so only the pool is device state.  Returns (pool', tokens [S])."""
     pool, x = _paged_stack(cfg, params, pool, ptab, tokens[:, None],
                            pos[:, None], active)
     with jax.named_scope("readout"):
@@ -1038,120 +778,23 @@ def paged_write_pages(cfg: TransformerConfig, pool: PagedKV, pids: Array,
 # Speculative decoding (serving tier 3)
 # ---------------------------------------------------------------------------
 
-def slot_verify(cfg: TransformerConfig, params: PyTree, slots: DecodeSlots,
-                active: Array, temperature: Array, seeds: Array,
-                drafts: Array) -> Tuple[DecodeSlots, Array, Array]:
-    """Target-model verify: score every slot's current token plus its k
-    draft proposals — W = k+1 positions — in ONE batched dispatch.
-
-    Row w consumes the token at position ``pos+w`` (w=0 the current
-    token, w>=1 draft w-1) and yields the target's own sampling
-    decision t_w at key ``_slot_key(seed, pos+w)`` — the SAME key the
-    sequential path would use at that position, so the committed chain
-    is token-for-token the non-speculative chain for ANY temperature,
-    not just greedy.  Longest-accepted-prefix: with n_acc = leading
-    matches of t vs drafts, tokens t_0..t_{n_acc} commit (drafts
-    0..n_acc-1 were consumed with exactly the committed context; row
-    n_acc's logits are the target's next step after them).  K/V rows
-    past the accepted region hold rejected-token state — overwritten
-    before ever attended (pinned), or confined to the slot's own pages
-    (paged).  Returns (slots', t [S, W], n_commit [S]) with n_commit=0
-    for inactive slots."""
-    cdt = jnp.dtype(cfg.compute_dtype)
-    quant = slots.k_scale is not None
-    S = slots.tokens.shape[0]
-    T_max = slots.k.shape[2]
-    k_spec = drafts.shape[1]
-    W = k_spec + 1
-    pos = slots.pos
-    toks_w = jnp.concatenate([slots.tokens[:, None], drafts], axis=1)
-    posw = pos[:, None] + jnp.arange(W)                       # [S, W]
-    pos_c = jnp.clip(posw, 0, cfg.max_len - 1)
-    e = params["embed"]
-    x = e["tok"][toks_w] + e["pos"][pos_c]                    # [S, W, H]
-    x = tfm.layer_norm(x, e["ln_g"], e["ln_b"], cfg.layer_norm_eps)
-
-    rows = jnp.arange(S)[:, None]
-    valid = jnp.arange(T_max)[None, None, :] <= posw[:, :, None]
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    blocks = params["blocks"]
-    for layer in range(cfg.n_layers):
-        p = jax.tree.map(lambda a, l=layer: a[l], blocks)
-        h = x.astype(cdt)
-        q = jnp.einsum("bth,hnd->btnd", h, p["wq"].astype(cdt),
-                       preferred_element_type=jnp.float32) + p["bq"]
-        k1 = jnp.einsum("bth,hnd->btnd", h, p["wk"].astype(cdt),
-                        preferred_element_type=jnp.float32) + p["bk"]
-        v1 = jnp.einsum("bth,hnd->btnd", h, p["wv"].astype(cdt),
-                        preferred_element_type=jnp.float32) + p["bv"]
-        if quant:
-            kq, ks = _kv_quant(k1)                  # [S,W,NH,D]i8, [S,W]
-            vq, vs = _kv_quant(v1)
-            k_cache = slots.k[layer].at[rows, posw].set(kq, mode="drop")
-            v_cache = slots.v[layer].at[rows, posw].set(vq, mode="drop")
-            ks_cache = slots.k_scale[layer].at[rows, posw].set(
-                ks, mode="drop")
-            vs_cache = slots.v_scale[layer].at[rows, posw].set(
-                vs, mode="drop")
-            new_ks.append(ks_cache)
-            new_vs.append(vs_cache)
-            k_read = _kv_load(k_cache, ks_cache, cdt)
-            v_read = _kv_load(v_cache, vs_cache, cdt)
-        else:
-            k_cache = slots.k[layer].at[rows, posw].set(
-                k1.astype(cdt), mode="drop")
-            v_cache = slots.v[layer].at[rows, posw].set(
-                v1.astype(cdt), mode="drop")
-            k_read, v_read = k_cache, v_cache
-        new_k.append(k_cache)
-        new_v.append(v_cache)
-
-        scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
-        s = jnp.einsum("bqnd,bknd->bnqk", q.astype(cdt), k_read,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid[:, None, :, :], s, -1e9)
-        probs = jax.nn.softmax(s, axis=-1).astype(cdt)
-        a = jnp.einsum("bnqk,bknd->bqnd", probs, v_read,
-                       preferred_element_type=jnp.float32)
-        a = jnp.einsum("btnd,ndh->bth", a.astype(cdt), p["wo"].astype(cdt),
-                       preferred_element_type=jnp.float32) + p["bo"]
-        x = tfm.layer_norm(x + a, p["ln1_g"], p["ln1_b"], cfg.layer_norm_eps)
-
-        h = x.astype(cdt)
-        f = jnp.einsum("bth,hf->btf", h, p["w1"].astype(cdt),
-                       preferred_element_type=jnp.float32) + p["b1"]
-        f = jax.nn.gelu(f).astype(cdt)
-        f = jnp.einsum("btf,fh->bth", f, p["w2"].astype(cdt),
-                       preferred_element_type=jnp.float32) + p["b2"]
-        x = tfm.layer_norm(x + f, p["ln2_g"], p["ln2_b"], cfg.layer_norm_eps)
-
-    logits = lm_logits(cfg, params, x)                        # [S, W, V]
-    keys = jax.vmap(lambda sd, pw: jax.vmap(
-        lambda pp: _slot_key(sd, pp))(pw))(seeds, posw)       # [S, W]
-    t = jax.vmap(jax.vmap(sample_token, in_axes=(0, 0, None)))(
-        logits, keys, temperature)                            # [S, W]
-    matches = (t[:, :k_spec] == drafts).astype(jnp.int32)
-    n_acc = jnp.sum(jnp.cumprod(matches, axis=1), axis=1)     # [S]
-    n_commit = jnp.where(active, n_acc + 1, 0)
-    last = jnp.take_along_axis(t, n_acc[:, None], axis=1)[:, 0]
-    return DecodeSlots(
-        jnp.stack(new_k), jnp.stack(new_v),
-        jnp.where(active, last, slots.tokens),
-        pos + n_commit,
-        k_scale=jnp.stack(new_ks) if quant else None,
-        v_scale=jnp.stack(new_vs) if quant else None,
-    ), t, n_commit
-
-
 def paged_verify(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
                  ptab: Array, tokens: Array, pos: Array, active: Array,
                  temperature: Array, seeds: Array, drafts: Array
                  ) -> Tuple[PagedKV, Array, Array]:
-    """:func:`slot_verify` over a paged pool, token for token: the same
-    per-layer row write and page read as :func:`paged_decode` with
-    ``W = k + 1`` rows a slot (the engine pre-allocates pages through
-    ``pos + k`` so rejected rows stay within the slot's own pages).
-    Returns (pool', t [S, W], n_commit [S])."""
+    """Target-model verify: every slot's current token plus its k draft
+    proposals, W = k+1 positions, in ONE batched dispatch with the row
+    writes and page reads of :func:`paged_decode` (the engine allocates
+    pages through ``pos + k``, so rejected rows stay within the slot's
+    own pages).  Row w consumes the token at ``pos+w`` (w=0 the current
+    token, w>=1 draft w-1) and yields the target's own decision t_w at
+    key ``_slot_key(seed, pos+w)`` — the SAME key the sequential path
+    uses at that position, so the committed chain is token-for-token
+    the non-speculative chain at ANY temperature.  Longest accepted
+    prefix: with n_acc = leading matches of t vs drafts, t_0..t_{n_acc}
+    commit (drafts 0..n_acc-1 were consumed with exactly the committed
+    context; row n_acc's logits are the target's next step after them).
+    Returns (pool', t [S, W], n_commit [S]), n_commit=0 where inactive."""
     k_spec = drafts.shape[1]
     toks_w = jnp.concatenate([tokens[:, None], drafts], axis=1)
     posw = pos[:, None] + jnp.arange(k_spec + 1)              # [S, W]
@@ -1167,38 +810,19 @@ def paged_verify(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
     return pool, t, jnp.where(active, n_acc + 1, 0)
 
 
-def draft_propose(cfg_d: TransformerConfig, params_d: PyTree,
-                  dslots: DecodeSlots, active: Array,
-                  n_steps: int) -> Tuple[DecodeSlots, Array]:
-    """Draft-model proposal: k greedy single-token steps (a lax.scan of
-    :func:`slot_decode` at temperature 0) from the draft's mirror of
-    the committed stream.  The draft needs NO re-sync dispatch between
-    rounds: its rows at the accepted positions consumed exactly the
-    committed tokens (that is what acceptance means), so after the host
-    advances its tokens/pos to the commit frontier every row below it
-    is already correct.  Returns (dslots', proposals [S, k]) — the
-    proposals stay on device and feed straight into the verify
-    dispatch."""
-    S = dslots.tokens.shape[0]
-    zt = jnp.zeros((S,), jnp.float32)
-    zs = jnp.zeros((S,), jnp.uint32)
-
-    def body(s, _):
-        s, t = slot_decode(cfg_d, params_d, s, active, zt, zs)
-        return s, t
-
-    dslots, props = lax.scan(body, dslots, None, length=n_steps)
-    return dslots, jnp.moveaxis(props, 0, 1)
-
-
 def paged_draft_propose(cfg_d: TransformerConfig, params_d: PyTree,
                         dpool: PagedKV, ptab: Array, tokens: Array,
                         pos: Array, active: Array, n_steps: int
                         ) -> Tuple[PagedKV, Array]:
-    """:func:`draft_propose` over a paged draft pool sharing the
-    TARGET's page table (same positions, same page ids — one allocator
-    covers both pools): a lax.scan of k greedy :func:`paged_decode`
-    steps, each reading the pages the step before it wrote."""
+    """Draft-model proposal over a draft pool sharing the TARGET's page
+    table (same positions, same page ids — one allocator covers both
+    pools): a lax.scan of k greedy :func:`paged_decode` steps from the
+    committed frontier the host hands in, each reading the pages the
+    step before it wrote.  No re-sync dispatch between rounds: the
+    draft's rows at accepted positions consumed exactly the committed
+    tokens (that is what acceptance means), so every row below the
+    frontier is already correct.  Returns (dpool', proposals [S, k]
+    left on device for the verify dispatch)."""
     S = tokens.shape[0]
     zt = jnp.zeros((S,), jnp.float32)
     zs = jnp.zeros((S,), jnp.uint32)

@@ -413,8 +413,8 @@ def make_dp_stream_epoch(mesh, axis: str, n_shards: int, per: int, *,
     _scan_stream_epoch signature.
 
     ``average=False`` skips the pmean (shard-local updates; replicas
-    DIVERGE) — only for measuring the collective's share of epoch time
-    (bench.py's w2v-dp row), never for training."""
+    DIVERGE) — only for measuring the collective's share of epoch
+    time, never for training."""
     from deeplearning4j_tpu.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -948,8 +948,8 @@ def prepare_train_tables(cache, table_size: int):
     """Device-ready training tables from a built vocab: (codes_t,
     points_t, mask_t, unigram table, hs code lengths) — the Huffman
     hierarchical-softmax encoding plus the negative-sampling
-    distribution.  Shared by ``Word2Vec.fit`` and bench.py's w2v-dp row
-    so the bench times the EXACT tables training uses
+    distribution.  One function, so a measurement made outside
+    ``Word2Vec.fit`` times the EXACT tables training uses
     (InMemoryLookupTable syn1/expTable/negative-table construction role,
     InMemoryLookupTable.java:98-180)."""
     codes_np, points_np, lengths_t = encode_hs_tables(cache)

@@ -373,9 +373,7 @@ class ServingChaos:
     def exhaust_pages(self, leave: int = 0) -> None:
         """Arm a one-shot grab of the replica's free KV pages (leaving
         ``leave``), held by this injector: admissions stall, then shed
-        with the typed ``KVPagesExhausted``.  Paged engines only."""
-        if self.engine._alloc is None:
-            raise ValueError("exhaust_pages requires a paged engine")
+        with the typed ``KVPagesExhausted``."""
         if leave < 0:
             raise ValueError(f"leave must be >= 0: {leave}")
 

@@ -6,7 +6,7 @@ outside with JAX's own ``JAX_COMPILATION_CACHE_DIR``; when that is unset
 the cache lives at ``<checkout>/.jax_cache``.  The path is part of the
 cache key, so it is derived from this package's location and from
 nothing that moves between runs (home directory, temporary names, pids,
-the clock).  Entry points (``chip_smoke.py``, ``bench.py --inner``, the
+the clock).  Entry points (``chip_smoke.py``, ``benchmark/run.py``, the
 CLI) call it before their first compile; importing this package touches
 no JAX configuration.  The minimum compile time worth persisting is
 JAX's ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``.

@@ -209,7 +209,7 @@ def measured_crossover(head_dim: int, causal: bool,
 
 def _sync(x) -> float:
     """Force completion by fetching a value: a host read cannot return
-    before the device has produced it (same sync as bench.py's)."""
+    before the device has produced it."""
     return float(np.asarray(x).ravel()[0])
 
 

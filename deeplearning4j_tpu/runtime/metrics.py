@@ -838,8 +838,8 @@ checkpoint_metrics = CheckpointMetrics()
 
 
 #: published bf16 peak FLOP/s per chip by device_kind substring — the
-#: denominator of every MFU estimate (single source; bench.py and the
-#: autotuner both consult it here).  Source: Google Cloud TPU
+#: denominator of every MFU estimate (single source; the autotuner
+#: consults it here).  Source: Google Cloud TPU
 #: documentation, the per-version "System architecture" pages ("TPU
 #: v5e": 197 TFLOP/s bf16 per chip; v5p 459, v6e/Trillium 918, v4 275,
 #: v3 123, v2 45).  JAX reports a v5e chip as device_kind "TPU v5 lite".

@@ -33,8 +33,8 @@ Three levels of defense, cheapest first:
    results instead of averaging them into the global params.
 
 Every skip/rollback/reject increments ``runtime.metrics
-.resilience_metrics`` so soak runs and ``bench.py`` rows carry the
-fault-handling evidence.
+.resilience_metrics`` so soak runs carry the fault-handling
+evidence.
 """
 
 from __future__ import annotations

@@ -36,7 +36,7 @@ weight quantization itself):
 
 Serving tier 3 (live tokens, live weights, raw tokens/s):
 
-- ``DecodeEngine(paged=True, n_pages=)``: the KV cache becomes a pool
+- ``DecodeEngine(n_pages=)``: the KV cache is ONE pool
   of fixed-size pages (``KV_PAGE_TOKENS`` rows each) with per-slot
   page tables — slots/chip bounded by LIVE tokens, not bucket length;
   prefix hits mount pool-resident pages BY REFERENCE (refcounted

@@ -10,18 +10,19 @@ serving half of Gemma-on-TPU (arXiv:2605.25645) and TensorFlow's
 persistent-dataflow lesson (arXiv:1605.08695) both land on the same
 recipe, implemented here:
 
-- ``DecodeEngine`` owns a persistent slot-structured KV cache
-  ``[L, S, T_max, NH, D]`` per cache-length bucket (S = max concurrent
-  sequences, bucketed T_max ladder like PR 3's batch ladder) and ONE
-  jitted, donated decode-step executable per (conf, bucket) — compiled
-  through ``runtime/compile_cache.cached_jit`` — that advances ALL
-  occupied slots by one token per dispatch.
+- ``DecodeEngine`` owns ONE persistent pool of KV pages (see KV PAGES
+  below), S slots per cache-length bucket (S = max concurrent
+  sequences, bucketed T_max ladder like PR 3's batch ladder) each with
+  a host-side page table, and ONE jitted, donated decode-step
+  executable per (conf, bucket) — compiled through
+  ``runtime/compile_cache.cached_jit`` — that advances ALL occupied
+  slots by one token per dispatch.
 - New requests JOIN the running batch: the prompt is prefilled into a
   free slot with the chunked dense prefill executable (matmul-bound
-  slabs + ``lax.dynamic_update_slice`` into the live cache) between two
+  slabs, one page write a chunk into the live pool) between two
   decode steps — nobody waits for a cohort to finish.  Finished
-  sequences (EOS or token budget) free their slot mid-flight and the
-  next pending request takes it.
+  sequences (EOS or token budget) free their slot and pages mid-flight
+  and the next pending request takes them.
 - ``ContinuousBatcher`` is the front-end: a background worker owns the
   engine, streams tokens back per request (``DecodeRequest`` handles),
   books time-to-first-token and per-token latency into
@@ -31,7 +32,7 @@ A replicated front-end with load-shedding lives in
 ``serving/router.py``.  Steady state is compile-free: ``warmup()``
 pre-traces both executables for every bucket, after which any mix of
 prompt lengths, joins, and slot recycling dispatches only cached
-programs (asserted by the bench row and the telemetry gate).  The
+programs (asserted by tier-1 tests and the telemetry gate).  The
 worker/lock contract (engine driven by ONE thread, shared request
 state mutated only under its Condition, no blocking wait under a held
 lock) is machine-checked by jaxlint's concurrency family.
@@ -41,8 +42,8 @@ MODEL-SHARDED serving (the data×model tentpole's serving half): pass
 model_degree=N)`` builds one per device group) and the engine pins
 GSPMD shardings on both executables: params laid out per
 ``gpt.shard_specs`` (heads/MLP over ``model``, tied embedding over
-vocab) and the slot KV cache sharded over its HEAD axis
-(``gpt.slot_specs``), so each chip holds only its heads' weights and
+vocab) and the page pool sharded over its HEAD axis
+(``gpt.paged_specs``), so each chip holds only its heads' weights and
 cache — a model bigger than one chip's HBM serves from a group of
 chips, with per-chip param bytes ~1/model_degree of the replicated
 layout.  The engine key grows ``mesh_signature`` so two groups (or a
@@ -58,32 +59,34 @@ arXiv:2309.08918):
   decode programs, so steady state streams int8 weight bytes from HBM.
   Quantized executables are NEW compile-cache entries (the engine key
   includes the mode); accuracy deltas are asserted by the tier-1
-  numerics tests and the bench row.
-- ``kv_dtype="int8"``: slot KV cache stored int8 with per-token-row
-  scales riding ``DecodeSlots`` — ~4x (fp32) / ~2x (bf16) the slots
+  numerics tests.
+- ``kv_dtype="int8"``: KV pages stored int8 with per-token-row
+  scales riding ``gpt.PagedKV`` — ~4x (fp32) / ~2x (bf16) the slots
   per chip at equal cache-length bucket (``kv_bytes_per_slot`` gauge).
 - ``prefix_cache=``: a content-hashed :class:`PrefixCache` — requests
   sharing a chunk-aligned prompt prefix skip its re-prefill by copying
-  cached KV pages into their slot (``gpt.slot_write_pages``), the
+  cached KV pages into their slot's (``gpt.paged_write_pages``), the
   chunked-prefill substrate picking up at the first uncached chunk.
   Hits are BIT-exact vs cold prefill (the pages are exact copies) and
   never trace: the page read/write executables are pre-traced by
   ``warmup()`` like everything else.  The store assumes frozen params
   (the serving contract) — call ``clear()`` after a weight swap.
 
-PAGED KV (``paged=True``; ``gpt.PagedKV``): the slabs give way to ONE
-pool of ``KV_PAGE_TOKENS``-token pages, [L, P, C, NH*D], shared by
+KV PAGES (``gpt.PagedKV``; the one storage scheme — the slab a slot
+owned per rung, ``paged=False``, was removed in PR 30): ONE pool of
+``KV_PAGE_TOKENS``-token pages, [L, P, C, NH*D], sized by ``n_pages``
+(default: ``n_slots`` x the largest rung, + the trash page), shared by
 every rung and donated to every dispatch, and a host-side page table a
 rung.  A decode (or verify, or draft) dispatch of a rung reads, layer
 by layer, the S x TBL pages its table names — that rung's rows of one
 layer at a time, never the pool, never an all-layer view — and writes
 each active slot's fresh rows of that layer at (layer, page, offset),
 in place; an inactive or stalled slot's rows go to the trash page 0.
-A prefill dispatch reads one slot's pages and writes one page.  The
-pinned engine (``paged=False``: ``gpt.slot_*`` on ``DecodeSlots``)
-shares none of this plumbing: a slab a slot owns and pages behind a
-table are two storage schemes, chosen by ``paged`` and visible in the
-type of the state.
+A prefill dispatch reads one slot's pages and writes one page.
+HBM holds what live tokens occupy, so admission counts free pages as
+well as free slots, and a slot whose next page cannot be had STALLS a
+dispatch instead of failing (:class:`KVPagesExhausted` only when
+nothing can move).
 """
 
 from __future__ import annotations
@@ -189,17 +192,17 @@ def hold_in_compute_dtype(cfg, tree: Any, shardings: Any = None,
     return tree
 
 
-#: tokens per KV page — ONE constant shared by the paged allocator and
+#: tokens per KV page — ONE constant shared by the page allocator and
 #: the PrefixCache's chunk alignment (== gpt.PREFILL_CHUNK, drift-guarded
 #: by tests/test_serving_tier3.py): harvested prefix pages mount into
-#: paged slots without re-chunking, and every prefill chunk is exactly
+#: slots without re-chunking, and every prefill chunk is exactly
 #: one page write
 KV_PAGE_TOKENS = gpt.PREFILL_CHUNK
 
 
 class KVPagesExhausted(RuntimeError):
     """Typed page-pool exhaustion: an admit/extend needed more KV pages
-    than the paged engine's pool has free.  Admission gates on
+    than the engine's pool has free.  Admission gates on
     ``DecodeEngine.can_admit`` and in-flight slots STALL (retry next
     dispatch) before this is raised; it reaches a request only when the
     pool cannot make progress at all (deadlock breaker evicts the
@@ -237,7 +240,7 @@ class DeadlineExceeded(RuntimeError):
 
 
 class PageAllocator:
-    """Host-side refcounted free-list allocator over the paged engine's
+    """Host-side refcounted free-list allocator over the engine's
     pool ids.  Page 0 is RESERVED (the trash page inactive-slot writes
     are redirected into) and never handed out.  ``alloc`` is
     all-or-nothing (typed :class:`KVPagesExhausted` on shortfall),
@@ -477,40 +480,30 @@ class PrefixCache:
 
 
 class _Bucket:
-    """Host-side state for one cache-length bucket: the device slot
-    state plus the occupancy/sampling arrays the decode dispatch takes
-    each step."""
+    """Host-side state for one cache-length bucket: the page tables
+    plus the occupancy/sampling arrays the decode dispatch takes each
+    step (the pool is the only DEVICE state, and the engine's)."""
 
-    __slots__ = ("t_max", "slots", "active", "temps", "seeds", "owners",
+    __slots__ = ("t_max", "active", "temps", "seeds", "owners",
                  "ptab", "n_pages", "tokens_h", "pos_h", "ran")
 
-    def __init__(self, t_max: int, n_slots: int,
-                 page_tokens: Optional[int] = None):
+    def __init__(self, t_max: int, n_slots: int, page_tokens: int):
         self.t_max = t_max
-        self.slots = None                       # DecodeSlots, lazy-init
         self.active = np.zeros((n_slots,), np.bool_)
         self.temps = np.zeros((n_slots,), np.float32)
         self.seeds = np.zeros((n_slots,), np.uint32)
         self.owners: List[Any] = [None] * n_slots
-        # paged mode: per-slot page table (trash-id 0 in unused
-        # entries), allocated-page counts, and host mirrors of
-        # tokens/pos (deterministic from the fetched stream — the pool
-        # is the only per-bucket DEVICE state); ``ran`` is the last
-        # dispatch's progress mask (a slot stalls when its next page
-        # cannot be allocated)
+        # per-slot page table (trash-id 0 in unused entries),
+        # allocated-page counts, and host mirrors of tokens/pos
+        # (deterministic from the fetched stream: every dispatch, the
+        # draft's too, takes them); ``ran`` is the last dispatch's
+        # progress mask (a slot stalls when its next page cannot be
+        # allocated)
         self.ran = np.zeros((n_slots,), np.bool_)
-        # tokens_h/pos_h exist in EVERY mode: speculative decoding on a
-        # pinned engine also mirrors the committed stream host-side
-        # (the draft dispatch takes them — the draft's device tokens/pos
-        # are overwritten per round with the verified frontier)
         self.tokens_h = np.zeros((n_slots,), np.int32)
         self.pos_h = np.zeros((n_slots,), np.int32)
-        if page_tokens is not None:
-            tbl = t_max // page_tokens
-            self.ptab = np.zeros((n_slots, tbl), np.int32)
-            self.n_pages = np.zeros((n_slots,), np.int32)
-        else:
-            self.ptab = None
+        self.ptab = np.zeros((n_slots, t_max // page_tokens), np.int32)
+        self.n_pages = np.zeros((n_slots,), np.int32)
 
     def free_slot(self) -> Optional[int]:
         for i, o in enumerate(self.owners):
@@ -523,9 +516,9 @@ class _Bucket:
 
 
 class DecodeEngine:
-    """Slot-structured KV-cache decode engine for a causal LM.  The
-    model family is an argument, not an import: a paged engine takes
-    its pool and its two dispatches from the family of ``cfg``
+    """Slot-structured, page-pooled KV-cache decode engine for a causal
+    LM.  The model family is an argument, not an import: the engine
+    takes its pool and its two dispatches from the family of ``cfg``
     (:func:`model_family`: ``models/gpt.py``, ``models/deepseek_v2.py``)
     and holds, for each ``params`` tree it is given, the tree its
     executables take (``current_params()``), made once per tree: the
@@ -540,8 +533,8 @@ class DecodeEngine:
     caller keeps its own tree (the engine drops its reference to a
     static one).  The draft's tree is held the same way by its own
     config.  ``quantize`` takes this step's place where it is set.  The
-    pinned engine, the mesh, quantization, int8 pools, speculative
-    decoding and the prefix store are ``models/gpt.py``'s; a family
+    mesh, quantization, int8 pools, speculative decoding and the
+    prefix store are ``models/gpt.py``'s; a family
     that has none of them says so and the engine raises.  NOT
     thread-safe: exactly one thread (normally the
     ``ContinuousBatcher`` worker) may drive ``start``/``advance``/
@@ -550,9 +543,12 @@ class DecodeEngine:
     ``params`` may be the pytree or a zero-arg callable returning it
     (live-params convention shared with ``InferenceEngine``).  Both the
     prefill and the decode executables are built through the module
-    compile engine with the slot state DONATED, so the cache updates in
+    compile engine with the page pool DONATED, so the cache updates in
     place (no 2x HBM) and identically-configured replicas share one
-    compile per bucket.
+    compile per bucket.  ``n_pages`` sizes the pool (default: room for
+    ``n_slots`` sequences of the largest bucket, + the trash page);
+    ``paged`` selects nothing — ``True`` is the only value, kept until
+    the benchmark's callers stop passing it.
 
     Tier-2 knobs (see the module docstring): ``quantize`` post-training
     weight quantization (``"int8"``/``"bf16"``, computed once per
@@ -570,7 +566,7 @@ class DecodeEngine:
                  quantize: Optional[str] = None,
                  kv_dtype: Optional[str] = None,
                  prefix_cache: Any = None,
-                 paged: Any = False, n_pages: Optional[int] = None,
+                 paged: bool = True, n_pages: Optional[int] = None,
                  draft: Optional[Tuple[Any, Any]] = None,
                  draft_k: int = 4):
         if n_slots < 1:
@@ -579,10 +575,14 @@ class DecodeEngine:
         self._params = params
         self.mesh = mesh
         self.n_slots = int(n_slots)
-        self.paged = bool(paged) or n_pages is not None
+        if not paged:
+            raise ValueError(
+                "DecodeEngine(paged=False): the pinned slot engine was "
+                "removed in PR 30; every engine keeps its KV in the page "
+                "pool (n_pages sizes it)")
         fam = self._family = model_family(cfg)
         fam_name = fam.__name__.rsplit(".", 1)[-1]
-        asked = {"pinned": not self.paged, "mesh": mesh is not None,
+        asked = {"mesh": mesh is not None,
                  "kv_dtype": kv_dtype is not None,
                  "quantize": quantize is not None,
                  "draft": draft is not None,
@@ -620,8 +620,7 @@ class DecodeEngine:
         # are interchangeable (same conf, same quantization modes, same
         # params GENERATION — rebind_params bumps the generation, so a
         # freshly-swapped replica can never hit pages an old-params
-        # replica harvested into the shared store mid-swap; paged and
-        # pinned engines interop because the space is mode-free)
+        # replica harvested into the shared store mid-swap)
         self._params_gen = 0
         self._prefix_space = (repr(cfg), quantize, kv_dtype, 0)
         self._held_memo = qz.QuantMemo()
@@ -652,24 +651,20 @@ class DecodeEngine:
                 f"prefill_chunk must be >= 1: {self.prefill_chunk}")
         self.prefill_chunk = chunk
         self.label = label
-        # paged geometry: the page width IS the (gcd-shrunk) prefill
+        # page geometry: the page width IS the (gcd-shrunk) prefill
         # chunk, so every prefill chunk is exactly one page write and
         # chunk-aligned prefix pages mount page-aligned.  The pool
-        # defaults to pinned-equivalent capacity (+ the trash page);
-        # pass n_pages to shrink it — bounding HBM by live tokens is
-        # the point of the knob.
-        self.page_tokens = chunk if self.paged else None
-        self.n_kv_pages: Optional[int] = None
-        self._alloc: Optional[PageAllocator] = None
+        # defaults to room for n_slots sequences of the largest bucket
+        # (+ the trash page); pass n_pages to shrink it — bounding HBM
+        # by live tokens is the point of the knob.
+        self.page_tokens = chunk
+        default_pages = self.n_slots * (self.buckets[-1] // chunk) + 1
+        self.n_kv_pages = int(n_pages or default_pages)
+        self._alloc = PageAllocator(self.n_kv_pages)
         self._pool = None
         self._dpool = None
-        self._dslots: Dict[int, Any] = {}
         self._resident: "OrderedDict[bytes, Tuple[np.ndarray, Tuple[int, ...]]]" = OrderedDict()
-        if self.paged:
-            default_pages = self.n_slots * (self.buckets[-1] // chunk) + 1
-            self.n_kv_pages = int(n_pages or default_pages)
-            self._alloc = PageAllocator(self.n_kv_pages)
-            self._resident_max = max(self.n_kv_pages // 2, 1)
+        self._resident_max = max(self.n_kv_pages // 2, 1)
         cfg_d = None
         self._draft_cfg = self._draft_params = None
         if draft is not None:
@@ -686,32 +681,27 @@ class DecodeEngine:
             t: _Bucket(t, self.n_slots, self.page_tokens)
             for t in self.buckets}
         verify_fn = None
-        if self.paged:
-            key = (f"{fam_name}_slots", repr(cfg))
+        # the key captures everything that determines the traced
+        # programs besides input shapes (``geo`` below extends it with
+        # the slot/bucket geometry)
+        key = (f"{fam_name}_slots", repr(cfg))
 
-            def prefill_fn(params, pool, ptab_s, toks, start, n_valid,
-                           temperature, seed):
-                return fam.paged_prefill(cfg, params, pool, ptab_s, toks,
-                                         start, n_valid, temperature, seed)
+        def prefill_fn(params, pool, ptab_s, toks, start, n_valid,
+                       temperature, seed):
+            return fam.paged_prefill(cfg, params, pool, ptab_s, toks,
+                                     start, n_valid, temperature, seed)
 
-            def decode_fn(params, pool, ptab, tokens, pos, active,
-                          temperature, seeds):
-                return fam.paged_decode(cfg, params, pool, ptab, tokens,
-                                        pos, active, temperature, seeds)
+        def decode_fn(params, pool, ptab, tokens, pos, active,
+                      temperature, seeds):
+            return fam.paged_decode(cfg, params, pool, ptab, tokens,
+                                    pos, active, temperature, seeds)
 
-            if draft is not None:
-                def verify_fn(params, pool, ptab, tokens, pos, active,
-                              temperature, seeds, drafts):
-                    return gpt.paged_verify(cfg, params, pool, ptab,
-                                            tokens, pos, active,
-                                            temperature, seeds, drafts)
-        else:
-            prefill_fn, decode_fn, key = gpt.make_slot_fns(cfg)
-            if draft is not None:
-                def verify_fn(params, slots, active, temperature, seeds,
-                              drafts):
-                    return gpt.slot_verify(cfg, params, slots, active,
-                                           temperature, seeds, drafts)
+        if draft is not None:
+            def verify_fn(params, pool, ptab, tokens, pos, active,
+                          temperature, seeds, drafts):
+                return gpt.paged_verify(cfg, params, pool, ptab,
+                                        tokens, pos, active,
+                                        temperature, seeds, drafts)
         if self.quantize is not None:
             # dequant fused INTO the jitted programs: the executables
             # take the quantized tree and stream int8 bytes from HBM.
@@ -741,8 +731,7 @@ class DecodeEngine:
         # key their own entries — a dequant-fused program must never be
         # served to a full-precision engine or vice versa
         geo = (self.n_slots, self.prefill_chunk, mesh_signature(mesh),
-               self.quantize, self.kv_dtype,
-               ("paged", self.n_kv_pages) if self.paged else None,
+               self.quantize, self.kv_dtype, ("paged", self.n_kv_pages),
                (repr(cfg_d), self.draft_k) if draft is not None else None)
         shard_kw_prefill: Dict[str, Any] = {}
         shard_kw_decode: Dict[str, Any] = {}
@@ -751,11 +740,9 @@ class DecodeEngine:
         shard_kw_verify: Dict[str, Any] = {}
         shard_kw_draft: Dict[str, Any] = {}
         shard_kw_dprefill: Dict[str, Any] = {}
-        self._slot_shardings = None
         self._param_shardings = None
         self._pool_shardings = None
         self._dpool_shardings = None
-        self._dslot_shardings = None
         self._draft_shardings = None
         if mesh is not None:
             from deeplearning4j_tpu.parallel.sharded_fit import \
@@ -765,8 +752,8 @@ class DecodeEngine:
             if cfg.n_heads % m_deg:
                 raise ValueError(
                     f"n_heads={cfg.n_heads} not divisible by model "
-                    f"degree {m_deg}: the slot KV cache shards over "
-                    f"heads (gpt.slot_specs)")
+                    f"degree {m_deg}: the KV page pool shards over "
+                    f"heads (gpt.paged_specs)")
             pspecs = gpt.shard_specs(cfg, model_degree=m_deg)
             if self.quantize is not None:
                 # int8 leaves keep the fp32 layout; per-channel scales
@@ -776,62 +763,33 @@ class DecodeEngine:
             psh = named_shardings(mesh, pspecs)
             repl = NamedSharding(mesh, P())
             self._param_shardings = psh
-            if self.paged:
-                poolsh = named_shardings(
-                    mesh, fam.paged_specs(cfg, self.kv_dtype))
-                self._pool_shardings = poolsh
-                # paged_prefill(params, pool, ptab_s, toks, start,
-                # n_valid, temp, seed) / paged_decode(params, pool,
-                # ptab, tokens, pos, active, temps, seeds): only params
-                # and the pool carry a layout
-                shard_kw_prefill = dict(
-                    in_shardings=(psh, poolsh) + (repl,) * 6,
-                    out_shardings=(poolsh, repl))
-                shard_kw_decode = dict(
-                    in_shardings=(psh, poolsh) + (repl,) * 6,
-                    out_shardings=(poolsh, repl))
-                # prefix pages [L, TBL, C, NH, D] shard over heads like
-                # the pool rows they copy; int8 scale pages replicated
-                page_sh = (NamedSharding(
-                    mesh, P(None, None, None, MODEL_AXIS, None)),) * 2
-                if self.kv_dtype == "int8":
-                    page_sh = page_sh + (repl, repl)
-                shard_kw_read = dict(in_shardings=(poolsh, repl),
-                                     out_shardings=page_sh)
-                shard_kw_write = dict(in_shardings=(poolsh, repl) + page_sh,
-                                      out_shardings=poolsh)
-                if draft is not None:
-                    shard_kw_verify = dict(
-                        in_shardings=(psh, poolsh) + (repl,) * 7,
-                        out_shardings=(poolsh, repl, repl))
-            else:
-                ssh = named_shardings(
-                    mesh, gpt.slot_specs(cfg, self.kv_dtype))
-                self._slot_shardings = ssh
-                # prefill(params, slots, toks, slot, start, n_valid,
-                # temp, seed) / decode(params, slots, active, temps,
-                # seeds): only params and the slot state carry a layout
-                shard_kw_prefill = dict(
-                    in_shardings=(psh, ssh) + (repl,) * 6,
-                    out_shardings=(ssh, repl))
-                shard_kw_decode = dict(
-                    in_shardings=(psh, ssh) + (repl,) * 3,
-                    out_shardings=(ssh, repl))
-                # prefix pages [L, T_max, NH, D] shard over heads like
-                # the cache rows they copy; int8 scale pages replicated
-                page_sh = (NamedSharding(mesh, P(None, None, MODEL_AXIS,
-                                                 None)),) * 2
-                if self.kv_dtype == "int8":
-                    page_sh = page_sh + (repl, repl)
-                shard_kw_read = dict(in_shardings=(ssh, repl),
-                                     out_shardings=page_sh)
-                shard_kw_write = dict(in_shardings=(ssh, repl) + page_sh,
-                                      out_shardings=ssh)
-                if draft is not None:
-                    shard_kw_verify = dict(
-                        in_shardings=(psh, ssh) + (repl,) * 4,
-                        out_shardings=(ssh, repl, repl))
+            poolsh = named_shardings(
+                mesh, fam.paged_specs(cfg, self.kv_dtype))
+            self._pool_shardings = poolsh
+            # paged_prefill(params, pool, ptab_s, toks, start,
+            # n_valid, temp, seed) / paged_decode(params, pool,
+            # ptab, tokens, pos, active, temps, seeds): only params
+            # and the pool carry a layout
+            shard_kw_prefill = dict(
+                in_shardings=(psh, poolsh) + (repl,) * 6,
+                out_shardings=(poolsh, repl))
+            shard_kw_decode = dict(
+                in_shardings=(psh, poolsh) + (repl,) * 6,
+                out_shardings=(poolsh, repl))
+            # prefix pages [L, TBL, C, NH, D] shard over heads like
+            # the pool rows they copy; int8 scale pages replicated
+            page_sh = (NamedSharding(
+                mesh, P(None, None, None, MODEL_AXIS, None)),) * 2
+            if self.kv_dtype == "int8":
+                page_sh = page_sh + (repl, repl)
+            shard_kw_read = dict(in_shardings=(poolsh, repl),
+                                 out_shardings=page_sh)
+            shard_kw_write = dict(in_shardings=(poolsh, repl) + page_sh,
+                                  out_shardings=poolsh)
             if draft is not None:
+                shard_kw_verify = dict(
+                    in_shardings=(psh, poolsh) + (repl,) * 7,
+                    out_shardings=(poolsh, repl, repl))
                 if cfg_d.n_heads % m_deg:
                     raise ValueError(
                         f"draft n_heads={cfg_d.n_heads} not divisible "
@@ -840,26 +798,15 @@ class DecodeEngine:
                 dpsh = named_shardings(
                     mesh, gpt.shard_specs(cfg_d, model_degree=m_deg))
                 self._draft_shardings = dpsh
-                if self.paged:
-                    dpoolsh = named_shardings(
-                        mesh, gpt.paged_specs(cfg_d, self.kv_dtype))
-                    self._dpool_shardings = dpoolsh
-                    shard_kw_draft = dict(
-                        in_shardings=(dpsh, dpoolsh) + (repl,) * 4,
-                        out_shardings=(dpoolsh, repl))
-                    shard_kw_dprefill = dict(
-                        in_shardings=(dpsh, dpoolsh) + (repl,) * 4,
-                        out_shardings=dpoolsh)
-                else:
-                    dssh = named_shardings(
-                        mesh, gpt.slot_specs(cfg_d, self.kv_dtype))
-                    self._dslot_shardings = dssh
-                    shard_kw_draft = dict(
-                        in_shardings=(dpsh, dssh, repl),
-                        out_shardings=(dssh, repl))
-                    shard_kw_dprefill = dict(
-                        in_shardings=(dpsh, dssh) + (repl,) * 4,
-                        out_shardings=dssh)
+                dpoolsh = named_shardings(
+                    mesh, gpt.paged_specs(cfg_d, self.kv_dtype))
+                self._dpool_shardings = dpoolsh
+                shard_kw_draft = dict(
+                    in_shardings=(dpsh, dpoolsh) + (repl,) * 4,
+                    out_shardings=(dpoolsh, repl))
+                shard_kw_dprefill = dict(
+                    in_shardings=(dpsh, dpoolsh) + (repl,) * 4,
+                    out_shardings=dpoolsh)
         if draft is not None:
             self._draft_params = self._hold_draft(self._draft_params)
         self._prefill = compile_cache.cached_jit(
@@ -873,29 +820,18 @@ class DecodeEngine:
         self._verify = self._draft_fn = self._draft_prefill = None
         if draft is not None:
             k_steps = self.draft_k
-            if self.paged:
-                def draft_fn(params_d, dpool, ptab, tokens, pos, active):
-                    return gpt.paged_draft_propose(
-                        cfg_d, params_d, dpool, ptab, tokens, pos,
-                        active, k_steps)
 
-                def draft_prefill_fn(params_d, dpool, ptab_s, toks,
-                                     start, n_valid):
-                    p, _ = gpt.paged_prefill(
-                        cfg_d, params_d, dpool, ptab_s, toks, start,
-                        n_valid, jnp.float32(0.0), jnp.uint32(0))
-                    return p
-            else:
-                def draft_fn(params_d, dslots, active):
-                    return gpt.draft_propose(cfg_d, params_d, dslots,
-                                             active, k_steps)
+            def draft_fn(params_d, dpool, ptab, tokens, pos, active):
+                return gpt.paged_draft_propose(
+                    cfg_d, params_d, dpool, ptab, tokens, pos,
+                    active, k_steps)
 
-                def draft_prefill_fn(params_d, dslots, toks, slot,
-                                     start, n_valid):
-                    s, _ = gpt.slot_prefill(
-                        cfg_d, params_d, dslots, toks, slot, start,
-                        n_valid, jnp.float32(0.0), jnp.uint32(0))
-                    return s
+            def draft_prefill_fn(params_d, dpool, ptab_s, toks,
+                                 start, n_valid):
+                p, _ = gpt.paged_prefill(
+                    cfg_d, params_d, dpool, ptab_s, toks, start,
+                    n_valid, jnp.float32(0.0), jnp.uint32(0))
+                return p
             self._verify = compile_cache.cached_jit(
                 verify_fn, key=(key, geo, "verify"),
                 label=f"{label}.verify", donate_argnums=(1,),
@@ -910,44 +846,32 @@ class DecodeEngine:
                 **shard_kw_dprefill)
         self._read = self._write = None
         if self._prefix is not None:
-            if self.paged:
-                def read_fn(pool, pids):
-                    return fam.paged_read_pages(cfg, pool, pids)
+            def read_fn(pool, pids):
+                return fam.paged_read_pages(cfg, pool, pids)
 
-                def write_fn(pool, pids, *pages):
-                    return fam.paged_write_pages(cfg, pool, pids, *pages)
+            def write_fn(pool, pids, *pages):
+                return fam.paged_write_pages(cfg, pool, pids, *pages)
 
-                self._read = compile_cache.cached_jit(
-                    read_fn, key=(key, geo, "prefix_read"),
-                    label=f"{label}.prefix_read", **shard_kw_read)
-                self._write = compile_cache.cached_jit(
-                    write_fn, key=(key, geo, "prefix_write"),
-                    label=f"{label}.prefix_write", donate_argnums=(0,),
-                    **shard_kw_write)
-            else:
-                self._read = compile_cache.cached_jit(
-                    gpt.slot_read_pages, key=(key, geo, "prefix_read"),
-                    label=f"{label}.prefix_read", **shard_kw_read)
-                self._write = compile_cache.cached_jit(
-                    gpt.slot_write_pages, key=(key, geo, "prefix_write"),
-                    label=f"{label}.prefix_write", donate_argnums=(0,),
-                    **shard_kw_write)
+            self._read = compile_cache.cached_jit(
+                read_fn, key=(key, geo, "prefix_read"),
+                label=f"{label}.prefix_read", **shard_kw_read)
+            self._write = compile_cache.cached_jit(
+                write_fn, key=(key, geo, "prefix_write"),
+                label=f"{label}.prefix_write", donate_argnums=(0,),
+                **shard_kw_write)
         #: KV bytes one slot of the largest bucket costs — the 'slots
         #: per chip' capacity denominator (int8 KV is the ~4x/2x lever)
         self.kv_bytes_per_slot = int(fam.slots_bytes_per_slot(
             cfg, self.buckets[-1], self.kv_dtype))
         decode_metrics.note_kv_bytes_per_slot(self.kv_bytes_per_slot)
-        #: total paged-pool HBM (target + draft pools) — the paged
-        #: capacity denominator: slots/chip at a given HBM budget is
-        #: bounded by live tokens, not bucket length
-        self.pool_bytes = 0
-        if self.paged:
-            self.pool_bytes = int(fam.pages_bytes(
-                cfg, self.n_kv_pages, self.page_tokens, self.kv_dtype))
-            if draft is not None:
-                self.pool_bytes += int(gpt.pages_bytes(
-                    cfg_d, self.n_kv_pages, self.page_tokens,
-                    self.kv_dtype))
+        #: total pool HBM (target + draft pools) — the capacity
+        #: denominator: slots/chip at a given HBM budget is bounded by
+        #: live tokens, not bucket length
+        self.pool_bytes = int(fam.pages_bytes(
+            cfg, self.n_kv_pages, self.page_tokens, self.kv_dtype))
+        if draft is not None:
+            self.pool_bytes += int(gpt.pages_bytes(
+                cfg_d, self.n_kv_pages, self.page_tokens, self.kv_dtype))
         # prefix harvesting is ASYNC: the page read dispatches on the
         # serving thread (cheap), but the device->host transfer +
         # store insert run on a harvest worker so they never stall the
@@ -1036,18 +960,6 @@ class DecodeEngine:
     def active_buckets(self) -> List[int]:
         return [t for t, b in self._buckets.items() if b.n_active()]
 
-    def _state(self, b: _Bucket):
-        if b.slots is None:
-            slots = gpt.init_slots(self.cfg, self.n_slots, b.t_max,
-                                   kv_dtype=self.kv_dtype)
-            if self._slot_shardings is not None:
-                # scatter the fresh cache into its head-sharded layout
-                # up front: the first donated dispatch then aliases the
-                # shards in place instead of resharding
-                slots = jax.device_put(slots, self._slot_shardings)
-            b.slots = slots
-        return b.slots
-
     def _pool_state(self):
         """Lazily materialize the page pool(s) — ONE pool shared by
         every bucket (page shape is bucket-independent; only the page
@@ -1069,44 +981,27 @@ class DecodeEngine:
             self._dpool = dpool
         return self._pool
 
-    def _dslots_state(self, b: _Bucket):
-        """Pinned-mode draft KV slots, one state per bucket (mirrors
-        ``_state`` for the draft model)."""
-        d = self._dslots.get(b.t_max)
-        if d is None:
-            d = gpt.init_slots(self._draft_cfg, self.n_slots, b.t_max,
-                               kv_dtype=self.kv_dtype)
-            if self._dslot_shardings is not None:
-                d = jax.device_put(d, self._dslot_shardings)
-            self._dslots[b.t_max] = d
-        return d
-
     def _live_rows(self) -> int:
-        """Token rows currently live across all paged slots — the
+        """Token rows currently live across all slots — the
         page_utilization numerator."""
         return int(sum(int(bb.pos_h[bb.active].sum())
                        for bb in self._buckets.values()))
 
-    # -- paged admission / page tables -------------------------------------
+    # -- admission / page tables -------------------------------------------
     def can_admit(self, bucket: int, prompt_len: int) -> bool:
         """Room for a request in ``bucket`` RIGHT NOW?  Slot
-        availability plus, for a paged engine, enough free pages for
-        the prompt and its first decode page.  In-flight growth past
-        that STALLS rather than deadlocks, so admission only gates on
-        the prompt floor."""
+        availability plus enough free pages for the prompt and its
+        first decode page.  In-flight growth past that STALLS rather
+        than deadlocks, so admission only gates on the prompt floor."""
         if self._buckets[bucket].free_slot() is None:
             return False
-        if not self.paged:
-            return True
         C = self.page_tokens
         needed = -(-prompt_len // C) + 1
         return self._alloc.n_free() >= needed
 
     def check_capacity(self, prompt_len: int) -> None:
         """Raise the typed error when a prompt alone can NEVER fit the
-        pool — the sync-validate path for oversize paged admits."""
-        if not self.paged:
-            return
+        pool — the sync-validate path for oversize admits."""
         C = self.page_tokens
         needed = -(-prompt_len // C) + 1
         total = self.n_kv_pages - self._alloc.n_reserved
@@ -1115,9 +1010,8 @@ class DecodeEngine:
 
     def last_ran(self, bucket: int) -> np.ndarray:
         """[S] mask of slots the last advance/advance_spec actually
-        moved — paged slots can STALL on page exhaustion (their token
-        output is stale and must be ignored); pinned engines always run
-        every active slot."""
+        moved — a slot can STALL on page exhaustion (its token output
+        is stale and must be ignored)."""
         return self._buckets[bucket].ran.copy()
 
     def _ensure_pages(self, b: _Bucket, span: int) -> np.ndarray:
@@ -1162,7 +1056,7 @@ class DecodeEngine:
         decode_metrics.note_pages_leaked(self.pages_unaccounted())
 
     def _drop_pool(self) -> None:
-        """Poison-reset after a failed paged dispatch: the pool was
+        """Poison-reset after a failed dispatch: the pool was
         donated into the failure, so it re-initializes to ZEROS on the
         next ``_pool_state``.  The resident-prefix registry must flush
         WITH it — its entries reference page ids whose KV bytes no
@@ -1170,18 +1064,13 @@ class DecodeEngine:
         zeroed cache rows as silently wrong tokens."""
         self._pool = None
         self._dpool = None
-        for _, (_, ids) in self._resident.items():
-            self._alloc.free(ids)
-        self._resident.clear()
-        decode_metrics.note_pages_leaked(self.pages_unaccounted())
+        self.drop_residents()
 
     def pages_unaccounted(self) -> int:
         """Allocator page references not explained by any live slot's
         page table or the resident-prefix registry — nonzero means a
         reclaim path leaked (exported as the ``pages_leaked`` gauge,
         asserted zero by the chaos drill after drain)."""
-        if not self.paged:
-            return 0
         accounted = sum(int(bb.n_pages.sum())
                         for bb in self._buckets.values())
         accounted += sum(len(ids) for _, ids in self._resident.values())
@@ -1276,10 +1165,7 @@ class DecodeEngine:
             if self._draft_cfg is None:
                 raise ValueError("engine built without draft=")
             self._draft_params = self._hold_draft(draft_params)
-        if self.paged:
-            for _, (_, ids) in self._resident.items():
-                self._alloc.free(ids)
-            self._resident.clear()
+        self.drop_residents()
 
     # -- prefix harvesting -------------------------------------------------
     def _ensure_harvester(self) -> None:
@@ -1297,21 +1183,19 @@ class DecodeEngine:
                 try:
                     if item is None:
                         return
-                    pages, prefix, chunk, paged = item
+                    pages, prefix, chunk = item
                     # the read executable's outputs are fresh buffers
-                    # — independent of the slot state later dispatches
+                    # — independent of the pool later dispatches
                     # donate — so fetching them here cannot race the
-                    # serving thread.  A PAGED read comes back
+                    # serving thread.  A read comes back
                     # [L, TBL, C, ...]; flatten to the store's row
-                    # format so paged and pinned engines sharing the
-                    # store serve each other's harvests.
+                    # format [L, m, ...], which knows no page width.
                     host = []
                     for p in pages:
                         a = np.asarray(p)  # jaxlint: disable=host-sync-on-serving-worker — the harvest worker EXISTS to absorb this fetch off the decode thread
-                        if paged:
-                            a = a.reshape(
-                                (a.shape[0], a.shape[1] * a.shape[2])
-                                + a.shape[3:])
+                        a = a.reshape(
+                            (a.shape[0], a.shape[1] * a.shape[2])
+                            + a.shape[3:])
                         host.append(a[:, :prefix.size])
                     store.insert(prefix, tuple(host), chunk, space)
                 except Exception:   # noqa: BLE001 — opportunistic path
@@ -1346,24 +1230,8 @@ class DecodeEngine:
             t.join()
         self._harvest_thread = None
 
-    @staticmethod
-    def _pad_pages(pages: Sequence[np.ndarray], t_max: int):
-        """Zero-pad stored prefix pages [L, m, ...] up to the target
-        bucket's full row length [L, t_max, ...] (host-side: the write
-        executable takes ONE shape per bucket, so a fresh hit length
-        never costs a trace)."""
-        out = []
-        for p in pages:
-            if p.shape[1] == t_max:
-                out.append(np.ascontiguousarray(p))
-            else:
-                buf = np.zeros((p.shape[0], t_max) + p.shape[2:], p.dtype)
-                buf[:, :p.shape[1]] = p
-                out.append(buf)
-        return out
-
     def _pad_pool_pages(self, pages: Sequence[np.ndarray], b: _Bucket):
-        """Re-chunk stored prefix rows [L, m, ...] into the paged write
+        """Re-chunk stored prefix rows [L, m, ...] into the write
         executable's fixed page format [L, TBL, C, ...] (host-side; pad
         pages land in the trash page, so one shape per bucket — a fresh
         hit length never costs a trace)."""
@@ -1383,8 +1251,8 @@ class DecodeEngine:
     def warmup(self) -> dict:
         """Pre-trace the prefill + decode executables for every bucket
         (AOT; plus the prefix page read/write pair when a prefix store
-        is attached — a HIT must never trace), then reset the slot
-        state — steady-state traffic after this is compile-free for any
+        is attached — a HIT must never trace), then reset the pool
+        — steady-state traffic after this is compile-free for any
         prompt length / join / prefix-reuse pattern.  Returns
         {"buckets": n, "compiles": traces, "warmup_ms": wall}."""
         from deeplearning4j_tpu.runtime.metrics import compile_metrics
@@ -1405,68 +1273,43 @@ class DecodeEngine:
             for t in self.buckets:
                 b = self._buckets[t]
                 toks = np.zeros((self.prefill_chunk,), np.int32)
-                if self.paged:
-                    # all warmup dispatches run with ZERO page tables
-                    # and all-inactive masks: every write lands in the
-                    # trash page, the allocator is untouched, and the
-                    # pool is dropped afterwards anyway
-                    pool = self._pool_state()
-                    ptab_s = np.zeros((b.ptab.shape[1],), np.int32)
-                    pool, _ = self._prefill(
-                        params, pool, ptab_s, toks, np.int32(0),
-                        np.int32(1), np.float32(0.0), np.uint32(0))
-                    self._pool = pool
-                    if self._prefix is not None:
-                        pages = self._read(pool, ptab_s)
-                        self._pool = pool = self._write(pool, ptab_s,
-                                                        *pages)
-                    if self.draft is not None:
-                        self._dpool = self._draft_prefill(
-                            self._draft_params, self._dpool, ptab_s,
-                            toks, np.int32(0), np.int32(1))
-                        self._dpool, props = self._draft_fn(
-                            self._draft_params, self._dpool,
-                            b.ptab.copy(), b.tokens_h.copy(),
-                            b.pos_h.copy(), b.active.copy())
-                        pool, _, _ = self._verify(
-                            params, pool, b.ptab.copy(),
-                            b.tokens_h.copy(), b.pos_h.copy(),
-                            b.active.copy(), b.temps, b.seeds, props)
-                        self._pool = pool
-                    pool, out = self._decode(
-                        params, self._pool, b.ptab.copy(),
+                # all warmup dispatches run with ZERO page tables
+                # and all-inactive masks: every write lands in the
+                # trash page, the allocator is untouched, and the
+                # pool is dropped afterwards anyway
+                pool = self._pool_state()
+                ptab_s = np.zeros((b.ptab.shape[1],), np.int32)
+                pool, _ = self._prefill(
+                    params, pool, ptab_s, toks, np.int32(0),
+                    np.int32(1), np.float32(0.0), np.uint32(0))
+                self._pool = pool
+                if self._prefix is not None:
+                    pages = self._read(pool, ptab_s)
+                    self._pool = pool = self._write(pool, ptab_s,
+                                                    *pages)
+                if self.draft is not None:
+                    self._dpool = self._draft_prefill(
+                        self._draft_params, self._dpool, ptab_s,
+                        toks, np.int32(0), np.int32(1))
+                    self._dpool, props = self._draft_fn(
+                        self._draft_params, self._dpool,
+                        b.ptab.copy(), b.tokens_h.copy(),
+                        b.pos_h.copy(), b.active.copy())
+                    pool, _, _ = self._verify(
+                        params, pool, b.ptab.copy(),
                         b.tokens_h.copy(), b.pos_h.copy(),
-                        b.active.copy(), b.temps, b.seeds)
+                        b.active.copy(), b.temps, b.seeds, props)
                     self._pool = pool
-                    jax.block_until_ready(out)
-                else:
-                    slots = self._state(b)
-                    slots, _ = self._prefill(
-                        params, slots, toks, np.int32(0), np.int32(0),
-                        np.int32(1), np.float32(0.0), np.uint32(0))
-                    if self._prefix is not None:
-                        pages = self._read(slots, np.int32(0))
-                        slots = self._write(slots, np.int32(0), *pages)
-                    if self.draft is not None:
-                        dsl = self._dslots_state(b)
-                        dsl = self._draft_prefill(
-                            self._draft_params, dsl, toks, np.int32(0),
-                            np.int32(0), np.int32(1))
-                        dsl, props = self._draft_fn(
-                            self._draft_params, dsl, b.active.copy())
-                        slots, _, _ = self._verify(
-                            params, slots, b.active.copy(), b.temps,
-                            b.seeds, props)
-                        self._dslots.pop(b.t_max, None)
-                    slots, out = self._decode(
-                        params, slots, b.active.copy(), b.temps, b.seeds)
-                    jax.block_until_ready(out)
-                b.slots = None                  # fresh state for serving
+                pool, out = self._decode(
+                    params, self._pool, b.ptab.copy(),
+                    b.tokens_h.copy(), b.pos_h.copy(),
+                    b.active.copy(), b.temps, b.seeds)
+                self._pool = pool
+                jax.block_until_ready(out)
             # warmup scribbled on the shared pools; re-init lazily so
             # serving starts from zeros
             self._pool = None
             self._dpool = None
-            self._dslots.clear()
         wall_ms = (time.perf_counter() - t0) * 1e3
         compiles = sum(
             compile_metrics.snapshot()["traces"].get(k, 0) for k in labels
@@ -1483,9 +1326,7 @@ class DecodeEngine:
         reader that wants device time by scope joins the two on the
         instruction.  Traces and lowers the step again (the executable
         comes from the compile cache): for set-up, never for the
-        serving thread.  Paged engines only."""
-        if not self.paged:
-            raise ValueError("decode_hlo reads a paged engine's step")
+        serving thread."""
         b = self._buckets[bucket]
         return self._decode.jitted.lower(
             self.current_params(), self._pool_state(), b.ptab, b.tokens_h,
@@ -1511,114 +1352,7 @@ class DecodeEngine:
         slot = b.free_slot()
         if slot is None:
             raise RuntimeError(f"no free slot in bucket {bucket}")
-        start = self._start_paged if self.paged else self._start_pinned
-        first_tok = start(prompt, b, bucket, slot, temperature, seed,
-                          getattr(owner, "rid", None))
-        b.tokens_h[slot] = first_tok
-        b.pos_h[slot] = prompt.size
-        b.active[slot] = True
-        b.temps[slot] = np.float32(temperature)
-        b.seeds[slot] = np.uint32(seed)
-        b.owners[slot] = owner
-        return bucket, slot, first_tok
-
-    def _start_pinned(self, prompt: np.ndarray, b: _Bucket, bucket: int,
-                      slot: int, temperature: float, seed: int,
-                      rid: Optional[int]) -> int:
-        params = self.current_params()
-        slots = self._state(b)
-        C = self.prefill_chunk
-        n_chunks = -(-prompt.size // C)
-        hit_len, pages = 0, None
-        if self._prefix is not None:
-            hit = self._prefix.lookup(prompt, C, self._prefix_space)
-            if hit is not None:
-                hit_len, pages = hit
-        with telemetry.span("decode.prefill",
-                            counter=(decode_metrics, "prefill_s"),
-                            rid=rid, bucket=bucket, slot=slot,
-                            prompt_tokens=int(prompt.size),
-                            chunks=n_chunks, prefix_hit_tokens=hit_len):
-            first = None
-            try:
-                if hit_len:
-                    # copy the cached pages over the slot's rows (zero
-                    # tail past the prefix — see slot_write_pages) and
-                    # pick chunked prefill up at the first uncached
-                    # chunk: the hit skips hit_len positions of prefill
-                    # compute and is bit-exact vs running them
-                    slots = self._write(slots, np.int32(slot),
-                                        *self._pad_pages(pages, b.t_max))
-                for c in range(hit_len // C, n_chunks):
-                    lo = c * C
-                    n_valid = min(C, prompt.size - lo)
-                    chunk = np.zeros((C,), np.int32)
-                    chunk[:n_valid] = prompt[lo:lo + n_valid]
-                    slots, first = self._prefill(
-                        params, slots, chunk, np.int32(slot),
-                        np.int32(lo), np.int32(n_valid),
-                        np.float32(temperature), np.uint32(seed))
-            except Exception:
-                # the state was donated into the failed dispatch — drop
-                # it so the bucket re-initializes instead of serving a
-                # deleted buffer
-                b.slots = None
-                raise
-            b.slots = slots
-            if self.draft is not None:
-                # the draft model prefills the WHOLE prompt (its KV has
-                # no prefix store — it is tiny; re-running its chunks
-                # costs a sliver of the target compute the hit saved)
-                dsl = self._dslots_state(b)
-                try:
-                    for c in range(n_chunks):
-                        lo = c * C
-                        n_valid = min(C, prompt.size - lo)
-                        chunk = np.zeros((C,), np.int32)
-                        chunk[:n_valid] = prompt[lo:lo + n_valid]
-                        dsl = self._draft_prefill(
-                            self._draft_params, dsl, chunk,
-                            np.int32(slot), np.int32(lo),
-                            np.int32(n_valid))
-                except Exception:
-                    self._dslots.pop(b.t_max, None)
-                    raise
-                self._dslots[b.t_max] = dsl
-            with telemetry.span("decode.prefill.sync",
-                                counter=(decode_metrics, "prefill_sync_s")):
-                first_tok = int(first)          # join-time sync, once
-        decode_metrics.note_prefill(n_chunks - hit_len // C)
-        if self._prefix is not None:
-            if hit_len:
-                decode_metrics.note_prefix_hit(hit_len)
-                telemetry.event("decode.prefix_hit", bucket=bucket,
-                                slot=slot, tokens_saved=hit_len)
-            else:
-                decode_metrics.note_prefix_miss()
-            m_store = C * ((prompt.size - 1) // C)
-            if m_store > hit_len and m_store >= C \
-                    and self.harvest_enabled:
-                # harvest this prompt's chunk-aligned prefix for later
-                # requests — also on PARTIAL hits, or a growing
-                # conversation would hit only its first turn's prefix
-                # and re-prefill the extension forever.  The page read
-                # dispatches here (pure read — the live slot state is
-                # untouched; its outputs are fresh buffers), but the
-                # device->host fetch + insert run on the harvest
-                # worker so in-flight decode latency never stalls on
-                # the transfer.
-                full = self._read(slots, np.int32(slot))
-                self._ensure_harvester()
-                try:
-                    self._harvest_q.put_nowait(
-                        (full, prompt[:m_store].copy(), C, False))
-                except queue.Full:
-                    pass            # backpressure: drop, opportunistic
-        return first_tok
-
-    def _start_paged(self, prompt: np.ndarray, b: _Bucket, bucket: int,
-                     slot: int, temperature: float, seed: int,
-                     rid: Optional[int]) -> int:
+        rid = getattr(owner, "rid", None)
         self.check_capacity(prompt.size)
         params = self.current_params()
         pool = self._pool_state()
@@ -1627,8 +1361,8 @@ class DecodeEngine:
         tbl = b.ptab.shape[1]
         # prefix reuse, best first: (1) pool-RESIDENT pages mount into
         # the page table BY REFERENCE — no copy, no dispatch; (2) the
-        # host PrefixCache (shared across replicas, paged or pinned)
-        # copies pages into freshly-allocated pool pages
+        # host PrefixCache (shared across replicas) copies pages into
+        # freshly-allocated pool pages
         hit_len, hit_ids = self._resident_lookup(prompt)
         resident_hit = hit_len > 0
         host_pages = None
@@ -1691,7 +1425,7 @@ class DecodeEngine:
                             np.int32(n_valid))
             except Exception:
                 # the pool was donated into the failed dispatch — every
-                # paged bucket's KV is gone; drop it so serving
+                # bucket's KV is gone; drop it so serving
                 # re-initializes instead of touching deleted buffers.
                 # FIRST return this slot's page-table references
                 # (resident-hit shares AND fresh pages) to the
@@ -1725,11 +1459,17 @@ class DecodeEngine:
                 self._ensure_harvester()
                 try:
                     self._harvest_q.put_nowait(
-                        (full, prompt[:m_store].copy(), C, True))
+                        (full, prompt[:m_store].copy(), C))
                 except queue.Full:
                     pass            # backpressure: drop, opportunistic
         decode_metrics.note_pages(self._alloc.in_use(), 0, 0)
-        return first_tok
+        b.tokens_h[slot] = first_tok
+        b.pos_h[slot] = prompt.size
+        b.active[slot] = True
+        b.temps[slot] = np.float32(temperature)
+        b.seeds[slot] = np.uint32(seed)
+        b.owners[slot] = owner
+        return bucket, slot, first_tok
 
     def advance(self, bucket: int) -> np.ndarray:
         """One decode dispatch for ``bucket``: every active slot emits
@@ -1742,53 +1482,33 @@ class DecodeEngine:
                             counter=(decode_metrics, "advance_s"),
                             bucket=bucket, active=n_act):
             params = self.current_params()
-            if self.paged:
-                return self._advance_paged(b, params)
             with telemetry.span("decode.stage"):
-                slots = self._state(b)
-                b.ran = b.active.copy()
-                active = b.active.copy()
+                run = self._ensure_pages(b, 0)
+                b.ran = run
+                pool = self._pool_state()
+                ptab, tokens, pos = (b.ptab.copy(), b.tokens_h.copy(),
+                                     b.pos_h.copy())
             with telemetry.span("decode.dispatch"):
                 try:
-                    slots, out = self._decode(params, slots, active,
-                                              b.temps, b.seeds)
+                    pool, out = self._decode(params, pool, ptab, tokens,
+                                             pos, run, b.temps, b.seeds)
                 except Exception:
-                    b.slots = None              # donated into the failure
+                    self._drop_pool()           # donated into the failure
                     raise
-                b.slots = slots
+                self._pool = pool
             toks = self._fetch(out)
-            b.tokens_h[b.ran] = toks[b.ran]
-            b.pos_h[b.ran] += 1
-            decode_metrics.note_decode_dispatch(n_act, self.n_slots)
+            if self._decode_counters:
+                # the family's counts came back behind the S tokens, in
+                # the one fetch a step makes
+                toks, counts = toks[:self.n_slots], toks[self.n_slots:]
+                decode_metrics.note_family_counts(self._decode_counters,
+                                                  counts)
+            b.tokens_h[run] = toks[run]
+            b.pos_h[run] += 1
+            decode_metrics.note_decode_dispatch(int(run.sum()), self.n_slots)
+            decode_metrics.note_pages(self._alloc.in_use(),
+                                      self._live_rows(), self.page_tokens)
             return toks
-
-    def _advance_paged(self, b: _Bucket, params: Any) -> np.ndarray:
-        with telemetry.span("decode.stage"):
-            run = self._ensure_pages(b, 0)
-            b.ran = run
-            pool = self._pool_state()
-            ptab, tokens, pos = (b.ptab.copy(), b.tokens_h.copy(),
-                                 b.pos_h.copy())
-        with telemetry.span("decode.dispatch"):
-            try:
-                pool, out = self._decode(params, pool, ptab, tokens, pos,
-                                         run, b.temps, b.seeds)
-            except Exception:
-                self._drop_pool()               # donated into the failure
-                raise
-            self._pool = pool
-        toks = self._fetch(out)
-        if self._decode_counters:
-            # the family's counts came back behind the S tokens, in the
-            # one fetch a step makes
-            toks, counts = toks[:self.n_slots], toks[self.n_slots:]
-            decode_metrics.note_family_counts(self._decode_counters, counts)
-        b.tokens_h[run] = toks[run]
-        b.pos_h[run] += 1
-        decode_metrics.note_decode_dispatch(int(run.sum()), self.n_slots)
-        decode_metrics.note_pages(self._alloc.in_use(), self._live_rows(),
-                                  self.page_tokens)
-        return toks
 
     @staticmethod
     def _fetch(out: Any) -> np.ndarray:
@@ -1818,50 +1538,28 @@ class DecodeEngine:
                             counter=(decode_metrics, "advance_s"),
                             bucket=bucket, active=b.n_active(), k=k):
             params = self.current_params()
-            if self.paged:
-                with telemetry.span("decode.stage"):
-                    run = self._ensure_pages(b, k)
-                    b.ran = run
-                    pool = self._pool_state()
-                    ptab, tokens, pos = (b.ptab.copy(), b.tokens_h.copy(),
-                                         b.pos_h.copy())
-                with telemetry.span("decode.dispatch"):
-                    try:
-                        self._dpool, props = self._draft_fn(
-                            self._draft_params, self._dpool, ptab, tokens,
-                            pos, run)
-                        pool, out, n_commit = self._verify(
-                            params, pool, ptab, tokens, pos, run,
-                            b.temps, b.seeds, props)
-                    except Exception:
-                        self._drop_pool()
-                        raise
-                    self._pool = pool
-            else:
-                with telemetry.span("decode.stage"):
-                    run = b.active.copy()
-                    b.ran = run
-                    slots = self._state(b)
-                    dsl = self._dslots_state(b)
-                    # the draft's device tokens/pos are overwritten with
-                    # the verified frontier: rows below it hold exactly
-                    # the committed tokens' KV (accepted proposals
-                    # consumed them), so no re-sync dispatch is ever
-                    # needed
-                    dsl = dsl._replace(tokens=b.tokens_h.copy(),
-                                       pos=b.pos_h.copy())
-                with telemetry.span("decode.dispatch"):
-                    try:
-                        dsl, props = self._draft_fn(self._draft_params,
-                                                    dsl, run)
-                        self._dslots[b.t_max] = dsl
-                        slots, out, n_commit = self._verify(
-                            params, slots, run, b.temps, b.seeds, props)
-                    except Exception:
-                        b.slots = None
-                        self._dslots.pop(b.t_max, None)
-                        raise
-                    b.slots = slots
+            with telemetry.span("decode.stage"):
+                run = self._ensure_pages(b, k)
+                b.ran = run
+                pool = self._pool_state()
+                # the draft is handed the verified frontier: its rows
+                # below it hold exactly the committed tokens' KV
+                # (accepted proposals consumed them), so no re-sync
+                # dispatch is ever needed
+                ptab, tokens, pos = (b.ptab.copy(), b.tokens_h.copy(),
+                                     b.pos_h.copy())
+            with telemetry.span("decode.dispatch"):
+                try:
+                    self._dpool, props = self._draft_fn(
+                        self._draft_params, self._dpool, ptab, tokens,
+                        pos, run)
+                    pool, out, n_commit = self._verify(
+                        params, pool, ptab, tokens, pos, run,
+                        b.temps, b.seeds, props)
+                except Exception:
+                    self._drop_pool()
+                    raise
+                self._pool = pool
             # the committed tokens, and their counts on the same
             # round-trip (the proposals never land)
             toks = self._fetch(out)
@@ -1873,23 +1571,20 @@ class DecodeEngine:
             decode_metrics.note_decode_dispatch(n_run, self.n_slots)
             decode_metrics.note_spec(k * n_run,
                                      int(np.maximum(n_c - 1, 0).sum()))
-            if self.paged:
-                decode_metrics.note_pages(self._alloc.in_use(),
-                                          self._live_rows(),
-                                          self.page_tokens)
+            decode_metrics.note_pages(self._alloc.in_use(),
+                                      self._live_rows(), self.page_tokens)
             return toks, n_c
 
     def release(self, bucket: int, slot: int) -> None:
-        """Free a finished slot — the cache rows need no scrubbing: a
-        future occupant prefills its prompt over them and decode never
-        attends past its own position.  A paged slot also returns its
-        page-table references to the allocator (pool-resident prefix
-        pages survive: the registry holds its own reference)."""
+        """Free a finished slot and return its page-table references
+        to the allocator (pool-resident prefix pages survive: the
+        registry holds its own reference) — the cache rows need no
+        scrubbing: a future occupant of a page prefills its prompt over
+        them and decode never attends past its own position."""
         b = self._buckets[bucket]
         b.active[slot] = False
         b.owners[slot] = None
-        if self.paged:
-            self._release_pages(b, slot)
+        self._release_pages(b, slot)
 
 
 class DecodeRequest:
@@ -2142,7 +1837,7 @@ class ContinuousBatcher:
             raise ValueError(f"deadline_ms must be > 0: {deadline_ms}")
         max_tokens = int(max_tokens or self.default_max_tokens)
         self.engine.pick_bucket(prompt.size + max_tokens)  # sync validate
-        self.engine.check_capacity(prompt.size)  # typed paged oversize
+        self.engine.check_capacity(prompt.size)  # typed oversize admit
         req = DecodeRequest(prompt, max_tokens, float(temperature),
                             int(seed), eos_id, deadline_ms=deadline_ms)
         with self._cv:
@@ -2318,7 +2013,7 @@ class ContinuousBatcher:
                 else:
                     toks = self.engine.advance(bucket)
             except KVPagesExhausted as e:
-                # paged deadlock breaker: the pool cannot advance ANY
+                # page deadlock breaker: the pool cannot advance ANY
                 # slot in this bucket — evict the named victim (typed
                 # error to its client; its pages free the others)
                 if e.slot is None:
@@ -2331,10 +2026,9 @@ class ContinuousBatcher:
                 continue
             except Exception as e:
                 # a failed dispatch poisons in-flight device state (it
-                # was donated): a PINNED bucket's slots die alone, but
-                # a PAGED failure drops the shared pool — EVERY paged
-                # bucket's KV is gone, not just this one's.  Free the
-                # affected slots (the page reclaim is host-side
+                # was donated): the failure drops the shared pool, so
+                # EVERY bucket's KV is gone, not just this one's.  Free
+                # every slot (the page reclaim is host-side
                 # bookkeeping and stays valid) and REPLAY the requests
                 # instead of dooming them: re-admitted as (prompt +
                 # emitted), each continues bit-identically.  Past the
@@ -2342,10 +2036,8 @@ class ContinuousBatcher:
                 # deterministic dispatch bug must not requeue forever.
                 self.dispatch_error_streak += 1
                 with self._cv:
-                    affected = [(k, r) for k, r in self._placed.items()
-                                if self.engine.paged or k[0] == bucket]
-                    for k, _ in affected:
-                        self._placed.pop(k, None)
+                    affected = list(self._placed.items())
+                    self._placed.clear()
                 replay = []
                 for (bk, slot), r in affected:
                     self.engine.release(bk, slot)

@@ -178,7 +178,13 @@ class Router:
         ``n_replicas`` exceeds the group count; ``n_replicas=None``
         defaults to one replica per group.  ``model_degree=1`` keeps
         the original per-device placement byte-for-byte (groups of one
-        device).  MIGRATION.md documents the signature change."""
+        device).  MIGRATION.md documents the signature change.
+
+        A replica's engine is the default ``DecodeEngine``: it admits
+        by free PAGES as well as free slots (the default pool is
+        ``n_slots`` x the largest rung, shared by all rungs, where the
+        pinned engine removed in PR 30 held a slab per slot per
+        rung)."""
         from deeplearning4j_tpu.models import gpt
         from deeplearning4j_tpu.parallel.mesh import MeshSpec, make_mesh
         from deeplearning4j_tpu.parallel.sharded_fit import named_shardings

@@ -20,7 +20,7 @@ from deeplearning4j_tpu.models import gpt
 from deeplearning4j_tpu.models.transformer import TransformerConfig
 from deeplearning4j_tpu.runtime.metrics import decode_metrics
 from deeplearning4j_tpu.serving.decode import (ContinuousBatcher,
-                                               DecodeEngine,
+                                               DecodeEngine, PageAllocator,
                                                default_length_buckets)
 from deeplearning4j_tpu.serving.router import OverloadedError, Router
 
@@ -35,11 +35,20 @@ def params():
     return gpt.init_params(jax.random.key(7), CFG)
 
 
+def _every_page_is_back(eng):
+    """Nothing in flight holds a page and no reclaim path leaked one:
+    with the resident-prefix registry dropped, the pool is all free."""
+    eng.drop_residents()
+    return (eng.n_active() == 0
+            and eng._alloc.in_use() + eng.pages_unaccounted() == 0)
+
+
 @pytest.fixture(scope="module")
 def engine(params):
     eng = DecodeEngine(CFG, params, n_slots=4, buckets=(32, 64))
     eng.warmup()
-    return eng
+    yield eng
+    assert _every_page_is_back(eng)
 
 
 def _solo(params, prompt, n_tokens):
@@ -69,6 +78,21 @@ def test_bucket_chunk_divisibility(params):
     assert eng.prefill_chunk == 16
     with pytest.raises(ValueError, match="exceeds the model"):
         DecodeEngine(CFG, params, buckets=(128,))
+
+
+def test_paged_selects_nothing_and_false_names_its_removal(params):
+    """One KV storage scheme: the default engine IS the page-pooled one
+    (``paged=True`` builds the same engine on the same compile-cache
+    entries), and asking for the pinned slot engine says where it
+    went."""
+    with pytest.raises(ValueError, match="removed in PR 30"):
+        DecodeEngine(CFG, params, paged=False)
+    kw = dict(n_slots=3, buckets=(32,), prefill_chunk=16)
+    a = DecodeEngine(CFG, params, **kw)
+    b = DecodeEngine(CFG, params, paged=True, **kw)
+    assert isinstance(a._alloc, PageAllocator)
+    assert a.n_kv_pages == b.n_kv_pages == 3 * (32 // 16) + 1
+    assert b._prefill is a._prefill and b._decode is a._decode
 
 
 # -- chunked dense prefill --------------------------------------------------
@@ -282,6 +306,28 @@ def test_router_load_shed(params, engine):
     assert decode_metrics.snapshot()["requests_shed"] == shed_before + 1
 
 
+def test_router_replicate_serves_the_measured_engine(params):
+    """``Router.replicate`` builds the engine the benchmark's cells
+    measure: replicas with a page pool, greedy streams equal to the
+    unbatched ``generate`` on both rungs, every page back after
+    ``close()``."""
+    router = Router.replicate(CFG, params, 2, n_slots=2, buckets=(32, 64),
+                              prefill_chunk=8)
+    rng = np.random.RandomState(12)
+    # the third prompt spans five pages and lands on the 64 rung
+    prompts = [rng.randint(1, CFG.vocab_size, size=n).astype(np.int32)
+               for n in (5, 19, 40)]
+    with router:
+        handles = [router.submit(p, max_tokens=8) for p in prompts]
+        outs = [h.result(120) for h in handles]
+    for p, out in zip(prompts, outs):
+        np.testing.assert_array_equal(out, _solo(params, p, 8))
+    assert len(router.batchers) == 2
+    for b in router.batchers:
+        assert isinstance(b.engine._alloc, PageAllocator)
+        assert _every_page_is_back(b.engine)
+
+
 def test_router_validation():
     with pytest.raises(ValueError):
         Router([], max_queue_depth=4)
@@ -329,3 +375,20 @@ def test_close_drains_accepted_requests(params, engine):
     assert h.result(1).shape == (10,)        # ran to completion
     with pytest.raises(RuntimeError, match="closed"):
         cb.submit(rng.randint(1, 64, size=5))
+
+
+# -- the shared engine, last ------------------------------------------------
+
+def test_shared_engine_has_every_page_back(params, engine):
+    """Whatever this module's tests put through the shared default
+    engine before this one (joins, EOS recycling, sampled placements,
+    routers, 16 concurrent clients, a draining close), and a prompt of
+    two pages after them: no slot is left active and no page is held
+    or unaccounted for.  The fixture asserts the same at teardown."""
+    prompt = np.arange(1, 41, dtype=np.int32)
+    with ContinuousBatcher(engine, default_max_tokens=6) as cb:
+        out = cb.submit(prompt).result(120)
+    np.testing.assert_array_equal(out, _solo(params, prompt, 6))
+    assert engine._resident                  # the prompt's first page
+    assert _every_page_is_back(engine)
+    assert engine._alloc.n_free() == engine.n_kv_pages - 1

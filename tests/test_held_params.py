@@ -6,8 +6,8 @@ tree, everything else the given arrays.
 The load-bearing properties:
 
 - with float32 masters under a bfloat16 compute type, every engine path
-  (paged and pinned, prefill and decode, greedy and sampled, verify with
-  a draft) emits EXACTLY the tokens, and leaves exactly the KV state,
+  (bfloat16 and int8 pools, prefill and decode, greedy and sampled,
+  verify with a draft) emits EXACTLY the tokens, and leaves exactly the KV state,
   that the family's functions give on the raw tree: the cast is the one
   the steps made themselves, made once and kept;
 - only the named leaves change type; every other leaf, and every leaf
@@ -100,8 +100,7 @@ def engine_tokens(eng, prompt, n, temperature, seed):
             toks = eng.advance(bucket)
             if eng.last_ran(bucket)[slot]:
                 out.append(int(toks[slot]))
-    state = eng._pool if eng.paged else eng._buckets[bucket].slots
-    state = [np.asarray(x) for x in jax.tree.leaves(state)]
+    state = [np.asarray(x) for x in jax.tree.leaves(eng._pool)]
     eng.release(bucket, slot)
     return out[:n], state
 
@@ -109,10 +108,11 @@ def engine_tokens(eng, prompt, n, temperature, seed):
 @pytest.mark.parametrize("temperature,seed", [(0.0, 0), (0.9, 5)],
                          ids=["greedy", "sampled"])
 @pytest.mark.parametrize("draft", [False, True], ids=["plain", "draft"])
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "pinned"])
-def test_tokens_and_kv_are_the_raw_trees(params, dparams, paged, draft,
+@pytest.mark.parametrize("kv_dtype", [None, "int8"],
+                         ids=["kv_compute_dtype", "kv_int8"])
+def test_tokens_and_kv_are_the_raw_trees(params, dparams, kv_dtype, draft,
                                          temperature, seed):
-    kw = dict(ENGINE, paged=paged,
+    kw = dict(ENGINE, kv_dtype=kv_dtype,
               draft=(DCFG, dparams) if draft else None)
     held = DecodeEngine(CFG, params, **kw)
     raw = on_raw_tree(DecodeEngine(CFG, params, **kw), params,
@@ -123,6 +123,7 @@ def test_tokens_and_kv_are_the_raw_trees(params, dparams, paged, draft,
     want, want_kv = engine_tokens(raw, prompt, 24, temperature, seed)
     assert len(set(got)) > 4            # a stream that can tell trees apart
     assert got == want
+    assert got_kv[0].dtype == (jnp.int8 if kv_dtype else jnp.bfloat16)
     for a, b in zip(got_kv, want_kv):
         np.testing.assert_array_equal(a, b)
     hp = held.current_params()
@@ -150,7 +151,7 @@ def _weight_converts(jaxpr, shapes):
 
 def test_only_the_named_leaves_change_and_no_convert_is_left(params):
     before = casts()
-    eng = DecodeEngine(CFG, params, paged=True, **ENGINE)
+    eng = DecodeEngine(CFG, params, **ENGINE)
     assert casts() == before            # made on first use, not before
     hp = eng.current_params()
     assert casts() == (before[0] + 1, before[1] + matrices_bytes(CFG))
@@ -198,12 +199,12 @@ def _gpt_in_compute_type(params):
     cast = jax.tree.map(lambda x: x, params)
     for name in MATRICES:
         cast["blocks"][name] = params["blocks"][name].astype(jnp.bfloat16)
-    return CFG, cast, dict(ENGINE, paged=True)
+    return CFG, cast, dict(ENGINE)
 
 
 def _gpt_float32_compute(params):
     cfg = dataclasses.replace(CFG, compute_dtype="float32")
-    return cfg, params, dict(ENGINE, paged=False)
+    return cfg, params, dict(ENGINE)
 
 
 def _deepseek_rehearsal(params):
@@ -219,7 +220,7 @@ def _deepseek_rehearsal(params):
     # tests/test_deepseek_v2.py serves the family; its steps take a
     # minute to compile here
     return (family.program_config(config), family.make_params(config, 3),
-            dict(n_slots=2, buckets=(32,), paged=True, serve=False))
+            dict(n_slots=2, buckets=(32,), serve=False))
 
 
 @pytest.mark.parametrize("case", [_gpt_in_compute_type, _gpt_float32_compute,
@@ -248,7 +249,7 @@ def test_a_tree_with_nothing_to_cast_is_held_as_given(params, case):
 
 def test_live_params_cast_once_per_tree(params):
     box = [params]
-    eng = DecodeEngine(CFG, lambda: box[0], paged=True, **ENGINE)
+    eng = DecodeEngine(CFG, lambda: box[0], **ENGINE)
     before = casts()[0]
     first = eng.current_params()
     eng.warmup()
@@ -261,7 +262,7 @@ def test_live_params_cast_once_per_tree(params):
     assert second is not first
     assert eng.current_params() is second
     assert casts()[0] == before + 2
-    want = on_raw_tree(DecodeEngine(CFG, box[0], paged=True, **ENGINE),
+    want = on_raw_tree(DecodeEngine(CFG, box[0], **ENGINE),
                        box[0])
     # another prompt: a swap without rebind_params leaves the first
     # tree's resident prefix pages mounted (the serving contract)
@@ -273,7 +274,7 @@ def test_live_params_cast_once_per_tree(params):
 def test_rebind_drops_the_held_tree_and_casts_the_new_one(params):
     before = casts()[0]
     p_new = make_params(CFG, 11)
-    eng = DecodeEngine(CFG, params, paged=True, **ENGINE)
+    eng = DecodeEngine(CFG, params, **ENGINE)
     eng.warmup()
     prompt = np.arange(1, 12, dtype=np.int32)
     old = engine_tokens(eng, prompt, 10, 0.0, 0)[0]
@@ -285,7 +286,7 @@ def test_rebind_drops_the_held_tree_and_casts_the_new_one(params):
     # same shapes and dtypes: the cast's executable and the steps' are
     # the ones the first tree compiled
     assert decode_metrics.snapshot()["compile_delta_since_mark"] == 0
-    want = on_raw_tree(DecodeEngine(CFG, p_new, paged=True, **ENGINE),
+    want = on_raw_tree(DecodeEngine(CFG, p_new, **ENGINE),
                        p_new)
     assert new == engine_tokens(want, prompt, 10, 0.0, 0)[0]
     assert new != old
@@ -298,7 +299,7 @@ def test_held_leaves_take_the_mesh_layout(params):
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices")
     mesh = make_mesh(MeshSpec(data=1, model=2), devices=jax.devices()[:2])
-    kw = dict(ENGINE, paged=True)
+    kw = dict(ENGINE)
     sharded = DecodeEngine(CFG, params, mesh=mesh, label="held-shard", **kw)
     hp = sharded.current_params()
     for name in MATRICES:
@@ -317,7 +318,7 @@ def test_quantize_takes_the_steps_place(params):
     """``quantize`` is a user's choice that changes numerics; where it
     is set the hold does not run beside it."""
     before = casts()
-    eng = DecodeEngine(CFG, params, quantize="bf16", paged=True, **ENGINE)
+    eng = DecodeEngine(CFG, params, quantize="bf16", **ENGINE)
     qp = eng.current_params()
     assert qp["embed"]["tok"].dtype == jnp.bfloat16     # quantize's own rule
     assert casts() == before

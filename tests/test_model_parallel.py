@@ -403,10 +403,10 @@ def test_decode_engine_model_sharded_parity(devices):
     pdb = per_device_bytes(sharded_params)
     assert len(pdb) == 4
     assert max(pdb.values()) < 0.45 * total
-    # the slot cache itself is head-sharded
-    b = eng_s._buckets[16]
-    assert b.slots is not None
-    assert MODEL_AXIS in b.slots.k.sharding.spec
+    # the page pool itself is head-sharded, and every page came back
+    assert MODEL_AXIS in eng_s._pool.k.sharding.spec
+    eng_s.drop_residents()              # the prompt's first page
+    assert eng_s._alloc.in_use() + eng_s.pages_unaccounted() == 0
 
 
 def test_router_replicate_device_groups(devices):

@@ -19,6 +19,7 @@ The load-bearing properties:
 - every new path preserves the zero-steady-state-compile invariant.
 """
 
+import dataclasses
 import threading
 import time
 
@@ -50,8 +51,8 @@ def params():
     return gpt.init_params(jax.random.key(7), CFG)
 
 
-def _solo(params, prompt, n_tokens, p=None):
-    out = gpt.generate(CFG, p if p is not None else params,
+def _solo(params, prompt, n_tokens, p=None, cfg=CFG):
+    out = gpt.generate(cfg, p if p is not None else params,
                        np.asarray(prompt, np.int32)[None, :],
                        n_tokens, jax.random.key(0), temperature=0.0)
     return list(np.asarray(out)[0])
@@ -235,18 +236,24 @@ def test_int8_kv_drift_bound(params):
     assert fp / eng.kv_bytes_per_slot >= 1.8
 
 
-def test_kv_bytes_per_slot_accounting(params):
-    """The gauge matches the real device arrays' bytes."""
-    slots = gpt.init_slots(CFG, 4, 32, kv_dtype="int8")
-    per_slot = sum(np.asarray(x).nbytes
-                   for x in jax.tree.leaves((slots.k, slots.v,
-                                             slots.k_scale,
-                                             slots.v_scale))) // 4
-    assert gpt.slots_bytes_per_slot(CFG, 32, "int8") == per_slot
+@pytest.mark.parametrize("kv_dtype", [None, "int8"],
+                         ids=["kv_compute_dtype", "kv_int8"])
+def test_kv_bytes_per_slot_accounting(params, kv_dtype):
+    """The gauges match the real device arrays' bytes, scale rows
+    included: a slot of a 32-token bucket is four live 8-token pages."""
+    pool = gpt.init_pages(CFG, 4, 8, kv_dtype=kv_dtype)
+    per_slot = sum(np.asarray(x).nbytes for x in jax.tree.leaves(pool))
+    assert gpt.pages_bytes(CFG, 4, 8, kv_dtype) == per_slot
+    assert gpt.slots_bytes_per_slot(CFG, 32, kv_dtype) == per_slot
     eng = DecodeEngine(CFG, params, n_slots=4, buckets=(32,),
-                       kv_dtype="int8", label="t2-kvbytes")
+                       prefill_chunk=8, kv_dtype=kv_dtype,
+                       label="t2-kvbytes")
     assert eng.kv_bytes_per_slot == per_slot
     assert decode_metrics.snapshot()["kv_bytes_per_slot"] == per_slot
+    # the default pool: n_slots slots of the largest rung + the trash page
+    assert eng.n_kv_pages == 4 * 4 + 1
+    assert eng.pool_bytes == sum(
+        np.asarray(x).nbytes for x in jax.tree.leaves(eng._pool_state()))
 
 
 # -- prefix cache -----------------------------------------------------------
@@ -316,7 +323,11 @@ def test_prefix_hit_bit_exact_vs_cold(params):
     partial-prefix request) decode BIT-identically to cold prefill,
     with hits/misses/tokens-saved booked and zero compiles."""
     store = PrefixCache()
-    eng = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
+    # a config of its own: engines of one config and geometry share
+    # their jitted programs (tests/test_serving_tier3.py builds this
+    # geometry on CFG), and this test counts its own traces
+    cfg = dataclasses.replace(CFG, layer_norm_eps=4e-5)
+    eng = DecodeEngine(cfg, params, n_slots=2, buckets=(32,),
                        prefill_chunk=8, prefix_cache=store,
                        label="t2-prefix")
     warm = eng.warmup()
@@ -333,7 +344,7 @@ def test_prefix_hit_bit_exact_vs_cold(params):
     decode_metrics.mark_compiles()
     hot = _engine_tokens(eng, prompt, 8)
     s2 = decode_metrics.snapshot()
-    assert hot == cold == _solo(params, prompt, 8)
+    assert hot == cold == _solo(params, prompt, 8, cfg=cfg)
     assert s2["prefix_hits"] == base["prefix_hits"] + 1
     # 21 tokens -> 16 chunk-aligned prefix tokens skipped
     assert s2["prefill_tokens_saved"] >= \
@@ -343,7 +354,7 @@ def test_prefix_hit_bit_exact_vs_cold(params):
     # partial hit: shares 2 chunks then diverges — still bit-exact
     tail = rng.randint(1, CFG.vocab_size, size=6).astype(np.int32)
     p2 = np.concatenate([prompt[:16], tail])
-    assert _engine_tokens(eng, p2, 8) == _solo(params, p2, 8)
+    assert _engine_tokens(eng, p2, 8) == _solo(params, p2, 8, cfg=cfg)
     assert decode_metrics.snapshot()["prefix_hits"] == \
         base["prefix_hits"] + 2
 
@@ -586,10 +597,10 @@ def test_int8_model_sharded_decode_parity(params):
     wq = qp["blocks"]["wq"]
     assert isinstance(wq, qz.QTensor) and wq.q.dtype == jnp.int8
     assert MODEL_AXIS in wq.q.sharding.spec
-    b = eng_s._buckets[32]
-    assert b.slots.k.dtype == jnp.int8
-    assert MODEL_AXIS in b.slots.k.sharding.spec
-    assert b.slots.k_scale.dtype == jnp.float32
+    pool = eng_s._pool
+    assert pool.k.dtype == jnp.int8
+    assert MODEL_AXIS in pool.k.sharding.spec
+    assert pool.k_scale.dtype == jnp.float32
 
 
 # -- one-shot engine quantization + steady state ----------------------------

@@ -7,15 +7,17 @@ The load-bearing properties:
   pages, is all-or-nothing (typed :class:`KVPagesExhausted` on
   shortfall), and keeps EXACT occupancy under a randomized
   admit/extend/free schedule;
-- a paged engine is BIT-identical to the pinned engine — greedy and
-  sampled, fp32 and int8 — because paging only re-indexes KV storage,
+- the engine is BIT-identical to the plain references that know no
+  pages (``gpt.generate``; a dense forward a token with
+  ``sample_token`` under the position key ``_slot_key``) — greedy and
+  sampled, fp32 and int8 — because paging only indexes KV storage,
   never changes a single matmul;
 - a pool-resident prefix hit mounts pages BY REFERENCE (refcounts, no
   copy) and a released slot returns its pages to the pool;
 - speculative decoding is bit-identical to plain decode at ANY
   temperature (position-keyed sampling), proposes/accepts are booked,
-  and the whole stack composes: paged + draft + int8 + batcher;
-- oversize paged admits fail SYNCHRONOUSLY with the typed error;
+  and the whole stack composes: pages + draft + int8 + batcher;
+- oversize admits fail SYNCHRONOUSLY with the typed error;
 - ``rebind_params`` requires an idle engine and flips outputs to the
   new checkpoint with zero new compiles; the router's
   ``swap_weights`` rolls a live fleet with zero dropped requests;
@@ -27,13 +29,14 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from deeplearning4j_tpu.models import gpt
 from deeplearning4j_tpu.models.transformer import TransformerConfig
 from deeplearning4j_tpu.parallel.chaos import ServingChaos
-from deeplearning4j_tpu.runtime import telemetry
+from deeplearning4j_tpu.runtime import quantize as qz, telemetry
 from deeplearning4j_tpu.runtime.metrics import decode_metrics
 from deeplearning4j_tpu.serving.decode import (KV_PAGE_TOKENS,
                                                BatcherClosed,
@@ -72,6 +75,33 @@ def _solo(p, prompt, n_tokens, cfg=CFG):
     out = gpt.generate(cfg, p, np.asarray(prompt, np.int32)[None, :],
                        n_tokens, jax.random.key(0), temperature=0.0)
     return list(np.asarray(out)[0])
+
+
+def _reference(p, prompt, n_tokens, temperature=0.0, seed=0, cfg=CFG,
+               int8_kv=False):
+    """The plain reference for ANY temperature, sharing no cache
+    plumbing with the engine: every token from a dense pass over the
+    whole sequence so far (``forward_logits``; for an int8 cache
+    ``_prefill_chunk`` over a fresh ``QKVCache``, which quantizes each
+    row as it writes it and attends over the rows dequantized) and
+    ``sample_token`` under the key of the position that produced the
+    logits, ``_slot_key(seed, pos)`` — what makes a stream independent
+    of slot, rung, round and replica."""
+    seq = [int(t) for t in prompt]
+    for _ in range(n_tokens):
+        toks = jnp.asarray(seq, jnp.int32)[None, :]
+        if int8_kv:
+            rows = (cfg.n_layers, 1, len(seq))
+            kv = jnp.zeros(rows + (cfg.n_heads, cfg.head_dim), jnp.int8)
+            sc = jnp.zeros(rows, jnp.float32)
+            _, logits = gpt._prefill_chunk(
+                cfg, p, gpt.QKVCache(kv, kv, sc, sc), toks, jnp.int32(0))
+        else:
+            logits = gpt.forward_logits(cfg, p, toks)
+        key = gpt._slot_key(jnp.uint32(seed), jnp.int32(len(seq) - 1))
+        seq.append(int(gpt.sample_token(logits[0, -1], key,
+                                        jnp.float32(temperature))))
+    return seq[len(prompt):]
 
 
 def _engine_tokens(eng, prompt, n, temperature=0.0, seed=0):
@@ -163,11 +193,11 @@ def test_page_allocator_randomized_schedule():
     assert a.in_use() == 0 and a.n_free() == 32
 
 
-# -- paged == pinned --------------------------------------------------------
+# -- the engine == the plain references -------------------------------------
 
 def test_paged_greedy_bit_exact_and_pages_released(params):
     eng = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
-                       prefill_chunk=8, paged=True)
+                       prefill_chunk=8)
     eng.warmup()
     prompt = np.arange(1, 7, dtype=np.int32)    # < one chunk: no harvest
     got = _engine_tokens(eng, prompt, 10)
@@ -178,28 +208,42 @@ def test_paged_greedy_bit_exact_and_pages_released(params):
     assert snap["pages_in_use_hw"] >= 2         # prompt page + growth
 
 
-def test_paged_int8_matches_pinned_int8(params):
-    kw = dict(n_slots=2, buckets=(32,), prefill_chunk=8,
-              quantize="int8", kv_dtype="int8")
-    paged = DecodeEngine(CFG, params, paged=True, **kw)
-    pinned = DecodeEngine(CFG, params, **kw)
-    paged.warmup()
-    pinned.warmup()
+def test_int8_pool_matches_the_int8_cache_reference(params):
+    """int8 weights and an int8 pool against the dequantized tree over
+    a plain int8 ``QKVCache``: same rows, same scales, no pages."""
+    eng = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
+                       prefill_chunk=8, quantize="int8", kv_dtype="int8")
+    eng.warmup()
     prompt = np.arange(1, 13, dtype=np.int32)
-    assert _engine_tokens(paged, prompt, 10) \
-        == _engine_tokens(pinned, prompt, 10)
+    dq = qz.dequantize_tree(qz.quantize_tree(params, "int8"))
+    got = _engine_tokens(eng, prompt, 10)
+    assert got == _reference(dq, prompt, 10, int8_kv=True)
+    assert eng._pool is not None and eng._pool.k.dtype == np.int8
+    eng.drop_residents()                # the prompt's first page
+    assert eng._alloc.in_use() + eng.pages_unaccounted() == 0
 
 
-def test_paged_sampled_matches_pinned(params):
-    kw = dict(n_slots=2, buckets=(32,), prefill_chunk=8)
-    paged = DecodeEngine(CFG, params, paged=True, **kw)
-    pinned = DecodeEngine(CFG, params, **kw)
-    paged.warmup()
-    pinned.warmup()
+def test_sampled_matches_the_dense_reference(params):
+    """A sampled stream is the dense forward's, key for key, and does
+    not depend on where the request was placed: alone in a fresh
+    one-slot engine, or second into another engine's second slot."""
+    kw = dict(buckets=(32,), prefill_chunk=8)
     prompt = np.arange(1, 10, dtype=np.int32)
-    a = _engine_tokens(paged, prompt, 12, temperature=0.8, seed=5)
-    b = _engine_tokens(pinned, prompt, 12, temperature=0.8, seed=5)
-    assert a == b
+    want = _reference(params, prompt, 12, temperature=0.8, seed=5)
+    assert len(set(want)) > 4           # sampled, not one token repeated
+    alone = DecodeEngine(CFG, params, n_slots=1, **kw)
+    alone.warmup()
+    assert _engine_tokens(alone, prompt, 12, temperature=0.8,
+                          seed=5) == want
+    eng = DecodeEngine(CFG, params, n_slots=2, **kw)
+    eng.warmup()
+    other = eng.start(np.arange(20, 31, dtype=np.int32), max_tokens=20,
+                      temperature=0.8, seed=1)
+    assert _engine_tokens(eng, prompt, 12, temperature=0.8,
+                          seed=5) == want
+    eng.release(*other[:2])
+    eng.drop_residents()                # each prompt's first page
+    assert eng._alloc.in_use() + eng.pages_unaccounted() == 0
 
 
 def _batched_streams(eng, prompts, budgets, temperature):
@@ -216,13 +260,13 @@ def _batched_streams(eng, prompts, budgets, temperature):
 
 @pytest.mark.parametrize("temperature", [0.0, 0.8],
                          ids=["greedy", "sampled"])
-def test_paged_multi_rung_joins_and_leaves_match_pinned(params,
-                                                        temperature):
+def test_multi_rung_joins_and_leaves_match_the_references(params,
+                                                          temperature):
     """Three rungs in flight, two slots a rung, seven requests: a round
     dispatches several rungs' programs against the ONE pool, slots fill
-    and free between them, and every stream still equals the pinned
-    engine's (and, greedy, the unbatched ``generate``) token for
-    token."""
+    and free between them, and every stream still equals the dense
+    reference's under its own seed (and, greedy, the unbatched
+    ``generate``) token for token."""
     # a config of its own: engines of one config and geometry share
     # their jitted programs, and other tests count their own traces
     cfg = dataclasses.replace(CFG, layer_norm_eps=2e-5)
@@ -231,20 +275,19 @@ def test_paged_multi_rung_joins_and_leaves_match_pinned(params,
     prompts = [rng.integers(1, 64, size=n).astype(np.int32)
                for n in (3, 20, 9, 40, 14, 27, 5)]
     budgets = [6, 10, 20, 12, 4, 30, 9]
-    paged = DecodeEngine(cfg, params, paged=True, **kw)
-    pinned = DecodeEngine(cfg, params, **kw)
-    assert {paged.pick_bucket(len(p) + n)
+    eng = DecodeEngine(cfg, params, **kw)
+    assert {eng.pick_bucket(len(p) + n)
             for p, n in zip(prompts, budgets)} == {16, 32, 64}
-    paged.warmup()
-    pinned.warmup()
-    got = _batched_streams(paged, prompts, budgets, temperature)
-    assert got == _batched_streams(pinned, prompts, budgets, temperature)
+    eng.warmup()
+    got = _batched_streams(eng, prompts, budgets, temperature)
+    assert got == [_reference(params, p, n, temperature, seed=i, cfg=cfg)
+                   for i, (p, n) in enumerate(zip(prompts, budgets))]
     assert [len(g) for g in got] == budgets
     if temperature == 0.0:
         assert got == [_solo(params, p, n, cfg)
                        for p, n in zip(prompts, budgets)]
-    paged.drop_residents()
-    assert paged._alloc.in_use() == 0
+    eng.drop_residents()
+    assert eng._alloc.in_use() == 0
 
 
 def _random_pool(kv_dtype, n_pages=6, page_tokens=8):
@@ -342,7 +385,7 @@ def test_paged_engine_on_a_model_mesh_matches_replicated(params):
         pytest.skip("needs >= 2 devices")
     mesh = make_mesh(MeshSpec(data=1, model=2), devices=jax.devices()[:2])
     cfg = dataclasses.replace(CFG, layer_norm_eps=3e-5)     # own programs
-    kw = dict(n_slots=2, buckets=(16, 32), prefill_chunk=8, paged=True)
+    kw = dict(n_slots=2, buckets=(16, 32), prefill_chunk=8)
     eng_r = DecodeEngine(cfg, params, label="t3-pg-repl", **kw)
     eng_s = DecodeEngine(cfg, params, mesh=mesh, label="t3-pg-shard", **kw)
     eng_r.warmup()
@@ -358,7 +401,7 @@ def test_resident_prefix_mounts_by_reference(params):
     request's pages: refcount > 1 while mounted, a prefix hit is
     booked, output stays bit-exact, and release only decrefs."""
     eng = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
-                       prefill_chunk=8, paged=True)
+                       prefill_chunk=8)
     eng.warmup()
     head = np.arange(1, 17, dtype=np.int32)             # two full chunks
     p1 = np.concatenate([head, [20, 21]])
@@ -384,7 +427,7 @@ def test_resident_prefix_mounts_by_reference(params):
 
 def test_oversize_paged_admit_is_typed_and_sync(params):
     eng = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
-                       prefill_chunk=8, paged=True, n_pages=4)
+                       prefill_chunk=8, n_pages=4)
     eng.warmup()
     with pytest.raises(KVPagesExhausted):
         eng.check_capacity(25)              # needs 4+1 pages, pool has 3
@@ -411,12 +454,12 @@ def test_spec_greedy_bit_identical_and_booked(params, dparams):
     assert proposed > 0 and 0 <= accepted <= proposed
 
 
-def test_spec_paged_sampled_matches_plain(params, dparams):
+def test_spec_sampled_matches_plain(params, dparams):
     """Position-keyed sampling makes speculative decoding token
-    -identical to plain decode at ANY temperature — paged + draft vs
-    the pinned plain engine."""
+    -identical to plain decode at ANY temperature — the engine with a
+    draft vs the engine without one, and vs the dense reference."""
     spec = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
-                        prefill_chunk=8, paged=True,
+                        prefill_chunk=8,
                         draft=(DCFG, dparams), draft_k=3)
     plain = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
                          prefill_chunk=8)
@@ -425,16 +468,16 @@ def test_spec_paged_sampled_matches_plain(params, dparams):
     prompt = np.arange(1, 8, dtype=np.int32)
     a = _engine_tokens(spec, prompt, 12, temperature=0.7, seed=9)
     b = _engine_tokens(plain, prompt, 12, temperature=0.7, seed=9)
-    assert a == b
+    assert a == b == _reference(params, prompt, 12, temperature=0.7, seed=9)
 
 
-def test_batcher_composes_paged_spec_int8(params, dparams):
+def test_batcher_composes_spec_int8_prefix(params, dparams):
     """The whole tier-3 stack at once: continuous batching over a
-    paged, speculative, int8-weight engine with a shared prefix store
-    — every request bit-matches the pinned int8 plain engine."""
+    speculative, int8-weight engine with a shared prefix store — every
+    request bit-matches the plain int8 engine serving it alone."""
     store = PrefixCache()
     eng = DecodeEngine(CFG, params, n_slots=4, buckets=(32,),
-                       prefill_chunk=8, paged=True, quantize="int8",
+                       prefill_chunk=8, quantize="int8",
                        draft=(DCFG, dparams), draft_k=3,
                        prefix_cache=store)
     ref = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
@@ -456,7 +499,7 @@ def test_batcher_composes_paged_spec_int8(params, dparams):
 
 def test_tier3_zero_steady_state_compiles(params, dparams):
     eng = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
-                       prefill_chunk=8, paged=True,
+                       prefill_chunk=8,
                        draft=(DCFG, dparams), draft_k=3)
     eng.warmup()                            # marks the compile baseline
     for start in (1, 5):
@@ -470,7 +513,7 @@ def test_tier3_zero_steady_state_compiles(params, dparams):
 def test_rebind_params_requires_idle_then_flips(params):
     p_new = gpt.init_params(jax.random.key(11), CFG)
     eng = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
-                       prefill_chunk=8, paged=True)
+                       prefill_chunk=8)
     eng.warmup()
     prompt = np.arange(1, 8, dtype=np.int32)
     bucket, slot, _ = eng.start(prompt, max_tokens=4)
@@ -488,7 +531,7 @@ def test_rebind_invalidates_resident_prefix(params):
     drops the resident registry."""
     p_new = gpt.init_params(jax.random.key(12), CFG)
     eng = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
-                       prefill_chunk=8, paged=True)
+                       prefill_chunk=8)
     eng.warmup()
     head = np.arange(1, 17, dtype=np.int32)
     _engine_tokens(eng, np.concatenate([head, [20]]), 6)
@@ -511,7 +554,7 @@ def test_router_swap_weights_zero_drops(params):
 
     def factory():
         eng = DecodeEngine(CFG, params, n_slots=4, buckets=(32,),
-                           prefill_chunk=8, paged=True,
+                           prefill_chunk=8,
                            prefix_cache=store)
         eng.warmup()
         return ContinuousBatcher(eng, default_max_tokens=6)
@@ -558,7 +601,7 @@ def test_swap_single_replica_spawns_temp(params):
 
     def factory():
         eng = DecodeEngine(CFG, params, n_slots=2, buckets=(32,),
-                           prefill_chunk=8, paged=True)
+                           prefill_chunk=8)
         eng.warmup()
         return ContinuousBatcher(eng, default_max_tokens=6)
 
@@ -581,7 +624,7 @@ def test_swap_single_replica_spawns_temp(params):
 
 def _ft_batcher(params, *, n_slots=2, default_max_tokens=6):
     eng = DecodeEngine(CFG, params, n_slots=n_slots, buckets=(32,),
-                       prefill_chunk=8, paged=True)
+                       prefill_chunk=8)
     eng.warmup()
     return ContinuousBatcher(eng, default_max_tokens=default_max_tokens)
 

@@ -607,8 +607,7 @@ def test_decode_loop_counts_its_phases_where_they_happen():
                             compute_dtype="float32", causal=True,
                             type_vocab_size=1)
     eng = DecodeEngine(cfg, gpt.init_params(jax.random.key(7), cfg),
-                       n_slots=2, buckets=(32,), prefill_chunk=8,
-                       paged=True)
+                       n_slots=2, buckets=(32,), prefill_chunk=8)
     eng.warmup()
     decode_metrics.reset()
     tr = telemetry.enable("decode-loop")

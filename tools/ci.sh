@@ -28,7 +28,7 @@ cd "$REPO"
 # the exit code contract is identical to text mode.  The cache file
 # makes repeat CI runs warm (summaries + per-file results persist).
 echo "[ci] jaxlint (two-pass linked analysis)"
-python -m tools.jaxlint deeplearning4j_tpu bench.py tools \
+python -m tools.jaxlint deeplearning4j_tpu tools \
   --format json --jobs 4 --cache-file .jaxlint_ci_cache.json || exit 1
 
 # Linked-analysis wall-clock budget: the v4 two-pass pipeline earns its
@@ -44,7 +44,7 @@ from pathlib import Path
 from tools.jaxlint import rules  # noqa: F401 — registers the rule set
 from tools.jaxlint.core import run_paths
 
-paths = [Path("deeplearning4j_tpu"), Path("bench.py"), Path("tools")]
+paths = [Path("deeplearning4j_tpu"), Path("tools")]
 nolink = Path(".jaxlint_ci_nolink.json")
 linked = Path(".jaxlint_ci_cache.json")   # warmed by the stage above
 run_paths(paths, cache_path=nolink, link=False)          # warm v3 cache

@@ -5,7 +5,7 @@ tools/jaxlint/rules/ for the rule set).
 Public API::
 
     from tools.jaxlint import run_paths, check_source, REGISTRY
-    findings = run_paths(["deeplearning4j_tpu", "bench.py", "tools"])
+    findings = run_paths(["deeplearning4j_tpu", "tools"])
 
 CLI: ``python -m tools.jaxlint [paths...]`` (see cli.py).
 """

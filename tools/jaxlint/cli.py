@@ -32,7 +32,7 @@ from tools.jaxlint.core import REGISTRY, iter_python_files, run_paths
 
 DEFAULT_BASELINE = Path(__file__).resolve().parent / "baseline.json"
 DEFAULT_CACHE = Path(".jaxlint_cache.json")
-DEFAULT_PATHS = ("deeplearning4j_tpu", "bench.py", "tools")
+DEFAULT_PATHS = ("deeplearning4j_tpu", "tools")
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:

@@ -22,7 +22,7 @@ either
   ``transformer.shard_specs`` re-exported — the delegatee carries the
   guard), or
 - carry an inline suppression explaining where the validation lives
-  (``gpt.slot_specs``: the DecodeEngine validates at construction).
+  (``gpt.paged_specs``: the DecodeEngine validates at construction).
 """
 
 from __future__ import annotations

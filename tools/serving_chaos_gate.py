@@ -60,7 +60,7 @@ def _make_factory(cfg, params):
 
     def factory():
         eng = DecodeEngine(cfg, params, n_slots=3, buckets=(16, 32),
-                           prefill_chunk=8, paged=True,
+                           prefill_chunk=8,
                            label="chaos-gate")
         eng.warmup()
         return ContinuousBatcher(eng, default_max_tokens=5)

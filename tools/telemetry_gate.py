@@ -482,7 +482,7 @@ def _tier3_decode_gate(registry, telemetry) -> int:
 
     def factory():
         eng = DecodeEngine(cfg, params, n_slots=3, buckets=(16, 32),
-                           prefill_chunk=8, paged=True,
+                           prefill_chunk=8,
                            draft=(dcfg, dp), draft_k=3,
                            prefix_cache=store, label="gate-tier3")
         eng.warmup()

@@ -3,8 +3,8 @@
 The kernels default to (block_q, block_k) = (128, 128); the best tiling
 depends on the chip generation (VMEM size / MXU shape) and sequence
 length.  This sweeps the grid at the bench shapes and prints one JSON
-line per (T, bq, bk) plus the winner per T, so the defaults (and
-bench_longctx) can be retuned from data rather than guesswork.
+line per (T, bq, bk) plus the winner per T, so the defaults can be
+retuned from data rather than guesswork.
 
 Usage:  python tools/tune_flash.py [T ...]     (default: 8192 16384 32768)
 """
