@@ -343,11 +343,7 @@ def phase_serve(cfg: Any, params: Any, sz: Sizes
     # what the longest rung's decode program asks for beside its
     # arguments (the pool is aliased): the bf16 weights, one layer's
     # pages; a pool copy or an all-layer view would show here
-    top = eng._buckets[eng.buckets[-1]]
-    mem = eng._decode.jitted.lower(
-        eng.current_params(), eng._pool_state(), top.ptab, top.tokens_h,
-        top.pos_h, top.active, top.temps, top.seeds
-    ).compile().memory_analysis()
+    mem = eng._lower_decode(eng.buckets[-1]).compile().memory_analysis()
     say("serve", rung=eng.buckets[-1], n_slots=sz.n_slots,
         decode_temp_bytes=int(mem.temp_size_in_bytes),
         decode_argument_bytes=int(mem.argument_size_in_bytes),

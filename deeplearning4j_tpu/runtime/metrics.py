@@ -289,6 +289,15 @@ class DecodeMetrics:
     - ``slot_steps`` / ``slot_capacity_steps``: active vs total slots
       summed over decode dispatches — ``snapshot()['slot_occupancy']``
       is their ratio (1.0 = every dispatch fully utilized);
+    - ``decode_dispatch_rungs``: summed over decode dispatches, the
+      DISTINCT rungs (``pick_bucket(prompt + max_tokens)``) of the
+      requests a dispatch carried — the dispatches an engine with a
+      slot table a rung would have made, so ``decode_dispatch_rungs /
+      decode_dispatches`` is the factor one table saves (1.0 when one
+      rung is live); ``decode_table_rows``: summed over decode
+      dispatches, ``n_slots`` x the table width the dispatch took — the
+      rows it gathered a layer, live or padding, to read against
+      ``slot_steps`` and ``page_token_rows``;
     - ``queue_depth`` / ``max_queue_depth``: most recent and high-water
       PER-BATCHER pending depth (each batcher reports its own count;
       with multiple router replicas this is a replica-level gauge, not
@@ -366,7 +375,8 @@ class DecodeMetrics:
       chunk's dispatch and the wait for the first token, and
       ``prefill_sync_s`` (``decode.prefill.sync``) that wait alone;
     - ``advance_s`` (``decode.advance``): ``engine.advance`` calls, one
-      per ``decode_dispatches``; ``fetch_s`` (``decode.fetch``): the
+      per ``decode_dispatches`` and, since the engine keeps one slot
+      table, one a round; ``fetch_s`` (``decode.fetch``): the
       part of them spent waiting for the step's tokens.
 
     The tree a ``DecodeEngine`` holds for its executables
@@ -417,6 +427,8 @@ class DecodeMetrics:
             self.joins = 0
             self.slot_steps = 0
             self.slot_capacity_steps = 0
+            self.decode_dispatch_rungs = 0
+            self.decode_table_rows = 0
             self.queue_depth = 0
             self.max_queue_depth = 0
             self.prefix_hits = 0
@@ -566,11 +578,14 @@ class DecodeMetrics:
         with self._lock:
             self.prefill_dispatches += int(chunks)
 
-    def note_decode_dispatch(self, active: int, capacity: int) -> None:
+    def note_decode_dispatch(self, active: int, capacity: int,
+                             rungs: int, table_rows: int) -> None:
         with self._lock:
             self.decode_dispatches += 1
             self.slot_steps += int(active)
             self.slot_capacity_steps += int(capacity)
+            self.decode_dispatch_rungs += int(rungs)
+            self.decode_table_rows += int(table_rows)
 
     def note_queue_depth(self, depth: int) -> None:
         with self._lock:
@@ -603,6 +618,8 @@ class DecodeMetrics:
                 "tokens_out": self.tokens_out,
                 "prefill_dispatches": self.prefill_dispatches,
                 "decode_dispatches": self.decode_dispatches,
+                "decode_dispatch_rungs": self.decode_dispatch_rungs,
+                "decode_table_rows": self.decode_table_rows,
                 "joins": self.joins,
                 "slot_occupancy": round(occ, 4),
                 "queue_depth": self.queue_depth,

@@ -12,8 +12,9 @@ One-shot forwards (classification, scoring):
 
 Autoregressive decode (models/gpt.py causal LMs):
 
-- ``DecodeEngine`` (decode.py): persistent slot-structured KV cache per
-  cache-length bucket, ONE donated decode-step executable advancing all
+- ``DecodeEngine`` (decode.py): one page pool and one table of
+  ``n_slots`` slots for every length, ONE donated decode-step
+  executable per table width of the cache-length ladder advancing all
   occupied slots per dispatch; new requests prefill into free slots
   mid-flight (continuous batching).
 - ``ContinuousBatcher`` (decode.py): streaming per-request front-end

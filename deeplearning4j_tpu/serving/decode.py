@@ -11,12 +11,15 @@ persistent-dataflow lesson (arXiv:1605.08695) both land on the same
 recipe, implemented here:
 
 - ``DecodeEngine`` owns ONE persistent pool of KV pages (see KV PAGES
-  below), S slots per cache-length bucket (S = max concurrent
-  sequences, bucketed T_max ladder like PR 3's batch ladder) each with
-  a host-side page table, and ONE jitted, donated decode-step
-  executable per (conf, bucket) — compiled through
-  ``runtime/compile_cache.cached_jit`` — that advances ALL occupied
-  slots by one token per dispatch.
+  below) and ONE table of S slots (S = ``n_slots``, the sequences it
+  runs at once, whatever their lengths), each with a host-side page
+  table row.  A RUNG of the cache-length ladder (``buckets``, a T_max
+  ladder like PR 3's batch ladder) is a compiled page-table WIDTH and
+  nothing else: no rung owns a slot, a page or a dispatch.  ONE
+  jitted, donated decode-step executable per (conf, width) — compiled
+  through ``runtime/compile_cache.cached_jit`` — advances ALL occupied
+  slots by one token per dispatch, taken at the narrowest width that
+  covers the longest running slot.
 - New requests JOIN the running batch: the prompt is prefilled into a
   free slot with the chunked dense prefill executable (matmul-bound
   slabs, one page write a chunk into the live pool) between two
@@ -30,9 +33,9 @@ recipe, implemented here:
 
 A replicated front-end with load-shedding lives in
 ``serving/router.py``.  Steady state is compile-free: ``warmup()``
-pre-traces both executables for every bucket, after which any mix of
-prompt lengths, joins, and slot recycling dispatches only cached
-programs (asserted by tier-1 tests and the telemetry gate).  The
+pre-traces both executables at every width of the ladder, after which
+any mix of prompt lengths, joins, and slot recycling dispatches only
+cached programs (asserted by tier-1 tests and the telemetry gate).  The
 worker/lock contract (engine driven by ONE thread, shared request
 state mutated only under its Condition, no blocking wait under a held
 lock) is machine-checked by jaxlint's concurrency family.
@@ -75,14 +78,17 @@ arXiv:2309.08918):
 KV PAGES (``gpt.PagedKV``; the one storage scheme — the slab a slot
 owned per rung, ``paged=False``, was removed in PR 30): ONE pool of
 ``KV_PAGE_TOKENS``-token pages, [L, P, C, NH*D], sized by ``n_pages``
-(default: ``n_slots`` x the largest rung, + the trash page), shared by
-every rung and donated to every dispatch, and a host-side page table a
-rung.  A decode (or verify, or draft) dispatch of a rung reads, layer
-by layer, the S x TBL pages its table names — that rung's rows of one
-layer at a time, never the pool, never an all-layer view — and writes
-each active slot's fresh rows of that layer at (layer, page, offset),
-in place; an inactive or stalled slot's rows go to the trash page 0.
-A prefill dispatch reads one slot's pages and writes one page.
+(default: ``n_slots`` x the largest rung, + the trash page), donated
+to every dispatch, and ONE host-side page table of ``n_slots`` rows as
+wide as the largest rung.  A decode (or verify, or draft) dispatch is
+handed the table's first ``w // C`` columns, ``w`` the narrowest rung
+that covers the longest running slot, and reads, layer by layer, the
+S x TBL pages those columns name — one layer's rows at a time, never
+the pool, never an all-layer view — and writes each active slot's
+fresh rows of that layer at (layer, page, offset), in place; an
+inactive or stalled slot's rows go to the trash page 0.  A prefill
+dispatch reads one slot's pages, at the width of the request's OWN
+rung (``pick_bucket(prompt + max_tokens)``), and writes one page.
 HBM holds what live tokens occupy, so admission counts free pages as
 well as free slots, and a slot whose next page cannot be had STALLS a
 dispatch instead of failing (:class:`KVPagesExhausted` only when
@@ -209,14 +215,13 @@ class KVPagesExhausted(RuntimeError):
     youngest stalled slot) or a prompt alone exceeds the whole pool."""
 
     def __init__(self, needed: int, free: int, total: int,
-                 bucket: Optional[int] = None, slot: Optional[int] = None):
+                 slot: Optional[int] = None):
         super().__init__(
             f"KV page pool exhausted: need {needed} page(s), "
             f"{free} free of {total}")
         self.needed = needed
         self.free = free
         self.total = total
-        self.bucket = bucket
         self.slot = slot
 
 
@@ -479,45 +484,47 @@ class PrefixCache:
             return {"entries": len(self._entries), "bytes": self._bytes}
 
 
-class _Bucket:
-    """Host-side state for one cache-length bucket: the page tables
-    plus the occupancy/sampling arrays the decode dispatch takes each
-    step (the pool is the only DEVICE state, and the engine's)."""
+class _SlotTable:
+    """Host-side state of an engine's ``n_slots`` slots, ONE table for
+    every length: the page table plus the occupancy/sampling arrays the
+    decode dispatch takes each step (the pool is the only DEVICE state,
+    and the engine's).  A rung of the ladder owns nothing here; a slot
+    remembers the rung of the request it holds (``rung``: the width its
+    prefill ran at and the most its page row may grow to)."""
 
-    __slots__ = ("t_max", "active", "temps", "seeds", "owners",
+    __slots__ = ("active", "temps", "seeds", "owners", "rung",
                  "ptab", "n_pages", "tokens_h", "pos_h", "ran")
 
-    def __init__(self, t_max: int, n_slots: int, page_tokens: int):
-        self.t_max = t_max
+    def __init__(self, n_slots: int, table_pages: int):
         self.active = np.zeros((n_slots,), np.bool_)
         self.temps = np.zeros((n_slots,), np.float32)
         self.seeds = np.zeros((n_slots,), np.uint32)
         self.owners: List[Any] = [None] * n_slots
-        # per-slot page table (trash-id 0 in unused entries),
-        # allocated-page counts, and host mirrors of tokens/pos
-        # (deterministic from the fetched stream: every dispatch, the
-        # draft's too, takes them); ``ran`` is the last dispatch's
-        # progress mask (a slot stalls when its next page cannot be
-        # allocated)
+        self.rung = np.zeros((n_slots,), np.int32)
+        # per-slot page table as wide as the largest rung (trash-id 0
+        # in unused entries), allocated-page counts, and host mirrors
+        # of tokens/pos (deterministic from the fetched stream: every
+        # dispatch, the draft's too, takes them); ``ran`` is the last
+        # dispatch's progress mask (a slot stalls when its next page
+        # cannot be allocated)
         self.ran = np.zeros((n_slots,), np.bool_)
         self.tokens_h = np.zeros((n_slots,), np.int32)
         self.pos_h = np.zeros((n_slots,), np.int32)
-        self.ptab = np.zeros((n_slots, t_max // page_tokens), np.int32)
+        self.ptab = np.zeros((n_slots, table_pages), np.int32)
         self.n_pages = np.zeros((n_slots,), np.int32)
-
-    def free_slot(self) -> Optional[int]:
-        for i, o in enumerate(self.owners):
-            if o is None:
-                return i
-        return None
-
-    def n_active(self) -> int:
-        return int(self.active.sum())
 
 
 class DecodeEngine:
     """Slot-structured, page-pooled KV-cache decode engine for a causal
-    LM.  The model family is an argument, not an import: the engine
+    LM: ONE table of ``n_slots`` slots, the sequences it runs at once
+    over ALL lengths (``n_slots`` bounds the engine, not a rung), and
+    ONE decode dispatch for all of them.  ``buckets`` is the ladder of
+    page-table WIDTHS a program is compiled for, no more: ``start()``
+    prefills a request at the width of its own rung
+    (``pick_bucket(prompt + max_tokens)``), ``advance()`` dispatches
+    every running slot at the narrowest rung that covers the longest of
+    them, read off the live positions and no option.  The model family
+    is an argument, not an import: the engine
     takes its pool and its two dispatches from the family of ``cfg``
     (:func:`model_family`: ``models/gpt.py``, ``models/deepseek_v2.py``)
     and holds, for each ``params`` tree it is given, the tree its
@@ -545,7 +552,7 @@ class DecodeEngine:
     prefill and the decode executables are built through the module
     compile engine with the page pool DONATED, so the cache updates in
     place (no 2x HBM) and identically-configured replicas share one
-    compile per bucket.  ``n_pages`` sizes the pool (default: room for
+    compile per width.  ``n_pages`` sizes the pool (default: room for
     ``n_slots`` sequences of the largest bucket, + the trash page);
     ``paged`` selects nothing — ``True`` is the only value, kept until
     the benchmark's callers stop passing it.
@@ -677,9 +684,8 @@ class DecodeEngine:
                     f"draft max_len {cfg_d.max_len} < largest bucket "
                     f"{self.buckets[-1]}: the draft mirrors target "
                     f"positions")
-        self._buckets: Dict[int, _Bucket] = {
-            t: _Bucket(t, self.n_slots, self.page_tokens)
-            for t in self.buckets}
+        self._slots = _SlotTable(self.n_slots,
+                                 self.buckets[-1] // self.page_tokens)
         verify_fn = None
         # the key captures everything that determines the traced
         # programs besides input shapes (``geo`` below extends it with
@@ -951,19 +957,27 @@ class DecodeEngine:
             f"request needs {total_len} positions; largest bucket is "
             f"{self.buckets[-1]} (model max_len {self.cfg.max_len})")
 
-    def free_slot(self, bucket: int) -> Optional[int]:
-        return self._buckets[bucket].free_slot()
+    def free_slot(self) -> Optional[int]:
+        for i, o in enumerate(self._slots.owners):
+            if o is None:
+                return i
+        return None
 
     def n_active(self) -> int:
-        return sum(b.n_active() for b in self._buckets.values())
+        return int(self._slots.active.sum())
 
-    def active_buckets(self) -> List[int]:
-        return [t for t, b in self._buckets.items() if b.n_active()]
+    def _width(self, top: int) -> int:
+        """The narrowest rung whose table covers positions below
+        ``top`` — the width a dispatch that writes through ``top - 1``
+        is handed; the widest when none does (a speculative round's
+        rows past the model's last position are out of every table and
+        land in the trash page)."""
+        return next((t for t in self.buckets if t >= top), self.buckets[-1])
 
     def _pool_state(self):
-        """Lazily materialize the page pool(s) — ONE pool shared by
-        every bucket (page shape is bucket-independent; only the page
-        TABLE width differs per bucket)."""
+        """Lazily materialize the page pool(s) — ONE pool for every
+        length (a page's shape does not depend on a rung; only the
+        page-table width a dispatch is handed does)."""
         if self._pool is None:
             pool = self._family.init_pages(self.cfg, self.n_kv_pages,
                                            self.page_tokens, self.kv_dtype)
@@ -984,16 +998,17 @@ class DecodeEngine:
     def _live_rows(self) -> int:
         """Token rows currently live across all slots — the
         page_utilization numerator."""
-        return int(sum(int(bb.pos_h[bb.active].sum())
-                       for bb in self._buckets.values()))
+        st = self._slots
+        return int(st.pos_h[st.active].sum())
 
     # -- admission / page tables -------------------------------------------
-    def can_admit(self, bucket: int, prompt_len: int) -> bool:
-        """Room for a request in ``bucket`` RIGHT NOW?  Slot
-        availability plus enough free pages for the prompt and its
-        first decode page.  In-flight growth past that STALLS rather
-        than deadlocks, so admission only gates on the prompt floor."""
-        if self._buckets[bucket].free_slot() is None:
+    def can_admit(self, prompt_len: int) -> bool:
+        """Room for a request RIGHT NOW?  A free slot (of the engine's
+        ``n_slots``, whatever the request's rung) plus enough free
+        pages for the prompt and its first decode page.  In-flight
+        growth past that STALLS rather than deadlocks, so admission
+        only gates on the prompt floor."""
+        if self.free_slot() is None:
             return False
         C = self.page_tokens
         needed = -(-prompt_len // C) + 1
@@ -1008,24 +1023,28 @@ class DecodeEngine:
         if needed > total:
             raise KVPagesExhausted(needed, total, self.n_kv_pages)
 
-    def last_ran(self, bucket: int) -> np.ndarray:
+    def last_ran(self) -> np.ndarray:
         """[S] mask of slots the last advance/advance_spec actually
         moved — a slot can STALL on page exhaustion (its token output
         is stale and must be ignored)."""
-        return self._buckets[bucket].ran.copy()
+        return self._slots.ran.copy()
 
-    def _ensure_pages(self, b: _Bucket, span: int) -> np.ndarray:
+    def _ensure_pages(self, span: int) -> np.ndarray:
         """Grow each active slot's page table to cover writes through
-        ``pos + span``.  Slots whose pages cannot be allocated STALL —
+        ``pos + span``, never past the slot's own rung (rows beyond it
+        are beyond the request's budget: they go to the trash page).
+        Slots whose pages cannot be allocated STALL —
         masked out of this dispatch, retried next — and when nothing
         active can run at all the deadlock breaker raises the typed
-        error naming a victim (the slot pinning the most pages, so
-        evicting it frees the most room).  Returns the runnable mask."""
+        error naming a victim (the slot pinning the most pages in the
+        engine, so evicting it frees the most room).  Returns the
+        runnable mask."""
+        b = self._slots
         run = b.active.copy()
         C = self.page_tokens
         for s in np.flatnonzero(b.active):
             need = int(b.pos_h[s] + span) // C + 1
-            need = min(need, b.ptab.shape[1])
+            need = min(need, int(b.rung[s]) // C)
             short = need - int(b.n_pages[s])
             if short <= 0:
                 continue
@@ -1040,11 +1059,11 @@ class DecodeEngine:
             victim = int(max(np.flatnonzero(b.active),
                              key=lambda s: int(b.n_pages[s])))
             raise KVPagesExhausted(1, self._alloc.n_free(),
-                                   self.n_kv_pages, bucket=b.t_max,
-                                   slot=victim)
+                                   self.n_kv_pages, slot=victim)
         return run
 
-    def _release_pages(self, b: _Bucket, slot: int) -> None:
+    def _release_pages(self, slot: int) -> None:
+        b = self._slots
         n = int(b.n_pages[slot])
         if n:
             self._alloc.free(int(p) for p in b.ptab[slot, :n])
@@ -1071,8 +1090,7 @@ class DecodeEngine:
         page table or the resident-prefix registry — nonzero means a
         reclaim path leaked (exported as the ``pages_leaked`` gauge,
         asserted zero by the chaos drill after drain)."""
-        accounted = sum(int(bb.n_pages.sum())
-                        for bb in self._buckets.values())
+        accounted = int(self._slots.n_pages.sum())
         accounted += sum(len(ids) for _, ids in self._resident.values())
         return self._alloc.total_refs() - accounted
 
@@ -1098,8 +1116,7 @@ class DecodeEngine:
                 return k * C, ent[1]
         return 0, None
 
-    def _resident_register(self, prompt: np.ndarray, b: _Bucket,
-                           slot: int) -> None:
+    def _resident_register(self, prompt: np.ndarray, slot: int) -> None:
         """Register the slot's chunk-aligned prompt prefix pages as
         pool-resident at every chunk boundary (so a partial prefix
         match still hits).  The registry holds its own reference on
@@ -1113,7 +1130,7 @@ class DecodeEngine:
         digs = PrefixCache._boundary_digests(prompt, C, m // C,
                                              self._prefix_space)
         for k in range(1, m // C + 1):
-            ids = tuple(int(p) for p in b.ptab[slot, :k])
+            ids = tuple(int(p) for p in self._slots.ptab[slot, :k])
             old = self._resident.pop(digs[k - 1], None)
             if old is not None:
                 self._alloc.free(old[1])
@@ -1230,13 +1247,13 @@ class DecodeEngine:
             t.join()
         self._harvest_thread = None
 
-    def _pad_pool_pages(self, pages: Sequence[np.ndarray], b: _Bucket):
+    def _pad_pool_pages(self, pages: Sequence[np.ndarray], tbl: int):
         """Re-chunk stored prefix rows [L, m, ...] into the write
-        executable's fixed page format [L, TBL, C, ...] (host-side; pad
-        pages land in the trash page, so one shape per bucket — a fresh
-        hit length never costs a trace)."""
+        executable's fixed page format [L, TBL, C, ...], ``tbl`` the
+        pages of the request's own rung (host-side; pad pages land in
+        the trash page, so one shape per rung — a fresh hit length
+        never costs a trace)."""
         C = self.page_tokens
-        tbl = b.ptab.shape[1]
         out = []
         for p in pages:
             buf = np.zeros((p.shape[0], tbl, C) + p.shape[2:], p.dtype)
@@ -1248,12 +1265,25 @@ class DecodeEngine:
         return out
 
     # -- AOT warmup --------------------------------------------------------
+    def _idle_step_args(self, bucket: int) -> Tuple[np.ndarray, ...]:
+        """What a decode (verify, draft) dispatch at ``bucket``'s width
+        takes behind the pool, with nothing running: a ZERO table slice
+        [S, bucket // C], zero tokens/pos, all-inactive, zero sampling
+        state — the shapes and types ``advance()`` dispatches with."""
+        S = self.n_slots
+        return (np.zeros((S, bucket // self.page_tokens), np.int32),
+                np.zeros((S,), np.int32), np.zeros((S,), np.int32),
+                np.zeros((S,), np.bool_), np.zeros((S,), np.float32),
+                np.zeros((S,), np.uint32))
+
     def warmup(self) -> dict:
-        """Pre-trace the prefill + decode executables for every bucket
-        (AOT; plus the prefix page read/write pair when a prefix store
-        is attached — a HIT must never trace), then reset the pool
+        """Pre-trace the prefill + decode executables at every width of
+        the ladder (AOT; plus the prefix page read/write pair when a
+        prefix store is attached — a HIT must never trace; plus the
+        draft/verify pair of a speculative engine), then reset the pool
         — steady-state traffic after this is compile-free for any
-        prompt length / join / prefix-reuse pattern.  Returns
+        prompt length / join / prefix-reuse pattern and for every
+        width the running slots can make a dispatch take.  Returns
         {"buckets": n, "compiles": traces, "warmup_ms": wall}."""
         from deeplearning4j_tpu.runtime.metrics import compile_metrics
 
@@ -1271,14 +1301,15 @@ class DecodeEngine:
         t0 = time.perf_counter()
         with telemetry.span("decode.warmup", buckets=len(self.buckets)):
             for t in self.buckets:
-                b = self._buckets[t]
                 toks = np.zeros((self.prefill_chunk,), np.int32)
                 # all warmup dispatches run with ZERO page tables
                 # and all-inactive masks: every write lands in the
                 # trash page, the allocator is untouched, and the
                 # pool is dropped afterwards anyway
                 pool = self._pool_state()
-                ptab_s = np.zeros((b.ptab.shape[1],), np.int32)
+                ptab, tokens, pos, idle, temps, seeds = \
+                    self._idle_step_args(t)
+                ptab_s = ptab[0]
                 pool, _ = self._prefill(
                     params, pool, ptab_s, toks, np.int32(0),
                     np.int32(1), np.float32(0.0), np.uint32(0))
@@ -1292,18 +1323,15 @@ class DecodeEngine:
                         self._draft_params, self._dpool, ptab_s,
                         toks, np.int32(0), np.int32(1))
                     self._dpool, props = self._draft_fn(
-                        self._draft_params, self._dpool,
-                        b.ptab.copy(), b.tokens_h.copy(),
-                        b.pos_h.copy(), b.active.copy())
+                        self._draft_params, self._dpool, ptab, tokens,
+                        pos, idle)
                     pool, _, _ = self._verify(
-                        params, pool, b.ptab.copy(),
-                        b.tokens_h.copy(), b.pos_h.copy(),
-                        b.active.copy(), b.temps, b.seeds, props)
+                        params, pool, ptab, tokens, pos, idle, temps,
+                        seeds, props)
                     self._pool = pool
                 pool, out = self._decode(
-                    params, self._pool, b.ptab.copy(),
-                    b.tokens_h.copy(), b.pos_h.copy(),
-                    b.active.copy(), b.temps, b.seeds)
+                    params, self._pool, ptab, tokens, pos, idle, temps,
+                    seeds)
                 self._pool = pool
                 jax.block_until_ready(out)
             # warmup scribbled on the shared pools; re-init lazily so
@@ -1318,47 +1346,52 @@ class DecodeEngine:
         return {"buckets": len(self.buckets), "compiles": compiles,
                 "warmup_ms": round(wall_ms, 1)}
 
-    def decode_hlo(self, bucket: int) -> str:
-        """Optimized HLO text of ``bucket``'s decode step as compiled
-        for this engine, every instruction with XLA's ``op_name`` (the
-        ``jax.named_scope`` path it was traced under).  A device trace
-        names its ops by instruction and carries no ``op_name``; a
-        reader that wants device time by scope joins the two on the
-        instruction.  Traces and lowers the step again (the executable
-        comes from the compile cache): for set-up, never for the
-        serving thread."""
-        b = self._buckets[bucket]
+    def _lower_decode(self, bucket: int):
+        """The decode step lowered for this engine at ``bucket``'s
+        table width (traced again; compiling it hits the cache): for
+        set-up, never for the serving thread."""
         return self._decode.jitted.lower(
-            self.current_params(), self._pool_state(), b.ptab, b.tokens_h,
-            b.pos_h, b.active, b.temps, b.seeds).compile().as_text()
+            self.current_params(), self._pool_state(),
+            *self._idle_step_args(bucket))
+
+    def decode_hlo(self, bucket: int) -> str:
+        """Optimized HLO text of the decode step at ``bucket``'s table
+        width as compiled for this engine, every instruction with XLA's
+        ``op_name`` (the ``jax.named_scope`` path it was traced under).
+        A device trace names its ops by instruction and carries no
+        ``op_name``; a reader that wants device time by scope joins the
+        two on the instruction."""
+        return self._lower_decode(bucket).compile().as_text()
 
     # -- serving -----------------------------------------------------------
     def start(self, prompt: np.ndarray, *, max_tokens: int,
               temperature: float = 0.0, seed: int = 0,
-              owner: Any = True) -> Tuple[int, int, int]:
-        """Prefill ``prompt`` [T_p] int32 into a free slot of the bucket
-        fitting ``T_p + max_tokens`` and return (bucket, slot,
-        first_token).  The other slots' decode state rides along
-        untouched — this is the mid-flight JOIN.  Raises RuntimeError
-        when the bucket has no free slot (callers gate on
-        ``free_slot``)."""
+              owner: Any = True) -> Tuple[int, int]:
+        """Prefill ``prompt`` [T_p] int32 into a free slot and return
+        (slot, first_token).  The prefill runs at the table width of
+        the request's OWN rung, ``pick_bucket(T_p + max_tokens)`` (the
+        slot's page row never grows past it), whatever the other slots
+        hold; their decode state rides along untouched — this is the
+        mid-flight JOIN.  Raises RuntimeError when the engine has no
+        free slot (callers gate on ``free_slot``)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
         if max_tokens < 1:
             raise ValueError(f"max_tokens must be >= 1: {max_tokens}")
         bucket = self.pick_bucket(prompt.size + max_tokens)
-        b = self._buckets[bucket]
-        slot = b.free_slot()
+        b = self._slots
+        slot = self.free_slot()
         if slot is None:
-            raise RuntimeError(f"no free slot in bucket {bucket}")
+            raise RuntimeError(
+                f"no free slot: all {self.n_slots} are taken")
         rid = getattr(owner, "rid", None)
         self.check_capacity(prompt.size)
         params = self.current_params()
         pool = self._pool_state()
         C = self.page_tokens
         n_chunks = -(-prompt.size // C)
-        tbl = b.ptab.shape[1]
+        tbl = bucket // C
         # prefix reuse, best first: (1) pool-RESIDENT pages mount into
         # the page table BY REFERENCE — no copy, no dispatch; (2) the
         # host PrefixCache (shared across replicas) copies pages into
@@ -1398,14 +1431,14 @@ class DecodeEngine:
                     pids = np.zeros((tbl,), np.int32)
                     pids[:h] = b.ptab[slot, :h]
                     self._pool = pool = self._write(
-                        pool, pids, *self._pad_pool_pages(host_pages, b))
+                        pool, pids, *self._pad_pool_pages(host_pages, tbl))
                 for c in range(h, n_chunks):
                     lo = c * C
                     n_valid = min(C, prompt.size - lo)
                     chunk = np.zeros((C,), np.int32)
                     chunk[:n_valid] = prompt[lo:lo + n_valid]
                     pool, first = self._prefill(
-                        params, pool, b.ptab[slot].copy(), chunk,
+                        params, pool, b.ptab[slot, :tbl].copy(), chunk,
                         np.int32(lo), np.int32(n_valid),
                         np.float32(temperature), np.uint32(seed))
                     self._pool = pool
@@ -1421,18 +1454,18 @@ class DecodeEngine:
                         chunk[:n_valid] = prompt[lo:lo + n_valid]
                         self._dpool = self._draft_prefill(
                             self._draft_params, self._dpool,
-                            b.ptab[slot].copy(), chunk, np.int32(lo),
-                            np.int32(n_valid))
+                            b.ptab[slot, :tbl].copy(), chunk,
+                            np.int32(lo), np.int32(n_valid))
             except Exception:
                 # the pool was donated into the failed dispatch — every
-                # bucket's KV is gone; drop it so serving
+                # slot's KV is gone; drop it so serving
                 # re-initializes instead of touching deleted buffers.
                 # FIRST return this slot's page-table references
                 # (resident-hit shares AND fresh pages) to the
                 # allocator: the failed dispatch destroyed the KV
                 # bytes, but the allocator's bookkeeping is host-side —
                 # skipping this leaked the pages until engine teardown
-                self._release_pages(b, slot)
+                self._release_pages(slot)
                 self._drop_pool()
                 raise
             with telemetry.span("decode.prefill.sync",
@@ -1451,7 +1484,7 @@ class DecodeEngine:
             # harvest: register the prefix pages pool-resident (no
             # dispatch — the registry just refs the page ids) and, with
             # a host store attached, enqueue the cross-replica fetch
-            self._resident_register(prompt, b, slot)
+            self._resident_register(prompt, slot)
             if self._prefix is not None:
                 pids = np.zeros((tbl,), np.int32)
                 pids[:m_store // C] = b.ptab[slot, :m_store // C]
@@ -1469,25 +1502,45 @@ class DecodeEngine:
         b.temps[slot] = np.float32(temperature)
         b.seeds[slot] = np.uint32(seed)
         b.owners[slot] = owner
-        return bucket, slot, first_tok
+        b.rung[slot] = bucket
+        return slot, first_tok
 
-    def advance(self, bucket: int) -> np.ndarray:
-        """One decode dispatch for ``bucket``: every active slot emits
-        its next token.  Returns the [S] token array (entries for
-        inactive slots are stale and must be ignored via the caller's
-        ownership map)."""
-        b = self._buckets[bucket]
-        n_act = b.n_active()
+    def _stage(self, span: int):
+        """What one dispatch for every running slot takes: the pages
+        through ``pos + span`` allocated, the runnable mask, and the
+        table at the narrowest rung's width ``w`` that covers the
+        longest RUNNING slot's writes — read off the live positions,
+        so a dispatch never gathers more than the longest live context
+        needs, and never more than the widest live rung's own dispatch
+        did when each rung made one.  Returns (ptab [S, w // C],
+        tokens, pos, run, w, rungs), ``rungs`` the distinct rungs of
+        the requests it carries (the dispatches a table a rung would
+        have made)."""
+        b = self._slots
+        run = self._ensure_pages(span)
+        b.ran = run
+        top = int(b.pos_h[run].max()) + 1 + span if run.any() else 0
+        w = self._width(top)
+        rungs = len(set(b.rung[run].tolist()))
+        return (b.ptab[:, :w // self.page_tokens].copy(),
+                b.tokens_h.copy(), b.pos_h.copy(), run, w, rungs)
+
+    def advance(self) -> np.ndarray:
+        """ONE decode dispatch for the engine: every active slot, of
+        whatever rung, emits its next token, at the narrowest table
+        width that covers the longest running one (:meth:`_stage`).
+        Returns the [S] token array (entries for inactive slots are
+        stale and must be ignored via the caller's ownership map;
+        stalled ones via :meth:`last_ran`)."""
+        b = self._slots
         with telemetry.span("decode.advance",
                             counter=(decode_metrics, "advance_s"),
-                            bucket=bucket, active=n_act):
+                            active=self.n_active()) as sp:
             params = self.current_params()
             with telemetry.span("decode.stage"):
-                run = self._ensure_pages(b, 0)
-                b.ran = run
+                ptab, tokens, pos, run, w, rungs = self._stage(0)
+                sp.set(width=w, rungs=rungs)
                 pool = self._pool_state()
-                ptab, tokens, pos = (b.ptab.copy(), b.tokens_h.copy(),
-                                     b.pos_h.copy())
             with telemetry.span("decode.dispatch"):
                 try:
                     pool, out = self._decode(params, pool, ptab, tokens,
@@ -1505,7 +1558,8 @@ class DecodeEngine:
                                                   counts)
             b.tokens_h[run] = toks[run]
             b.pos_h[run] += 1
-            decode_metrics.note_decode_dispatch(int(run.sum()), self.n_slots)
+            decode_metrics.note_decode_dispatch(
+                int(run.sum()), self.n_slots, rungs, self.n_slots * w)
             decode_metrics.note_pages(self._alloc.in_use(),
                                       self._live_rows(), self.page_tokens)
             return toks
@@ -1519,8 +1573,10 @@ class DecodeEngine:
                             counter=(decode_metrics, "fetch_s")):
             return np.asarray(out)  # jaxlint: disable=host-sync-on-serving-worker — the per-step token fetch IS the stream
 
-    def advance_spec(self, bucket: int) -> Tuple[np.ndarray, np.ndarray]:
-        """One SPECULATIVE round for ``bucket``: the draft proposes
+    def advance_spec(self) -> Tuple[np.ndarray, np.ndarray]:
+        """One SPECULATIVE round for the engine, over the same slot
+        table and at the width :meth:`_stage` reads off it (through
+        ``pos + draft_k``): the draft proposes
         ``draft_k`` tokens per slot in one dispatch (proposals stay on
         device), the target verifies all k+1 positions in ONE batched
         dispatch, and the longest accepted prefix (+ the target's own
@@ -1532,22 +1588,20 @@ class DecodeEngine:
         step-keyed."""
         if self._draft_fn is None:
             raise RuntimeError("engine built without draft=")
-        b = self._buckets[bucket]
+        b = self._slots
         k = self.draft_k
         with telemetry.span("decode.advance",
                             counter=(decode_metrics, "advance_s"),
-                            bucket=bucket, active=b.n_active(), k=k):
+                            active=self.n_active(), k=k) as sp:
             params = self.current_params()
             with telemetry.span("decode.stage"):
-                run = self._ensure_pages(b, k)
-                b.ran = run
-                pool = self._pool_state()
                 # the draft is handed the verified frontier: its rows
                 # below it hold exactly the committed tokens' KV
                 # (accepted proposals consumed them), so no re-sync
                 # dispatch is ever needed
-                ptab, tokens, pos = (b.ptab.copy(), b.tokens_h.copy(),
-                                     b.pos_h.copy())
+                ptab, tokens, pos, run, w, rungs = self._stage(k)
+                sp.set(width=w, rungs=rungs)
+                pool = self._pool_state()
             with telemetry.span("decode.dispatch"):
                 try:
                     self._dpool, props = self._draft_fn(
@@ -1568,23 +1622,25 @@ class DecodeEngine:
             b.tokens_h[idx] = toks[idx, n_c[idx] - 1]
             b.pos_h += n_c.astype(np.int32)
             n_run = int(run.sum())
-            decode_metrics.note_decode_dispatch(n_run, self.n_slots)
+            decode_metrics.note_decode_dispatch(
+                n_run, self.n_slots, rungs, self.n_slots * w)
             decode_metrics.note_spec(k * n_run,
                                      int(np.maximum(n_c - 1, 0).sum()))
             decode_metrics.note_pages(self._alloc.in_use(),
                                       self._live_rows(), self.page_tokens)
             return toks, n_c
 
-    def release(self, bucket: int, slot: int) -> None:
+    def release(self, slot: int) -> None:
         """Free a finished slot and return its page-table references
         to the allocator (pool-resident prefix pages survive: the
         registry holds its own reference) — the cache rows need no
         scrubbing: a future occupant of a page prefills its prompt over
         them and decode never attends past its own position."""
-        b = self._buckets[bucket]
+        b = self._slots
         b.active[slot] = False
         b.owners[slot] = None
-        self._release_pages(b, slot)
+        b.rung[slot] = 0
+        self._release_pages(slot)
 
 
 class DecodeRequest:
@@ -1784,9 +1840,9 @@ class _ReplayRequest(DecodeRequest):
 class ContinuousBatcher:
     """Streaming front-end over a ``DecodeEngine``: one worker thread
     admits pending requests into free slots (prefill joins between
-    decode steps), advances every occupied bucket one token per
-    iteration, recycles slots on EOS/budget, and resolves
-    ``DecodeRequest`` handles.  ``close()`` drains: accepted requests
+    decode steps), advances every occupied slot one token per
+    iteration in ONE dispatch, recycles slots on EOS/budget, and
+    resolves ``DecodeRequest`` handles.  ``close()`` drains: accepted requests
     run to completion, then the worker exits."""
 
     #: a request is requeued at most this many times after failed
@@ -1807,7 +1863,8 @@ class ContinuousBatcher:
         #: mid-admit requests, or the router's shed bound would admit
         #: over capacity through the pop-to-place window
         self._admitting: List[DecodeRequest] = []
-        self._placed: Dict[Tuple[int, int], DecodeRequest] = {}
+        #: slot -> the request it holds
+        self._placed: Dict[int, DecodeRequest] = {}
         self._open = True
         #: health surface the router's monitor polls (plain reads of
         #: worker-written fields — a torn read costs one poll):
@@ -1933,12 +1990,10 @@ class ContinuousBatcher:
                 for i, r in enumerate(self._pending):
                     # a REPLAYED request re-prefills prompt + emitted
                     # (len(r._tokens) is worker-written only — this IS
-                    # the worker); its bucket is unchanged because
+                    # the worker); its rung is unchanged because
                     # emitted tokens move from budget to prompt 1:1
-                    bucket = self.engine.pick_bucket(
-                        r.prompt.size + r.max_tokens)
                     if self.engine.can_admit(
-                            bucket, r.prompt.size + len(r._tokens)):
+                            r.prompt.size + len(r._tokens)):
                         req = self._pending.pop(i)
                         self._admitting.append(req)
                         break
@@ -1961,7 +2016,7 @@ class ContinuousBatcher:
                 # whether p was reached by decode here or by prefilling
                 # the journaled stream — prefix-cache hits make the
                 # re-prefill cheap
-                bucket, slot, first = self.engine.start(
+                slot, first = self.engine.start(
                     eff_prompt,
                     max_tokens=req.max_tokens - emitted.size,
                     temperature=req.temperature, seed=req.seed,
@@ -1974,107 +2029,104 @@ class ContinuousBatcher:
                 continue
             if joined:
                 decode_metrics.note_join()
-            telemetry.event("decode.join", rid=req.rid, bucket=bucket,
-                            slot=slot, prompt_tokens=int(eff_prompt.size),
+            telemetry.event("decode.join", rid=req.rid, slot=slot,
+                            prompt_tokens=int(eff_prompt.size),
                             mid_flight=joined, replayed=bool(emitted.size))
             admitted += 1
             with self._cv:
                 self._last_progress = time.perf_counter()
                 if req in self._admitting:   # evacuate() may have
                     self._admitting.remove(req)  # adopted it mid-start
-                self._placed[(bucket, slot)] = req
+                self._placed[slot] = req
             req._push(first)
-            self._maybe_finish(bucket, slot, req, first,
-                               n_out=len(req._tokens))
+            self._maybe_finish(slot, req, first, n_out=len(req._tokens))
 
-    def _maybe_finish(self, bucket: int, slot: int, req: DecodeRequest,
-                      tok: int, n_out: int) -> bool:
+    def _maybe_finish(self, slot: int, req: DecodeRequest, tok: int,
+                      n_out: int) -> bool:
         if (req.eos_id is not None and tok == req.eos_id) \
                 or n_out >= req.max_tokens:
-            self.engine.release(bucket, slot)
+            self.engine.release(slot)
             with self._cv:
-                self._placed.pop((bucket, slot), None)
+                self._placed.pop(slot, None)
             decode_metrics.note_complete(n_out)
             req._finish()
-            telemetry.event("decode.complete", rid=req.rid, bucket=bucket,
-                            slot=slot, tokens=n_out,
+            telemetry.event("decode.complete", rid=req.rid, slot=slot,
+                            tokens=n_out,
                             ttft_ms=round(req.ttft_ms or 0.0, 3))
             return True
         return False
 
     def _advance_all(self) -> int:
-        """One dispatch per active bucket; returns how many ran."""
+        """ONE dispatch for every running slot, whatever their rungs,
+        and its tokens delivered; returns how many dispatches ran (0 or
+        1)."""
+        if not self.engine.n_active():
+            return 0
         spec = self.engine.draft is not None and self.engine.spec_enabled
-        advanced = 0
-        for bucket in self.engine.active_buckets():
-            try:
-                if spec:
-                    out, n_c = self.engine.advance_spec(bucket)
-                else:
-                    toks = self.engine.advance(bucket)
-            except KVPagesExhausted as e:
-                # page deadlock breaker: the pool cannot advance ANY
-                # slot in this bucket — evict the named victim (typed
-                # error to its client; its pages free the others)
-                if e.slot is None:
-                    raise
-                with self._cv:
-                    r = self._placed.pop((bucket, e.slot), None)
-                self.engine.release(bucket, e.slot)
-                if r is not None:
+        try:
+            if spec:
+                out, n_c = self.engine.advance_spec()
+            else:
+                toks = self.engine.advance()
+        except KVPagesExhausted as e:
+            # page deadlock breaker: the pool cannot advance ANY slot —
+            # evict the named victim (typed error to its client; its
+            # pages free the others)
+            if e.slot is None:
+                raise
+            with self._cv:
+                r = self._placed.pop(e.slot, None)
+            self.engine.release(e.slot)
+            if r is not None:
+                r._finish(e)
+            return 0
+        except Exception as e:
+            # a failed dispatch poisons in-flight device state (it
+            # was donated): the failure drops the pool, so EVERY slot's
+            # KV is gone.  Free every slot (the page reclaim is
+            # host-side bookkeeping and stays valid) and REPLAY the
+            # requests instead of dooming them: re-admitted as (prompt +
+            # emitted), each continues bit-identically.  Past the
+            # replay budget the error resolves the future — a
+            # deterministic dispatch bug must not requeue forever.
+            self.dispatch_error_streak += 1
+            with self._cv:
+                affected = list(self._placed.items())
+                self._placed.clear()
+            replay = []
+            for slot, r in affected:
+                self.engine.release(slot)
+                if r._replays >= self.MAX_REPLAYS:
                     r._finish(e)
-                continue
-            except Exception as e:
-                # a failed dispatch poisons in-flight device state (it
-                # was donated): the failure drops the shared pool, so
-                # EVERY bucket's KV is gone, not just this one's.  Free
-                # every slot (the page reclaim is host-side
-                # bookkeeping and stays valid) and REPLAY the requests
-                # instead of dooming them: re-admitted as (prompt +
-                # emitted), each continues bit-identically.  Past the
-                # replay budget the error resolves the future — a
-                # deterministic dispatch bug must not requeue forever.
-                self.dispatch_error_streak += 1
+                else:
+                    r._replays += 1
+                    replay.append(r)
+                    decode_metrics.note_request_replayed()
+            if replay:
                 with self._cv:
-                    affected = list(self._placed.items())
-                    self._placed.clear()
-                replay = []
-                for (bk, slot), r in affected:
-                    self.engine.release(bk, slot)
-                    if r._replays >= self.MAX_REPLAYS:
-                        r._finish(e)
-                    else:
-                        r._replays += 1
-                        replay.append(r)
-                        decode_metrics.note_request_replayed()
-                if replay:
-                    with self._cv:
-                        self._pending[:0] = replay
-                continue
-            advanced += 1
-            self.dispatch_error_streak = 0
-            with telemetry.span("decode.deliver"):
-                ran = self.engine.last_ran(bucket)
-                with self._cv:
-                    self._last_progress = time.perf_counter()
-                    owned = [(k, r) for k, r in self._placed.items()
-                             if k[0] == bucket]
-                for (bk, slot), r in owned:
-                    if not ran[slot]:
-                        continue    # stalled on pages; retried next pass
-                    if spec:
-                        for j in range(int(n_c[slot])):
-                            tok = int(out[slot, j])
-                            r._push(tok)
-                            if self._maybe_finish(bk, slot, r, tok,
-                                                  n_out=len(r._tokens)):
-                                break
-                    else:
-                        tok = int(toks[slot])
+                    self._pending[:0] = replay
+            return 0
+        self.dispatch_error_streak = 0
+        with telemetry.span("decode.deliver"):
+            ran = self.engine.last_ran()
+            with self._cv:
+                self._last_progress = time.perf_counter()
+                owned = list(self._placed.items())
+            for slot, r in owned:
+                if not ran[slot]:
+                    continue        # stalled on pages; retried next pass
+                if spec:
+                    for j in range(int(n_c[slot])):
+                        tok = int(out[slot, j])
                         r._push(tok)
-                        self._maybe_finish(bk, slot, r, tok,
-                                           n_out=len(r._tokens))
-        return advanced
+                        if self._maybe_finish(slot, r, tok,
+                                              n_out=len(r._tokens)):
+                            break
+                else:
+                    tok = int(toks[slot])
+                    r._push(tok)
+                    self._maybe_finish(slot, r, tok, n_out=len(r._tokens))
+        return 1
 
     def _expire(self) -> None:
         """Free every deadline-expired request (worker thread): queued
@@ -2087,12 +2139,12 @@ class ContinuousBatcher:
             exp_q = [r for r in self._pending if r._expired(now)]
             for r in exp_q:
                 self._pending.remove(r)
-            exp_s = [(k, r) for k, r in self._placed.items()
+            exp_s = [(slot, r) for slot, r in self._placed.items()
                      if r._expired(now)]
-            for k, _ in exp_s:
-                self._placed.pop(k, None)
-        for (bucket, slot), _ in exp_s:
-            self.engine.release(bucket, slot)
+            for slot, _ in exp_s:
+                self._placed.pop(slot, None)
+        for slot, _ in exp_s:
+            self.engine.release(slot)
         for r in exp_q + [r for _, r in exp_s]:
             decode_metrics.note_deadline_expiration()
             r._finish(DeadlineExceeded(

@@ -9,16 +9,20 @@ reproducibility across placements, router least-depth dispatch and
 load-shedding, and the zero-steady-state-compile contract.
 """
 
+import dataclasses
 import threading
 import time
+from typing import Any, Callable, NamedTuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.models import gpt
+from deeplearning4j_tpu.models import deepseek_v2 as ds, gpt
 from deeplearning4j_tpu.models.transformer import TransformerConfig
-from deeplearning4j_tpu.runtime.metrics import decode_metrics
+from deeplearning4j_tpu.runtime.metrics import (compile_metrics,
+                                                decode_metrics)
 from deeplearning4j_tpu.serving.decode import (ContinuousBatcher,
                                                DecodeEngine, PageAllocator,
                                                default_length_buckets)
@@ -124,26 +128,24 @@ def test_mid_flight_join_token_parity(params, engine):
     pb = rng.randint(1, CFG.vocab_size, size=11).astype(np.int32)
     n_a, n_b = 12, 9
 
-    bucket, slot_a, first_a = engine.start(pa, max_tokens=n_a,
-                                           owner="A")
+    slot_a, first_a = engine.start(pa, max_tokens=n_a, owner="A")
     toks_a = [first_a]
     for _ in range(4):                       # A decodes alone ...
-        toks_a.append(int(engine.advance(bucket)[slot_a]))
+        toks_a.append(int(engine.advance()[slot_a]))
 
     joins_before = decode_metrics.snapshot()["joins"]
     assert engine.n_active() == 1
-    bucket_b, slot_b, first_b = engine.start(pb, max_tokens=n_b,
-                                             owner="B")
-    assert bucket_b == bucket and slot_b != slot_a   # joined, mid-flight
+    slot_b, first_b = engine.start(pb, max_tokens=n_b, owner="B")
+    assert slot_b != slot_a                          # joined, mid-flight
     toks_b = [first_b]
     while len(toks_a) < n_a or len(toks_b) < n_b:    # ... then together
-        out = engine.advance(bucket)
+        out = engine.advance()
         if len(toks_a) < n_a:
             toks_a.append(int(out[slot_a]))
         if len(toks_b) < n_b:
             toks_b.append(int(out[slot_b]))
-    engine.release(bucket, slot_a)
-    engine.release(bucket, slot_b)
+    engine.release(slot_a)
+    engine.release(slot_b)
 
     np.testing.assert_array_equal(toks_a, _solo(params, pa, n_a))
     np.testing.assert_array_equal(toks_b, _solo(params, pb, n_b))
@@ -216,7 +218,7 @@ def test_eos_ends_early_and_recycles_slots(params, engine):
         for r in outs:
             assert r.result(120).shape == (5,)
     assert engine.n_active() == 0
-    assert all(b.free_slot() == 0 for b in engine._buckets.values())
+    assert engine.free_slot() == 0
 
 
 def test_request_streaming_matches_result(params, engine):
@@ -375,6 +377,233 @@ def test_close_drains_accepted_requests(params, engine):
     assert h.result(1).shape == (10,)        # ran to completion
     with pytest.raises(RuntimeError, match="closed"):
         cb.submit(rng.randint(1, 64, size=5))
+
+
+# -- one slot table, one dispatch a round -----------------------------------
+#
+# A rung of the ladder is a compiled page-table WIDTH: requests of every
+# rung sit in the engine's one table of ``n_slots`` slots and advance in
+# ONE dispatch, at the narrowest width that covers the longest running
+# one.  Both model families, one engine each for the whole section.
+
+LADDER = (32, 64, 128)
+
+
+class _Family(NamedTuple):
+    name: str
+    cfg: Any
+    engine: DecodeEngine
+    solo: Callable            # (prompt, n) -> the n greedy tokens
+
+
+def _dense_greedy(forward, vocab_rows, prompt, n):
+    """Greedy continuation by the family's cache-less forward over the
+    whole row, padded to the model's positions (causal: the padding is
+    never attended), one token at a time."""
+    row = [int(t) for t in prompt]
+    for _ in range(n):
+        ids = np.zeros((1, LADDER[-1]), np.int32)
+        ids[0, :len(row)] = row
+        logits = np.asarray(forward(jnp.asarray(ids)))[0, len(row) - 1]
+        row.append(int(np.argmax(logits[:vocab_rows])))
+    return row[len(prompt):]
+
+
+@pytest.fixture(scope="module", params=["gpt", "deepseek_v2"])
+def family(request):
+    if request.param == "gpt":
+        cfg = dataclasses.replace(CFG, max_len=LADDER[-1])
+        params = gpt.init_params(jax.random.key(7), cfg)
+
+        def solo(prompt, n):
+            out = gpt.generate(cfg, params,
+                               np.asarray(prompt, np.int32)[None, :], n,
+                               jax.random.key(0), temperature=0.0)
+            return [int(t) for t in np.asarray(out)[0]]
+    else:
+        cfg = ds.tiny_config(max_len=LADDER[-1], compute_dtype="float32")
+        params = ds.init_params(jax.random.key(0), cfg, std=0.3)
+        forward = jax.jit(lambda ids: ds.forward_logits(cfg, params, ids))
+
+        def solo(prompt, n):
+            return _dense_greedy(forward, cfg.vocab_size, prompt, n)
+    eng = DecodeEngine(cfg, params, n_slots=3, buckets=LADDER,
+                       prefill_chunk=8, label=f"one-table-{request.param}")
+    eng.warmup()
+    yield _Family(request.param, cfg, eng, solo)
+    assert _every_page_is_back(eng)
+
+
+def _prompt(fam, n, seed):
+    return np.random.RandomState(seed).randint(
+        1, fam.cfg.vocab_size, size=n).astype(np.int32)
+
+
+def _counts():
+    snap = decode_metrics.snapshot()
+    return {k: snap[k] for k in ("decode_dispatches", "decode_dispatch_rungs",
+                                 "decode_table_rows", "rounds")}
+
+
+def _delta(before):
+    return {k: v - before[k] for k, v in _counts().items()}
+
+
+def test_three_rungs_advance_in_one_dispatch(family):
+    """Requests of three different rungs run together: ONE dispatch
+    carries all three (the parent made three), and each request's
+    tokens are its stand-alone reference stream bit for bit — by hand
+    on the engine, then through the batcher, a round a dispatch."""
+    eng, n = family.engine, 12
+    prompts = [_prompt(family, t, 20 + t) for t in (5, 40, 90)]
+    assert [eng.pick_bucket(p.size + n) for p in prompts] == list(LADDER)
+    refs = [family.solo(p, n) for p in prompts]
+
+    before = _counts()
+    placed = [eng.start(p, max_tokens=n) for p in prompts]
+    outs = [[first] for _, first in placed]
+    for _ in range(n - 1):
+        toks = eng.advance()
+        assert eng.last_ran().all()
+        for out, (slot, _) in zip(outs, placed):
+            out.append(int(toks[slot]))
+    for slot, _ in placed:
+        eng.release(slot)
+    assert outs == refs
+    got = _delta(before)
+    assert got["decode_dispatches"] == n - 1
+    assert got["decode_dispatch_rungs"] == 3 * (n - 1)
+
+    before = _counts()
+    with ContinuousBatcher(eng) as cb:
+        handles = [cb.submit(p, max_tokens=n) for p in prompts]
+        served = [h.result(120).tolist() for h in handles]
+    assert served == refs
+    got = _delta(before)
+    assert got["decode_dispatches"] == got["rounds"] > 0
+    assert got["decode_dispatch_rungs"] > got["decode_dispatches"]
+
+
+def test_width_follows_the_longest_running_slot(family):
+    """A request that crosses a rung edge mid-flight (its step at
+    position 63 reads a 64-wide table, the one at 64 a 128-wide one)
+    widens the dispatch at that step and the dispatch narrows again
+    when it leaves; tokens are the references', and nothing is traced
+    or compiled across the changes."""
+    eng = family.engine
+    long_p, short_p = _prompt(family, 60, 1), _prompt(family, 5, 2)
+    n_long, n_short = 8, 14
+    ref_long = family.solo(long_p, n_long)
+    ref_short = family.solo(short_p, n_short)
+    compiles = compile_metrics.snapshot()["compile_count"]
+
+    s_long, first = eng.start(long_p, max_tokens=n_long)
+    out_long = [first]
+    s_short, first = eng.start(short_p, max_tokens=n_short)
+    out_short = [first]
+    widths = []
+    while len(out_short) < n_short:
+        before = _counts()
+        toks = eng.advance()
+        widths.append(_delta(before)["decode_table_rows"] // eng.n_slots)
+        if len(out_long) < n_long:
+            out_long.append(int(toks[s_long]))
+            if len(out_long) == n_long:
+                eng.release(s_long)
+        out_short.append(int(toks[s_short]))
+    eng.release(s_short)
+    # the long request feeds positions 60..66, then the short one is
+    # alone at positions 12..17
+    assert widths == [64] * 4 + [128] * 3 + [32] * 6
+    assert out_long == ref_long and out_short == ref_short
+    assert compile_metrics.snapshot()["compile_count"] == compiles
+
+
+def test_n_slots_bounds_the_engine_not_a_rung(family):
+    """``n_slots`` requests of mixed rungs fill the engine: one more,
+    of any rung, waits in the queue and is admitted when any slot
+    frees; every page is back after ``close()``."""
+    eng = family.engine
+    prompts = [_prompt(family, t, 40 + t) for t in (4, 36, 80)]
+    n = 30
+    fourth = _prompt(family, 6, 9)
+    refs = [family.solo(p, n) for p in prompts] + [family.solo(fourth, 5)]
+    with ContinuousBatcher(eng) as cb:
+        handles = [cb.submit(p, max_tokens=n) for p in prompts]
+        streams = [h.stream(60) for h in handles]
+        for st in streams:
+            next(st)                         # all three are placed
+        assert eng.free_slot() is None and not eng.can_admit(1)
+        with pytest.raises(RuntimeError, match="no free slot"):
+            eng.start(fourth, max_tokens=5)
+        late = cb.submit(fourth, max_tokens=5)        # the smallest rung
+        for _ in range(3):                   # rounds pass, it still waits:
+            next(streams[0])                 # no slot frees before token 30
+        assert len(late._tokens) == 0 and cb.depth() == 4
+        outs = [h.result(120).tolist() for h in handles]
+        outs.append(late.result(120).tolist())
+    assert outs == refs
+    assert _every_page_is_back(eng)
+
+
+def test_dispatch_counters_against_a_hand_count(family):
+    """``decode_dispatch_rungs`` sums the distinct rungs a dispatch
+    carried, ``decode_table_rows`` ``n_slots`` x its table width."""
+    eng = family.engine
+    S = eng.n_slots
+    before = _counts()
+    occupancy = decode_metrics.slot_steps
+    a, _ = eng.start(_prompt(family, 5, 3), max_tokens=4)       # rung 32
+    b, _ = eng.start(_prompt(family, 70, 4), max_tokens=6)      # rung 128
+    for _ in range(3):
+        eng.advance()               # both: 2 rungs, b at 70..72 -> 128
+    eng.release(a)
+    for _ in range(2):
+        eng.advance()               # b alone, still 128 wide
+    eng.release(b)
+    c, _ = eng.start(_prompt(family, 3, 5), max_tokens=3)
+    for _ in range(2):
+        eng.advance()               # a lone short request: 32 wide
+    eng.release(c)
+    assert _delta(before) == {
+        "decode_dispatches": 7, "decode_dispatch_rungs": 2 * 3 + 2 + 2,
+        "decode_table_rows": S * 128 * 5 + S * 32 * 2, "rounds": 0}
+    assert decode_metrics.slot_steps - occupancy == 2 * 3 + 2 + 2
+
+
+def test_speculative_rounds_share_the_one_table(params):
+    """The speculative path on the one table: mixed-rung requests get
+    one draft + verify pair a round, and every stream is the
+    non-speculative engine's bit for bit."""
+    cfg = dataclasses.replace(CFG, max_len=LADDER[-1])
+    dcfg = dataclasses.replace(cfg, hidden=16, n_layers=1, ffn_dim=32)
+    tparams = gpt.init_params(jax.random.key(7), cfg)
+    dparams = gpt.init_params(jax.random.key(8), dcfg)
+    kw = dict(n_slots=3, buckets=LADDER, prefill_chunk=8)
+    plain = DecodeEngine(cfg, tparams, label="one-table-plain", **kw)
+    spec = DecodeEngine(cfg, tparams, label="one-table-spec",
+                        draft=(dcfg, dparams), draft_k=3, **kw)
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(1, cfg.vocab_size, size=t).astype(np.int32)
+               for t in (6, 38, 85, 20)]
+    budgets = [20, 24, 30, 9]
+    assert {plain.pick_bucket(p.size + m)
+            for p, m in zip(prompts, budgets)} == set(LADDER)
+    streams = []
+    for eng in (plain, spec):
+        eng.warmup()
+        before = _counts()
+        with ContinuousBatcher(eng) as cb:
+            handles = [cb.submit(p, max_tokens=m, temperature=0.7, seed=i)
+                       for i, (p, m) in enumerate(zip(prompts, budgets))]
+            streams.append([h.result(120).tolist() for h in handles])
+        got = _delta(before)
+        assert got["decode_dispatches"] == got["rounds"] > 0
+        assert decode_metrics.snapshot()["compile_delta_since_mark"] == 0
+        assert _every_page_is_back(eng)
+    assert streams[0] == streams[1]
+    assert [len(o) for o in streams[1]] == budgets
+    assert decode_metrics.snapshot()["draft_proposed"] > 0
 
 
 # -- the shared engine, last ------------------------------------------------

@@ -268,6 +268,42 @@ def test_continuous_batcher_serves_it_and_returns_every_page():
     assert 0 < hits <= held < made
 
 
+def test_three_rungs_share_a_dispatch_and_its_expert_counts():
+    """Requests of three rungs in the engine's one slot table: every
+    dispatch carries all three, its expert layers route the three rows
+    together (the counts behind the tokens say so), and each stream is
+    the plain reference's."""
+    cfg, params = model(held_experts=(0, 8))
+    eng = DecodeEngine(cfg, params, n_slots=3, buckets=(16, 32, 64),
+                       prefill_chunk=8)
+    eng.warmup()
+    rng = np.random.default_rng(3)
+    n = 6
+    prompts = [rng.integers(0, cfg.vocab_size, t).astype(np.int32)
+               for t in (4, 20, 45)]
+    assert [eng.pick_bucket(p.size + n) for p in prompts] == [16, 32, 64]
+    placed = [eng.start(p, max_tokens=n) for p in prompts]
+    outs = [[first] for _, first in placed]
+    keys = ("decode_dispatches", "decode_dispatch_rungs",
+            "decode_table_rows", "moe_assignments", "moe_layer_dispatches")
+    for _ in range(n - 1):
+        before = decode_metrics.snapshot()
+        toks = eng.advance()
+        after = decode_metrics.snapshot()
+        # one dispatch, three rungs in it, as wide as the longest needs;
+        # both expert layers saw all three rows
+        assert [after[k] - before[k] for k in keys] == [
+            1, 3, 3 * 64, 3 * 2 * cfg.num_experts_per_tok, 2]
+        for out, (slot, _) in zip(outs, placed):
+            out.append(int(toks[slot]))
+    for slot, _ in placed:
+        eng.release(slot)
+    for p, out in zip(prompts, outs):
+        assert out == greedy_reference(cfg, params, p, n)
+    eng.drop_residents()
+    assert eng._alloc.in_use() + eng.pages_unaccounted() == 0
+
+
 @pytest.mark.parametrize("option", [
     {"paged": False}, {"kv_dtype": "int8"}, {"quantize": "int8"},
     {"prefix_cache": True}, {"mesh": "a mesh"},

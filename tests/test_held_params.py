@@ -88,20 +88,19 @@ def on_raw_tree(eng, params, dparams=None):
 
 
 def engine_tokens(eng, prompt, n, temperature, seed):
-    bucket, slot, first = eng.start(np.asarray(prompt, np.int32),
-                                    max_tokens=n, temperature=temperature,
-                                    seed=seed)
+    slot, first = eng.start(np.asarray(prompt, np.int32), max_tokens=n,
+                            temperature=temperature, seed=seed)
     out = [first]
     while len(out) < n:
         if eng.draft is not None:
-            toks, n_c = eng.advance_spec(bucket)
+            toks, n_c = eng.advance_spec()
             out.extend(int(t) for t in toks[slot, :int(n_c[slot])])
         else:
-            toks = eng.advance(bucket)
-            if eng.last_ran(bucket)[slot]:
+            toks = eng.advance()
+            if eng.last_ran()[slot]:
                 out.append(int(toks[slot]))
     state = [np.asarray(x) for x in jax.tree.leaves(eng._pool)]
-    eng.release(bucket, slot)
+    eng.release(slot)
     return out[:n], state
 
 
@@ -174,7 +173,6 @@ def test_only_the_named_leaves_change_and_no_convert_is_left(params):
     # the step itself: with the raw tree it converts each layer's six
     # matrices (what XLA hoists onto the [n_layers, ...] stacks on the
     # chip), with the held tree nothing weight-shaped
-    b = eng._buckets[64]
     shapes = set()
     for name in MATRICES:
         shape = tuple(params["blocks"][name].shape)
@@ -182,8 +180,7 @@ def test_only_the_named_leaves_change_and_no_convert_is_left(params):
 
     def step_jaxpr(tree):
         return jax.make_jaxpr(eng._decode.jitted)(
-            tree, eng._pool_state(), b.ptab, b.tokens_h, b.pos_h, b.active,
-            b.temps, b.seeds).jaxpr
+            tree, eng._pool_state(), *eng._idle_step_args(64)).jaxpr
 
     assert len(_weight_converts(step_jaxpr(params), shapes)) == \
         len(MATRICES) * CFG.n_layers
@@ -241,9 +238,9 @@ def test_a_tree_with_nothing_to_cast_is_held_as_given(params, case):
         return
     eng.warmup()
     prompt = np.arange(1, 12, dtype=np.int32)
-    bucket, slot, _ = eng.start(prompt, max_tokens=4)
-    eng.advance(bucket)
-    eng.release(bucket, slot)
+    slot, _ = eng.start(prompt, max_tokens=4)
+    eng.advance()
+    eng.release(slot)
     assert casts() == before
 
 

@@ -368,12 +368,11 @@ def test_device_loss_on_data_model_mesh_resumes(devices, tmp_path):
 # -- model-sharded serving ---------------------------------------------------
 
 def _greedy(eng, prompt, n):
-    bucket, slot, first = eng.start(prompt, max_tokens=n, temperature=0.0,
-                                    seed=7)
+    slot, first = eng.start(prompt, max_tokens=n, temperature=0.0, seed=7)
     toks = [first]
     while len(toks) < n:
-        toks.append(int(eng.advance(bucket)[slot]))
-    eng.release(bucket, slot)
+        toks.append(int(eng.advance()[slot]))
+    eng.release(slot)
     return toks
 
 

@@ -59,10 +59,9 @@ def _solo(params, prompt, n_tokens, p=None, cfg=CFG):
 
 
 def _engine_tokens(eng, prompt, n):
-    bucket, slot, first = eng.start(np.asarray(prompt, np.int32),
-                                    max_tokens=n)
-    toks = [first] + [int(eng.advance(bucket)[slot]) for _ in range(n - 1)]
-    eng.release(bucket, slot)
+    slot, first = eng.start(np.asarray(prompt, np.int32), max_tokens=n)
+    toks = [first] + [int(eng.advance()[slot]) for _ in range(n - 1)]
+    eng.release(slot)
     return toks
 
 

@@ -107,22 +107,21 @@ def _reference(p, prompt, n_tokens, temperature=0.0, seed=0, cfg=CFG,
 def _engine_tokens(eng, prompt, n, temperature=0.0, seed=0):
     """Drive one request to n tokens through plain or speculative
     advance, honoring the ran-mask contract."""
-    bucket, slot, first = eng.start(np.asarray(prompt, np.int32),
-                                    max_tokens=n, temperature=temperature,
-                                    seed=seed)
+    slot, first = eng.start(np.asarray(prompt, np.int32), max_tokens=n,
+                            temperature=temperature, seed=seed)
     out = [first]
     while len(out) < n:
         if eng.draft is not None:
-            toks, n_c = eng.advance_spec(bucket)
+            toks, n_c = eng.advance_spec()
             for j in range(int(n_c[slot])):
                 out.append(int(toks[slot, j]))
                 if len(out) >= n:
                     break
         else:
-            toks = eng.advance(bucket)
-            if eng.last_ran(bucket)[slot]:
+            toks = eng.advance()
+            if eng.last_ran()[slot]:
                 out.append(int(toks[slot]))
-    eng.release(bucket, slot)
+    eng.release(slot)
     return out[:n]
 
 
@@ -241,7 +240,7 @@ def test_sampled_matches_the_dense_reference(params):
                       temperature=0.8, seed=1)
     assert _engine_tokens(eng, prompt, 12, temperature=0.8,
                           seed=5) == want
-    eng.release(*other[:2])
+    eng.release(other[0])
     eng.drop_residents()                # each prompt's first page
     assert eng._alloc.in_use() + eng.pages_unaccounted() == 0
 
@@ -410,16 +409,15 @@ def test_resident_prefix_mounts_by_reference(params):
     held = eng._alloc.in_use()
     assert held >= 2                                    # registry pins
     before = decode_metrics.snapshot()["prefix_hits"]
-    bucket, slot, first = eng.start(p2, max_tokens=8)
+    slot, first = eng.start(p2, max_tokens=8)
     assert decode_metrics.snapshot()["prefix_hits"] == before + 1
-    b = eng._buckets[bucket]
-    shared = [int(x) for x in b.ptab[slot, :2]]
+    shared = [int(x) for x in eng._slots.ptab[slot, :2]]
     assert all(eng._alloc.refcount(p) >= 2 for p in shared)
     out = [first]
     while len(out) < 8:
-        toks = eng.advance(bucket)
+        toks = eng.advance()
         out.append(int(toks[slot]))
-    eng.release(bucket, slot)
+    eng.release(slot)
     assert out == _solo(params, p2, 8)
     assert all(eng._alloc.refcount(p) >= 1 for p in shared)
     assert eng._alloc.in_use() >= held                  # only decrefs
@@ -516,10 +514,10 @@ def test_rebind_params_requires_idle_then_flips(params):
                        prefill_chunk=8)
     eng.warmup()
     prompt = np.arange(1, 8, dtype=np.int32)
-    bucket, slot, _ = eng.start(prompt, max_tokens=4)
+    slot, _ = eng.start(prompt, max_tokens=4)
     with pytest.raises(RuntimeError, match="busy"):
         eng.rebind_params(p_new)
-    eng.release(bucket, slot)
+    eng.release(slot)
     eng.rebind_params(p_new)
     assert _engine_tokens(eng, prompt, 10) == _solo(p_new, prompt, 10)
     assert decode_metrics.snapshot()["compile_delta_since_mark"] == 0
