@@ -378,7 +378,7 @@ class ServingChaos:
             raise ValueError(f"leave must be >= 0: {leave}")
 
         def hook(name: str) -> None:
-            alloc = self.engine._alloc
+            alloc = self.engine._kinds[0].alloc
             n = max(alloc.n_free() - int(leave), 0)
             if n:
                 with self._lock:
@@ -406,7 +406,7 @@ class ServingChaos:
         def hook(name: str) -> None:
             with self._lock:
                 held, self._held_pages = self._held_pages, []
-            alloc = self.engine._alloc
+            alloc = self.engine._kinds[0].alloc
             if alloc is not None and held:
                 alloc.free(held)
                 self.injected["release"] += 1

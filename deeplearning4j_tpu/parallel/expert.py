@@ -239,9 +239,9 @@ def make_gspmd_moe_ffn(mesh: Optional[Mesh], cfg: MoEConfig):
 
 
 # ---------------------------------------------------------------------------
-# One expert-parallel rank on the decode path: group-limited routing over
-# every expert, no capacity, and the part of the result the experts HELD
-# HERE give.  What the absent ranks' experts would add is left out (it
+# One expert-parallel rank on the decode path: routing over every expert
+# (group-limited, or plain top-k), no capacity, and the part of the result
+# the experts HELD HERE give.  What the absent ranks' experts would add is left out (it
 # would arrive by the all-to-all this rank is not part of); nothing here
 # stands in for them.
 # ---------------------------------------------------------------------------
@@ -268,6 +268,21 @@ def route_group_limited(scores: Array, n_group: int, topk_group: int,
     chosen = jnp.zeros((N, E), jnp.bool_).at[
         jnp.arange(N)[:, None], top_experts].set(True)
     return jnp.where(chosen, scores * scale, 0.0), chosen
+
+
+def route_topk_renorm(scores: Array, top_k: int) -> Tuple[Array, Array]:
+    """Plain top-k routing with renormalisation (``norm_topk_prob``
+    true): of ``scores`` [N, E] float32 router probabilities the
+    ``top_k`` largest a token are taken, each weighted by its share of
+    their sum; no groups, no capacity, no token dropped.  Returns what
+    :func:`route_group_limited` does: (weights [N, E] float32, zero off
+    the chosen experts and summing to 1 a token; chosen [N, E] bool)."""
+    N, E = scores.shape
+    top, top_experts = lax.top_k(scores, top_k)                 # [N, k]
+    chosen = jnp.zeros((N, E), jnp.bool_).at[
+        jnp.arange(N)[:, None], top_experts].set(True)
+    return jnp.where(chosen, scores / top.sum(axis=-1, keepdims=True),
+                     0.0), chosen
 
 
 def gated_ffn(x: Array, w_gate: Array, w_up: Array, w_down: Array) -> Array:

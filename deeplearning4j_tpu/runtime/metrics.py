@@ -400,6 +400,19 @@ class DecodeMetrics:
       holds; ``moe_expert_hits``: distinct held experts some token
       chose, a layer a dispatch (what the step read of the experts);
       ``moe_layer_dispatches``: expert layers run.
+
+    Kinds of page (an engine whose family declares them,
+    ``models/mellum.py``: a slab and a page table for the full-attention
+    layers, another for the sliding-window layers, whose table row is a
+    ring no longer than the window):
+
+    - ``pages_in_use_full`` / ``pages_in_use_window``: gauges, pages
+      allocated of each kind; ``kv_rows_held_full`` /
+      ``kv_rows_held_window``: summed over decode dispatches, the rows a
+      layer of that kind holds for the slots that ran (all of a
+      sequence; at most the ring's newest); ``window_pages_reused``:
+      pages of the window kind a slot wrote its newest rows over, in
+      decode steps and prefill chunks alike.
     """
 
     MAX_SAMPLES = 8192
@@ -410,6 +423,11 @@ class DecodeMetrics:
     #: (``note_family_counts``)
     FAMILY_COUNTS = ("moe_assignments", "moe_assignments_held",
                      "moe_expert_hits", "moe_layer_dispatches")
+    #: per KIND OF PAGE of an engine whose family declares its kinds
+    #: (``note_page_kinds``): gauges, then counts
+    KIND_GAUGES = ("pages_in_use_full", "pages_in_use_window")
+    KIND_COUNTS = ("kv_rows_held_full", "kv_rows_held_window",
+                   "window_pages_reused")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -458,7 +476,8 @@ class DecodeMetrics:
             self.params_held_bytes = 0
             for key in self.SECONDS:
                 setattr(self, key, 0.0)
-            for key in self.FAMILY_COUNTS:
+            for key in (self.FAMILY_COUNTS + self.KIND_GAUGES
+                        + self.KIND_COUNTS):
                 setattr(self, key, 0)
             self._ttft_ms: List[float] = []
             self._compile_mark: Optional[int] = None
@@ -478,6 +497,23 @@ class DecodeMetrics:
                 raise KeyError(f"no family counter {key!r}")
         with self._lock:
             for key, n in zip(names, counts):
+                setattr(self, key, getattr(self, key) + int(n))
+
+    def note_page_kinds(self, gauges: Dict[str, int],
+                        counts: Dict[str, int]) -> None:
+        """What an engine with several kinds of page says of them:
+        ``gauges`` (from ``KIND_GAUGES``) are set, ``counts`` (from
+        ``KIND_COUNTS``) added."""
+        for key in gauges:
+            if key not in self.KIND_GAUGES:
+                raise KeyError(f"no page-kind gauge {key!r}")
+        for key in counts:
+            if key not in self.KIND_COUNTS:
+                raise KeyError(f"no page-kind counter {key!r}")
+        with self._lock:
+            for key, n in gauges.items():
+                setattr(self, key, int(n))
+            for key, n in counts.items():
                 setattr(self, key, getattr(self, key) + int(n))
 
     def note_params_held(self, nbytes: int) -> None:
@@ -656,7 +692,9 @@ class DecodeMetrics:
                 "params_held_casts": self.params_held_casts,
                 "params_held_bytes": self.params_held_bytes,
                 **{key: getattr(self, key) for key in self.SECONDS},
-                **{key: getattr(self, key) for key in self.FAMILY_COUNTS},
+                **{key: getattr(self, key) for key in (
+                    self.FAMILY_COUNTS + self.KIND_GAUGES
+                    + self.KIND_COUNTS)},
                 "compile_mark": self._compile_mark,
             }
         if out["compile_mark"] is not None:

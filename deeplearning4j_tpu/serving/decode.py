@@ -93,6 +93,26 @@ HBM holds what live tokens occupy, so admission counts free pages as
 well as free slots, and a slot whose next page cannot be had STALLS a
 dispatch instead of failing (:class:`KVPagesExhausted` only when
 nothing can move).
+
+KINDS OF PAGE (PR 32).  A family whose layers do not all keep a row
+equally long declares the kinds of page its pool has
+(``page_kinds(cfg, page_tokens)``; ``models/mellum.py``: ``full``, a
+rung's worth a slot over the full-attention layers' slab, and
+``window``, ``sliding_window / C + 1`` pages a slot at most over the
+sliding-window layers' slab).  The engine keeps an allocator and a page
+table a kind (:class:`_PageKind`), admits on all of them, grows and
+releases all of them, audits all of them, and hands the family's two
+dispatches the tables in the declared order (the bare array where
+there is one kind, as ever).  A kind's table row is a ring of its
+``cap`` columns, the page of positions ``j C ..`` in column ``j %
+cap``: a bounded kind REUSES a slot's oldest page for its newest rows,
+in decode steps and between prefill chunks alike, with no allocator
+call (the page's rows all lie further back than the window reads); an
+unbounded kind's ``cap`` is the longest rung's pages and never wraps.
+One algorithm with the kinds as data: ``gpt`` and ``deepseek_v2``
+declare none and run it with one unbounded kind.  A family with a
+bounded kind mounts no prefix by reference (a later slot would be
+handed pages since written over).
 """
 
 from __future__ import annotations
@@ -127,7 +147,10 @@ def model_family(cfg):
     A family may also state ``UNSUPPORTED_ENGINE_OPTIONS`` (engine
     options it has no code for: the engine raises instead of running
     another family's), ``DECODE_COUNTERS`` (names of the counts its
-    ``paged_decode`` appends to the step's tokens) and
+    ``paged_decode`` appends to the step's tokens), ``page_kinds(cfg,
+    page_tokens)`` (the KINDS OF PAGE its pool has, each ``(name, the
+    most pages a slot may hold or None for a rung's worth)``: see
+    :class:`_PageKind`; a family that states none has one kind) and
     ``COMPUTE_DTYPE_LEAVES`` (the leaves its steps read only through
     ``.astype(cfg.compute_dtype)``, each by its keys from the root:
     :func:`hold_in_compute_dtype`)."""
@@ -308,6 +331,61 @@ class PageAllocator:
         return sum(self._refs.values())
 
 
+class AllocatorSet:
+    """The allocators of an engine whose pool has several kinds of page,
+    read as one: what ``DecodeEngine._alloc`` is for such an engine, so
+    that an audit that asks it for ``in_use()`` counts pages of EVERY
+    kind.  (An engine with one kind keeps the bare
+    :class:`PageAllocator` there.)"""
+
+    def __init__(self, allocators: Sequence[PageAllocator]):
+        self.allocators = tuple(allocators)
+
+    def in_use(self) -> int:
+        return sum(a.in_use() for a in self.allocators)
+
+    def total_refs(self) -> int:
+        return sum(a.total_refs() for a in self.allocators)
+
+
+class _PageKind:
+    """One KIND OF PAGE of an engine's pool: pages of one slab of the
+    family's pool (``gpt`` and ``deepseek_v2`` have one slab and one
+    kind; ``mellum`` a slab for its full-attention layers and one for
+    its sliding-window layers), with an allocator and a host page table
+    of their own.  ``cap`` is the most pages a slot may hold of the
+    kind: a rung's worth where the family gives no bound, else the
+    bound (a sliding-window layer never reads further back than its
+    window).  A slot's table row is a RING of ``cap`` columns: the page
+    that holds positions ``j * C .. j * C + C - 1`` sits in column
+    ``j % cap``, so a sequence that outgrows ``cap`` pages writes its
+    newest rows over its oldest page, and a kind without a bound
+    (``cap`` columns cover the longest rung) never wraps: the one
+    table of PR 31."""
+
+    __slots__ = ("name", "bounded", "cap", "alloc", "ptab", "n_pages")
+
+    def __init__(self, name: str, bound: Optional[int], table_pages: int,
+                 n_slots: int, n_pages: Optional[int]):
+        self.name = name
+        self.bounded = bound is not None
+        self.cap = min(bound, table_pages) if self.bounded else table_pages
+        size = n_slots * self.cap + 1               # + the trash page
+        if n_pages and not (self.bounded and n_pages > size):
+            size = int(n_pages)
+        self.alloc = PageAllocator(size)
+        # trash-id 0 in unused entries
+        self.ptab = np.zeros((n_slots, self.cap), np.int32)
+        self.n_pages = np.zeros((n_slots,), np.int32)
+
+
+def _bare(per_kind: Sequence[Any]) -> Any:
+    """What a family is handed of a thing the engine keeps per kind of
+    page (a table, a page count): the thing itself where there is one
+    kind, the tuple in the family's declared order where several."""
+    return per_kind[0] if len(per_kind) == 1 else tuple(per_kind)
+
+
 def default_length_buckets(max_len: int, min_bucket: int = 32
                            ) -> Tuple[int, ...]:
     """Powers-of-two cache-length ladder up to (and including)
@@ -486,32 +564,29 @@ class PrefixCache:
 
 class _SlotTable:
     """Host-side state of an engine's ``n_slots`` slots, ONE table for
-    every length: the page table plus the occupancy/sampling arrays the
-    decode dispatch takes each step (the pool is the only DEVICE state,
-    and the engine's).  A rung of the ladder owns nothing here; a slot
-    remembers the rung of the request it holds (``rung``: the width its
-    prefill ran at and the most its page row may grow to)."""
+    every length: the occupancy/sampling arrays the decode dispatch
+    takes each step (the page tables are :class:`_PageKind`'s; the pool
+    is the only DEVICE state, and the engine's).  A rung of the ladder
+    owns nothing here; a slot remembers the rung of the request it
+    holds (``rung``: the width its prefill ran at and the most its page
+    rows may grow to)."""
 
     __slots__ = ("active", "temps", "seeds", "owners", "rung",
-                 "ptab", "n_pages", "tokens_h", "pos_h", "ran")
+                 "tokens_h", "pos_h", "ran")
 
-    def __init__(self, n_slots: int, table_pages: int):
+    def __init__(self, n_slots: int):
         self.active = np.zeros((n_slots,), np.bool_)
         self.temps = np.zeros((n_slots,), np.float32)
         self.seeds = np.zeros((n_slots,), np.uint32)
         self.owners: List[Any] = [None] * n_slots
         self.rung = np.zeros((n_slots,), np.int32)
-        # per-slot page table as wide as the largest rung (trash-id 0
-        # in unused entries), allocated-page counts, and host mirrors
-        # of tokens/pos (deterministic from the fetched stream: every
-        # dispatch, the draft's too, takes them); ``ran`` is the last
-        # dispatch's progress mask (a slot stalls when its next page
-        # cannot be allocated)
+        # host mirrors of tokens/pos (deterministic from the fetched
+        # stream: every dispatch, the draft's too, takes them); ``ran``
+        # is the last dispatch's progress mask (a slot stalls when its
+        # next page cannot be allocated)
         self.ran = np.zeros((n_slots,), np.bool_)
         self.tokens_h = np.zeros((n_slots,), np.int32)
         self.pos_h = np.zeros((n_slots,), np.int32)
-        self.ptab = np.zeros((n_slots, table_pages), np.int32)
-        self.n_pages = np.zeros((n_slots,), np.int32)
 
 
 class DecodeEngine:
@@ -526,7 +601,8 @@ class DecodeEngine:
     them, read off the live positions and no option.  The model family
     is an argument, not an import: the engine
     takes its pool and its two dispatches from the family of ``cfg``
-    (:func:`model_family`: ``models/gpt.py``, ``models/deepseek_v2.py``)
+    (:func:`model_family`: ``models/gpt.py``, ``models/deepseek_v2.py``,
+    ``models/mellum.py``)
     and holds, for each ``params`` tree it is given, the tree its
     executables take (``current_params()``), made once per tree: the
     leaves the family names in ``COMPUTE_DTYPE_LEAVES`` (those its steps
@@ -553,7 +629,9 @@ class DecodeEngine:
     compile engine with the page pool DONATED, so the cache updates in
     place (no 2x HBM) and identically-configured replicas share one
     compile per width.  ``n_pages`` sizes the pool (default: room for
-    ``n_slots`` sequences of the largest bucket, + the trash page);
+    ``n_slots`` sequences of the largest bucket, + the trash page; where
+    the family declares several kinds of page, every kind, a bounded
+    one never past ``n_slots`` rings);
     ``paged`` selects nothing — ``True`` is the only value, kept until
     the benchmark's callers stop passing it.
 
@@ -665,13 +743,35 @@ class DecodeEngine:
         # (+ the trash page); pass n_pages to shrink it — bounding HBM
         # by live tokens is the point of the knob.
         self.page_tokens = chunk
-        default_pages = self.n_slots * (self.buckets[-1] // chunk) + 1
-        self.n_kv_pages = int(n_pages or default_pages)
-        self._alloc = PageAllocator(self.n_kv_pages)
+        # the kinds of page the family's pool has (one where it states
+        # none), each with its allocator and its table: ``n_pages``
+        # sizes every kind, a bounded one never past its default
+        declared = getattr(fam, "page_kinds", None)
+        self._kinds: Tuple[_PageKind, ...] = tuple(
+            _PageKind(name, bound, self.buckets[-1] // chunk, self.n_slots,
+                      n_pages)
+            for name, bound in (declared(cfg, chunk) if declared
+                                else (("kv", None),)))
+        #: names of the kinds a family DECLARED (their counters and span
+        #: attributes are noted; a one-kind family's round notes nothing
+        #: it did not before)
+        self._kind_names = tuple(k.name for k in self._kinds
+                                 ) if declared else ()
+        #: pages of the pool: an int, or a tuple in the declared order
+        self.n_kv_pages = _bare([k.alloc.n_pages for k in self._kinds])
+        allocs = [k.alloc for k in self._kinds]
+        self._alloc = allocs[0] if len(allocs) == 1 else AllocatorSet(allocs)
         self._pool = None
         self._dpool = None
+        # prefixes are mounted BY REFERENCE only where a page, once
+        # written, keeps its rows for as long as it is referenced: a
+        # bounded kind's ring writes a slot's newest rows over its
+        # oldest page, so a family with one registers and looks up
+        # nothing (and what a mount copies is a row of ONE table)
+        self._mounts_prefixes = (len(self._kinds) == 1
+                                 and not self._kinds[0].bounded)
         self._resident: "OrderedDict[bytes, Tuple[np.ndarray, Tuple[int, ...]]]" = OrderedDict()
-        self._resident_max = max(self.n_kv_pages // 2, 1)
+        self._resident_max = max(self._kinds[0].alloc.n_pages // 2, 1)
         cfg_d = None
         self._draft_cfg = self._draft_params = None
         if draft is not None:
@@ -684,8 +784,7 @@ class DecodeEngine:
                     f"draft max_len {cfg_d.max_len} < largest bucket "
                     f"{self.buckets[-1]}: the draft mirrors target "
                     f"positions")
-        self._slots = _SlotTable(self.n_slots,
-                                 self.buckets[-1] // self.page_tokens)
+        self._slots = _SlotTable(self.n_slots)
         verify_fn = None
         # the key captures everything that determines the traced
         # programs besides input shapes (``geo`` below extends it with
@@ -974,6 +1073,18 @@ class DecodeEngine:
         land in the trash page)."""
         return next((t for t in self.buckets if t >= top), self.buckets[-1])
 
+    def _table_widths(self, width: int) -> List[int]:
+        """Columns of each kind's table a dispatch (or a prefill) of
+        ``width`` positions is handed: the rung's pages, a kind's ring
+        at most."""
+        return [min(k.cap, width // self.page_tokens) for k in self._kinds]
+
+    def _tables(self, width: int, rows: Any = slice(None)) -> Any:
+        """A copy of every kind's table at ``width`` as a dispatch takes
+        it (:func:`_bare`): all slots' rows, or one slot's."""
+        return _bare([k.ptab[rows, :n].copy() for k, n in
+                      zip(self._kinds, self._table_widths(width))])
+
     def _pool_state(self):
         """Lazily materialize the page pool(s) — ONE pool for every
         length (a page's shape does not depend on a rung; only the
@@ -1010,18 +1121,19 @@ class DecodeEngine:
         only gates on the prompt floor."""
         if self.free_slot() is None:
             return False
-        C = self.page_tokens
-        needed = -(-prompt_len // C) + 1
-        return self._alloc.n_free() >= needed
+        needed = -(-prompt_len // self.page_tokens) + 1
+        return all(k.alloc.n_free() >= min(needed, k.cap)
+                   for k in self._kinds)
 
     def check_capacity(self, prompt_len: int) -> None:
         """Raise the typed error when a prompt alone can NEVER fit the
         pool — the sync-validate path for oversize admits."""
-        C = self.page_tokens
-        needed = -(-prompt_len // C) + 1
-        total = self.n_kv_pages - self._alloc.n_reserved
-        if needed > total:
-            raise KVPagesExhausted(needed, total, self.n_kv_pages)
+        needed = -(-prompt_len // self.page_tokens) + 1
+        for k in self._kinds:
+            total = k.alloc.n_pages - k.alloc.n_reserved
+            if min(needed, k.cap) > total:
+                raise KVPagesExhausted(min(needed, k.cap), total,
+                                       k.alloc.n_pages)
 
     def last_ran(self) -> np.ndarray:
         """[S] mask of slots the last advance/advance_spec actually
@@ -1045,34 +1157,67 @@ class DecodeEngine:
         for s in np.flatnonzero(b.active):
             need = int(b.pos_h[s] + span) // C + 1
             need = min(need, int(b.rung[s]) // C)
-            short = need - int(b.n_pages[s])
-            if short <= 0:
-                continue
-            try:
-                ids = self._alloc.alloc(short)
-            except KVPagesExhausted:
-                run[s] = False
-                continue
-            b.ptab[s, int(b.n_pages[s]):need] = ids
-            b.n_pages[s] = need
+            for k in self._kinds:
+                # a kind's ring is full at ``cap`` pages: from there on
+                # the slot's newest rows go over its oldest page
+                held = int(k.n_pages[s])
+                short = min(need, k.cap) - held
+                if short <= 0:
+                    continue
+                try:
+                    ids = k.alloc.alloc(short)
+                except KVPagesExhausted:
+                    run[s] = False
+                    break
+                k.ptab[s, held:held + short] = ids
+                k.n_pages[s] = held + short
         if b.active.any() and not run.any():
-            victim = int(max(np.flatnonzero(b.active),
-                             key=lambda s: int(b.n_pages[s])))
-            raise KVPagesExhausted(1, self._alloc.n_free(),
-                                   self.n_kv_pages, slot=victim)
+            victim = int(max(np.flatnonzero(b.active), key=lambda s: sum(
+                int(k.n_pages[s]) for k in self._kinds)))
+            short_of = min(self._kinds, key=lambda k: k.alloc.n_free())
+            raise KVPagesExhausted(1, short_of.alloc.n_free(),
+                                   short_of.alloc.n_pages, slot=victim)
         return run
 
     def _release_pages(self, slot: int) -> None:
         b = self._slots
-        n = int(b.n_pages[slot])
-        if n:
-            self._alloc.free(int(p) for p in b.ptab[slot, :n])
-        b.ptab[slot, :] = 0
-        b.n_pages[slot] = 0
+        for k in self._kinds:
+            n = int(k.n_pages[slot])
+            if n:
+                k.alloc.free(int(p) for p in k.ptab[slot, :n])
+            k.ptab[slot, :] = 0
+            k.n_pages[slot] = 0
         b.tokens_h[slot] = 0
         b.pos_h[slot] = 0
         decode_metrics.note_pages(self._alloc.in_use(), 0, 0)
+        if self._kind_names:
+            self._note_kinds()
         decode_metrics.note_pages_leaked(self.pages_unaccounted())
+
+    def _note_kinds(self, written_at: np.ndarray = np.zeros((0,), np.int32),
+                    decode: bool = False) -> None:
+        """The per-kind counters of a family that declared its kinds of
+        page, after rows were written at positions ``written_at`` (a
+        decode dispatch's, one a slot that ran, or the starts of a
+        prefill's chunks): the gauges ``pages_in_use_<kind>``;
+        ``<kind>_pages_reused`` of a bounded kind grows by the rows that
+        opened a page past the ring, written over the slot's oldest;
+        after a decode dispatch ``kv_rows_held_<kind>`` grows by the
+        rows a layer of the kind then holds for those slots (a ring of
+        ``cap`` pages holds the newest)."""
+        C = self.page_tokens
+        gauges, counts = {}, {}
+        for k in self._kinds:
+            gauges[f"pages_in_use_{k.name}"] = k.alloc.in_use()
+            if k.bounded:
+                counts[f"{k.name}_pages_reused"] = int(
+                    ((written_at % C == 0) & (written_at // C >= k.cap)
+                     ).sum())
+            if decode:
+                behind = np.maximum(0, written_at // C + 1 - k.cap) * C
+                counts[f"kv_rows_held_{k.name}"] = int(
+                    (written_at + 1 - behind).sum())
+        decode_metrics.note_page_kinds(gauges, counts)
 
     def _drop_pool(self) -> None:
         """Poison-reset after a failed dispatch: the pool was
@@ -1086,11 +1231,12 @@ class DecodeEngine:
         self.drop_residents()
 
     def pages_unaccounted(self) -> int:
-        """Allocator page references not explained by any live slot's
-        page table or the resident-prefix registry — nonzero means a
+        """Allocator page references, of every kind of page, not
+        explained by any live slot's page tables or the resident-prefix
+        registry — nonzero means a
         reclaim path leaked (exported as the ``pages_leaked`` gauge,
         asserted zero by the chaos drill after drain)."""
-        accounted = int(self._slots.n_pages.sum())
+        accounted = sum(int(k.n_pages.sum()) for k in self._kinds)
         accounted += sum(len(ids) for _, ids in self._resident.values())
         return self._alloc.total_refs() - accounted
 
@@ -1104,7 +1250,7 @@ class DecodeEngine:
         DECREFS the shared ids."""
         C = self.page_tokens
         n = (prompt.size - 1) // C
-        if n < 1 or not self._resident:
+        if n < 1 or not self._resident:     # never filled without mounts
             return 0, None
         digs = PrefixCache._boundary_digests(prompt, C, n,
                                              self._prefix_space)
@@ -1125,12 +1271,13 @@ class DecodeEngine:
         entry and the last sharer releases."""
         C = self.page_tokens
         m = C * ((prompt.size - 1) // C)
-        if m < C:
+        if m < C or not self._mounts_prefixes:
             return
         digs = PrefixCache._boundary_digests(prompt, C, m // C,
                                              self._prefix_space)
+        ptab = self._kinds[0].ptab      # the one kind of a family that mounts
         for k in range(1, m // C + 1):
-            ids = tuple(int(p) for p in self._slots.ptab[slot, :k])
+            ids = tuple(int(p) for p in ptab[slot, :k])
             old = self._resident.pop(digs[k - 1], None)
             if old is not None:
                 self._alloc.free(old[1])
@@ -1268,10 +1415,12 @@ class DecodeEngine:
     def _idle_step_args(self, bucket: int) -> Tuple[np.ndarray, ...]:
         """What a decode (verify, draft) dispatch at ``bucket``'s width
         takes behind the pool, with nothing running: a ZERO table slice
-        [S, bucket // C], zero tokens/pos, all-inactive, zero sampling
+        [S, bucket // C] (one a kind of page), zero tokens/pos,
+        all-inactive, zero sampling
         state — the shapes and types ``advance()`` dispatches with."""
         S = self.n_slots
-        return (np.zeros((S, bucket // self.page_tokens), np.int32),
+        return (_bare([np.zeros((S, n), np.int32)
+                       for n in self._table_widths(bucket)]),
                 np.zeros((S,), np.int32), np.zeros((S,), np.int32),
                 np.zeros((S,), np.bool_), np.zeros((S,), np.float32),
                 np.zeros((S,), np.uint32))
@@ -1309,7 +1458,7 @@ class DecodeEngine:
                 pool = self._pool_state()
                 ptab, tokens, pos, idle, temps, seeds = \
                     self._idle_step_args(t)
-                ptab_s = ptab[0]
+                ptab_s = jax.tree.map(lambda a: a[0], ptab)
                 pool, _ = self._prefill(
                     params, pool, ptab_s, toks, np.int32(0),
                     np.int32(1), np.float32(0.0), np.uint32(0))
@@ -1409,36 +1558,40 @@ class DecodeEngine:
                             rid=rid, bucket=bucket, slot=slot,
                             prompt_tokens=int(prompt.size),
                             chunks=n_chunks, prefix_hit_tokens=hit_len):
+            # only a RESIDENT hit reuses pages by reference (a family
+            # that mounts has one kind of page); a host-store hit copies
+            # into fresh pool pages, so it needs the full n_chunks
+            # allocated (the hit region included)
+            mounted = h if resident_hit else 0
+            try:
+                for k in self._kinds:
+                    # chunk c is written into column c % cap: a prompt
+                    # past a bounded kind's ring goes round it
+                    n_held = min(n_chunks, k.cap)
+                    k.ptab[slot, mounted:n_held] = k.alloc.alloc(
+                        n_held - mounted)
+                    k.n_pages[slot] = n_held
+            except KVPagesExhausted:
+                self._release_pages(slot)
+                raise
             if resident_hit:
                 self._alloc.share(hit_ids)
-                b.ptab[slot, :h] = hit_ids
-            # only a RESIDENT hit reuses pages by reference; a host
-            # -store hit copies into fresh pool pages, so it needs the
-            # full n_chunks allocated (the hit region included)
-            n_fresh = n_chunks - h if resident_hit else n_chunks
-            try:
-                fresh = self._alloc.alloc(n_fresh)
-            except KVPagesExhausted:
-                if resident_hit:
-                    self._alloc.free(hit_ids)
-                    b.ptab[slot, :h] = 0
-                raise
-            b.ptab[slot, n_chunks - n_fresh:n_chunks] = fresh
-            b.n_pages[slot] = n_chunks
+                self._kinds[0].ptab[slot, :h] = hit_ids
             first = None
             try:
                 if host_pages is not None:
                     pids = np.zeros((tbl,), np.int32)
-                    pids[:h] = b.ptab[slot, :h]
+                    pids[:h] = self._kinds[0].ptab[slot, :h]
                     self._pool = pool = self._write(
                         pool, pids, *self._pad_pool_pages(host_pages, tbl))
+                ptab_s = self._tables(bucket, slot)
                 for c in range(h, n_chunks):
                     lo = c * C
                     n_valid = min(C, prompt.size - lo)
                     chunk = np.zeros((C,), np.int32)
                     chunk[:n_valid] = prompt[lo:lo + n_valid]
                     pool, first = self._prefill(
-                        params, pool, b.ptab[slot, :tbl].copy(), chunk,
+                        params, pool, ptab_s, chunk,
                         np.int32(lo), np.int32(n_valid),
                         np.float32(temperature), np.uint32(seed))
                     self._pool = pool
@@ -1453,8 +1606,7 @@ class DecodeEngine:
                         chunk = np.zeros((C,), np.int32)
                         chunk[:n_valid] = prompt[lo:lo + n_valid]
                         self._dpool = self._draft_prefill(
-                            self._draft_params, self._dpool,
-                            b.ptab[slot, :tbl].copy(), chunk,
+                            self._draft_params, self._dpool, ptab_s, chunk,
                             np.int32(lo), np.int32(n_valid))
             except Exception:
                 # the pool was donated into the failed dispatch — every
@@ -1487,7 +1639,8 @@ class DecodeEngine:
             self._resident_register(prompt, slot)
             if self._prefix is not None:
                 pids = np.zeros((tbl,), np.int32)
-                pids[:m_store // C] = b.ptab[slot, :m_store // C]
+                pids[:m_store // C] = self._kinds[0].ptab[slot,
+                                                          :m_store // C]
                 full = self._read(pool, pids)
                 self._ensure_harvester()
                 try:
@@ -1496,6 +1649,8 @@ class DecodeEngine:
                 except queue.Full:
                     pass            # backpressure: drop, opportunistic
         decode_metrics.note_pages(self._alloc.in_use(), 0, 0)
+        if self._kind_names:
+            self._note_kinds(np.arange(h, n_chunks) * C)
         b.tokens_h[slot] = first_tok
         b.pos_h[slot] = prompt.size
         b.active[slot] = True
@@ -1512,8 +1667,10 @@ class DecodeEngine:
         longest RUNNING slot's writes — read off the live positions,
         so a dispatch never gathers more than the longest live context
         needs, and never more than the widest live rung's own dispatch
-        did when each rung made one.  Returns (ptab [S, w // C],
-        tokens, pos, run, w, rungs), ``rungs`` the distinct rungs of
+        did when each rung made one.  Returns (ptab [S, w // C] (a
+        tuple of them where the pool has several kinds of page, a
+        bounded kind's no wider than its ring), tokens, pos, run, w,
+        rungs), ``rungs`` the distinct rungs of
         the requests it carries (the dispatches a table a rung would
         have made)."""
         b = self._slots
@@ -1522,8 +1679,8 @@ class DecodeEngine:
         top = int(b.pos_h[run].max()) + 1 + span if run.any() else 0
         w = self._width(top)
         rungs = len(set(b.rung[run].tolist()))
-        return (b.ptab[:, :w // self.page_tokens].copy(),
-                b.tokens_h.copy(), b.pos_h.copy(), run, w, rungs)
+        return (self._tables(w), b.tokens_h.copy(), b.pos_h.copy(), run, w,
+                rungs)
 
     def advance(self) -> np.ndarray:
         """ONE decode dispatch for the engine: every active slot, of
@@ -1540,6 +1697,10 @@ class DecodeEngine:
             with telemetry.span("decode.stage"):
                 ptab, tokens, pos, run, w, rungs = self._stage(0)
                 sp.set(width=w, rungs=rungs)
+                if self._kind_names:
+                    sp.set(**{f"width_{name}": n * self.page_tokens
+                              for name, n in zip(self._kind_names,
+                                                 self._table_widths(w))})
                 pool = self._pool_state()
             with telemetry.span("decode.dispatch"):
                 try:
@@ -1562,6 +1723,8 @@ class DecodeEngine:
                 int(run.sum()), self.n_slots, rungs, self.n_slots * w)
             decode_metrics.note_pages(self._alloc.in_use(),
                                       self._live_rows(), self.page_tokens)
+            if self._kind_names:
+                self._note_kinds(pos[run], decode=True)
             return toks
 
     @staticmethod

@@ -411,7 +411,7 @@ def test_resident_prefix_mounts_by_reference(params):
     before = decode_metrics.snapshot()["prefix_hits"]
     slot, first = eng.start(p2, max_tokens=8)
     assert decode_metrics.snapshot()["prefix_hits"] == before + 1
-    shared = [int(x) for x in eng._slots.ptab[slot, :2]]
+    shared = [int(x) for x in eng._kinds[0].ptab[slot, :2]]
     assert all(eng._alloc.refcount(p) >= 2 for p in shared)
     out = [first]
     while len(out) < 8:
