@@ -383,13 +383,14 @@ def phase_serve(cfg: Any, params: Any, sz: Sizes
           f"{eng._alloc.in_use()} KV page(s) still allocated after close "
           f"({eng.pages_unaccounted()} unaccounted)")
 
-    # the unbatched reference, at the cache length and chunk the engine
-    # used for this request, so the two run the same arithmetic
+    # the unbatched reference, at the cache length and the rows a
+    # prefill dispatch the engine used for this request, so the two run
+    # the same arithmetic
     i = sz.ref_request
     bucket = eng.pick_bucket(len(prompts[i]) + sz.max_tokens)
     ref_fn = jax.jit(lambda p, pr: gpt.generate(
         cfg, p, pr, sz.max_tokens, jax.random.key(0), temperature=0.0,
-        max_len=bucket, prefill_chunk=eng.prefill_chunk))
+        max_len=bucket, prefill_chunk=eng.prefill_rows(bucket)))
     ref = ref_fn(params, jnp.asarray(prompts[i])[None, :])[0]
     why = near_tie_or_equal(cfg, params, prompts[i], outs[i], ref)
     say("serve", reference="gpt.generate", request=i, bucket=bucket,

@@ -494,7 +494,8 @@ def _paged_stack(cfg: DeepSeekV2Config, params: PyTree, pool: PagedLatent,
                  ) -> Tuple[PagedLatent, Array, Array]:
     """The layer stack over the pool, W rows a sequence: row w of
     sequence s feeds ``toks_w[s, w]`` at position ``posw[s, w]`` (decode:
-    S slots, W = 1; a prefill chunk: S = 1, W = C).  Layer by layer the
+    S slots, W = 1; a prefill dispatch: S = 1, W rows of one or more
+    pages).  Layer by layer the
     fresh rows are written at (layer, page, offset) and the sequence's
     pages of that layer read back through ``ptab`` [S, TBL], the fresh
     rows among them, as in :func:`models.gpt._paged_stack`.  Rows where
@@ -544,13 +545,16 @@ def paged_prefill(cfg: DeepSeekV2Config, params: PyTree, pool: PagedLatent,
                   ptab_s: Array, toks: Array, start: Array, n_valid: Array,
                   temperature: Array, seed: Array
                   ) -> Tuple[PagedLatent, Array]:
-    """One chunk ``toks`` [C] (C the page width) of the sequence whose
-    page table is ``ptab_s`` [TBL], at chunk-aligned ``start``: its rows
-    are written into page ``ptab_s[start // C]`` (those past ``n_valid``
-    into the trash page) and it attends its context through the table.
-    Returns (pool', the token sampled after row ``n_valid - 1``)."""
-    C = toks.shape[0]
-    at = jnp.arange(C, dtype=jnp.int32)
+    """One prefill dispatch's rows ``toks`` [W] (any number of rows: the
+    engine sends a whole number of pages, ``DecodeEngine.prefill_rows``)
+    of the sequence whose page table is ``ptab_s`` [TBL], at
+    page-aligned ``start``: each row is written at the page and offset
+    of its own position (those past ``n_valid``, and those past the
+    table's end, into the trash page) and attends its context through
+    the table.  Returns (pool', the token sampled after row
+    ``n_valid - 1``)."""
+    W = toks.shape[0]
+    at = jnp.arange(W, dtype=jnp.int32)
     pool, x, _ = _paged_stack(cfg, params, pool, ptab_s[None, :],
                               toks[None, :], (start + at)[None, :],
                               (at < n_valid)[None, :])

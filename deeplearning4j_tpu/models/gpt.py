@@ -254,6 +254,19 @@ def _decode_step(cfg: TransformerConfig, params: PyTree, cache: KVCache,
     return KVCache(jnp.stack(new_k), jnp.stack(new_v)), logits
 
 
+def _embed_rows(cfg: TransformerConfig, params: PyTree, toks: Array,
+                pos: Array) -> Array:
+    """Token + position rows through the embedding LayerNorm, fp32, for
+    ``toks`` at positions ``pos`` (broadcast against it), clipped to the
+    model's positions: a padded row or an idle slot may lie past them,
+    ``tfm.embed`` (``jnp.take``) would give it a NaN position row, and a
+    NaN row, once cached, poisons every row that reads it at weight 0."""
+    e = params["embed"]
+    pos = jnp.clip(pos, 0, cfg.max_len - 1)
+    x = e["tok"][toks] + e["pos"][pos]
+    return tfm.layer_norm(x, e["ln_g"], e["ln_b"], cfg.layer_norm_eps)
+
+
 def _prefill_chunk(cfg: TransformerConfig, params: PyTree, cache: KVCache,
                    toks: Array, start: Array) -> Tuple[KVCache, Array]:
     """One dense prefill chunk: ``toks`` [B, C] int32 at positions
@@ -270,9 +283,9 @@ def _prefill_chunk(cfg: TransformerConfig, params: PyTree, cache: KVCache,
     quant = isinstance(cache, QKVCache)
     B, C = toks.shape
     T_max = cache.k.shape[2]
-    x = tfm.embed(cfg, params, toks, None, start)             # [B, C, H]
-
     pos_q = start + jnp.arange(C)                             # [C]
+    x = _embed_rows(cfg, params, toks, pos_q)                 # [B, C, H]
+
     # causal over the whole cache row: key col <= query pos.  Stale or
     # padded K/V beyond the written slab sits at col > pos and is never
     # attended; garbage WITHIN the slab from padded prompt rows is
@@ -583,7 +596,8 @@ def _paged_stack(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
                  ptab: Array, toks_w: Array, posw: Array, active: Array
                  ) -> Tuple[PagedKV, Array]:
     """The block stack over a paged pool, ``W`` rows a slot (decode:
-    ``W = 1``; verify: ``W = k + 1``): row w of slot s feeds
+    ``W = 1``; verify: ``W = k + 1``; a prefill dispatch does not run
+    this: :func:`paged_prefill`): row w of slot s feeds
     ``toks_w[s, w]`` at position ``posw[s, w]``.  Returns (pool',
     hidden [S, W, H]).
 
@@ -608,10 +622,7 @@ def _paged_stack(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
     S, TBL = ptab.shape
     C = pool.k.shape[2]
     T = TBL * C
-    e = params["embed"]
-    pos_c = jnp.clip(posw, 0, cfg.max_len - 1)
-    x = e["tok"][toks_w] + e["pos"][pos_c]                    # [S, W, H]
-    x = tfm.layer_norm(x, e["ln_g"], e["ln_b"], cfg.layer_norm_eps)
+    x = _embed_rows(cfg, params, toks_w, posw)                # [S, W, H]
 
     pw = jnp.clip(posw, 0, T - 1)
     ok = (posw >= 0) & (posw < T) & active[:, None]
@@ -672,26 +683,36 @@ def _paged_stack(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
 def paged_prefill(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
                   ptab_s: Array, toks: Array, start: Array, n_valid: Array,
                   temperature: Array, seed: Array) -> Tuple[PagedKV, Array]:
-    """Prefill one chunk ``toks`` [C] of a prompt (C == the pool's page
-    width — the engine aligns its prefill chunk to the page size) into
-    the slot whose page table is ``ptab_s`` [TBL], at chunk-aligned
-    ``start`` (rows past ``n_valid`` are padding); the other slots'
-    pages ride along untouched — how a request joins a RUNNING batch
-    without a barrier.  The chunk is exactly one page, so persisting it
-    is a single page write at ``ptab_s[start//C]``.  Returns (pool',
-    first_token sampled at the last valid row: the final chunk's counts)."""
+    """Prefill one dispatch's rows ``toks`` [W] of a prompt (W = m C, a
+    whole number ``m`` of the pool's C-row pages: the engine derives it,
+    ``DecodeEngine.prefill_rows``) into the slot whose page table is
+    ``ptab_s`` [TBL], at page-aligned ``start`` (rows past ``n_valid``
+    are padding); the other slots' pages ride along untouched — how a
+    request joins a RUNNING batch without a barrier.  The rows are the
+    ``m`` pages ``ptab_s[start // C : start // C + m]``, persisted as
+    ``m`` page writes.  A padded last dispatch may reach PAST the
+    table's end (a page-aligned prefix hit starts it anywhere): the
+    table is read with ``m - 1`` trash entries behind it, so those
+    pages' rows land in the trash page — ``lax.dynamic_slice`` and
+    ``dynamic_update_slice`` CLAMP a start that would overrun, which
+    would shift the whole slab back onto live rows.  Returns (pool',
+    first_token sampled at the last valid row: the final dispatch's
+    counts)."""
     L, Pn, C, F = pool.k.shape
     NH, D = cfg.n_heads, cfg.head_dim
-    TBL = ptab_s.shape[0]
+    W = toks.shape[0]
+    m = W // C
+    ptab_v = jnp.concatenate([ptab_s, jnp.zeros((m - 1,), ptab_s.dtype)])
+    T = ptab_v.shape[0] * C
     quant = pool.k_scale is not None
     with jax.named_scope("prefill_page_io"):
-        lp = _every_layer(L, ptab_s)
-        k = _read_pages(pool.k, lp).reshape(L, 1, TBL * C, NH, D)
-        v = _read_pages(pool.v, lp).reshape(L, 1, TBL * C, NH, D)
+        lp = _every_layer(L, ptab_v)
+        k = _read_pages(pool.k, lp).reshape(L, 1, T, NH, D)
+        v = _read_pages(pool.v, lp).reshape(L, 1, T, NH, D)
         if quant:
             cache_in = QKVCache(
-                k, v, _read_pages(pool.k_scale, lp).reshape(L, 1, TBL * C),
-                _read_pages(pool.v_scale, lp).reshape(L, 1, TBL * C))
+                k, v, _read_pages(pool.k_scale, lp).reshape(L, 1, T),
+                _read_pages(pool.v_scale, lp).reshape(L, 1, T))
         else:
             cache_in = KVCache(k, v)
     cache, logits = _prefill_chunk(cfg, params, cache_in, toks[None, :],
@@ -702,21 +723,15 @@ def paged_prefill(cfg: TransformerConfig, params: PyTree, pool: PagedKV,
         first = sample_token(last, _slot_key(seed, start + n_valid - 1),
                              temperature)
     with jax.named_scope("prefill_page_io"):
-        pid = ptab_s[start // C]
-        page_k = lax.dynamic_slice(cache.k, (0, 0, start, 0, 0),
-                                   (L, 1, C, NH, D)).reshape(L, C, F)
-        page_v = lax.dynamic_slice(cache.v, (0, 0, start, 0, 0),
-                                   (L, 1, C, NH, D)).reshape(L, C, F)
-        pool = pool._replace(k=pool.k.at[:, pid].set(page_k),
-                             v=pool.v.at[:, pid].set(page_v))
-        if quant:
-            ps_k = lax.dynamic_slice(cache.k_scale, (0, 0, start),
-                                     (L, 1, C))[:, 0]
-            ps_v = lax.dynamic_slice(cache.v_scale, (0, 0, start),
-                                     (L, 1, C))[:, 0]
-            pool = pool._replace(
-                k_scale=pool.k_scale.at[:, pid].set(ps_k),
-                v_scale=pool.v_scale.at[:, pid].set(ps_v))
+        lidx = jnp.arange(L)[:, None]
+        pids = lax.dynamic_slice_in_dim(ptab_v, start // C, m)
+        # the dispatch's rows of each cache leaf, page by page, into
+        # the pool leaf they were read from (an int8 pool's scales too)
+        pool = PagedKV(*(
+            a.at[lidx, pids].set(
+                lax.dynamic_slice_in_dim(c, start, W, axis=2).reshape(
+                    (L, m, C) + a.shape[3:]))
+            for c, a in zip(cache, pool)))
     return pool, first
 
 

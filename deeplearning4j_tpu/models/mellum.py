@@ -458,7 +458,8 @@ def _paged_stack(cfg: MellumConfig, params: PyTree, pool: PagedGQA,
                  row_ok: Array) -> Tuple[PagedGQA, Array, Array]:
     """The layer stack over the pool, W rows a sequence: row w of
     sequence s feeds ``toks_w[s, w]`` at position ``posw[s, w]`` (decode:
-    S slots, W = 1; a prefill chunk: S = 1, W = C rows of ONE page).
+    S slots, W = 1; a prefill dispatch: S = 1, W = C rows of ONE page,
+    which is what the ring below assumes of a dispatch's rows).
     ``ptabs``: the full kind's table [S, TBL] (column j the page of
     positions ``j C ..``) and the window kind's [S, R], a ring (that page
     in column ``j % R``; what a column holds is read off the slot's
@@ -553,15 +554,19 @@ def paged_prefill(cfg: MellumConfig, params: PyTree, pool: PagedGQA,
                   ptab_s: Tuple[Array, Array], toks: Array, start: Array,
                   n_valid: Array, temperature: Array, seed: Array
                   ) -> Tuple[PagedGQA, Array]:
-    """One chunk ``toks`` [C] (C the page width) of the sequence whose
-    page tables are ``ptab_s`` ([TBL] full, [R] window), at chunk-aligned
-    ``start``: its rows are written into one page of each kind (those
+    """One prefill dispatch's rows ``toks`` [W] of the sequence whose
+    page tables are ``ptab_s`` ([TBL] full, [R] window), at page-aligned
+    ``start``.  W is ONE page: the engine sends a family with a bounded
+    kind of page no more (``DecodeEngine.prefill_rows``), because the
+    window ring holds the pages one page's rows reach back to and ``m``
+    pages would need ``m - 1`` more (the full kind's row scatter takes
+    any W).  Its rows are written into one page of each kind (those
     past ``n_valid`` into the trash pages; on the window kind over the
     slot's oldest page once the ring is full) and it attends its
     context through the tables.  Returns (pool', the token sampled after
     row ``n_valid - 1``)."""
-    C = toks.shape[0]
-    at = jnp.arange(C, dtype=jnp.int32)
+    W = toks.shape[0]
+    at = jnp.arange(W, dtype=jnp.int32)
     pool, x, _ = _paged_stack(cfg, params, pool,
                               tuple(t[None, :] for t in ptab_s),
                               toks[None, :], (start + at)[None, :],

@@ -282,7 +282,12 @@ class DecodeMetrics:
     - ``prompt_tokens`` / ``tokens_out``: prompt tokens prefilled and
       continuation tokens streamed back;
     - ``prefill_dispatches`` / ``decode_dispatches``: device dispatches
-      of the two slot executables;
+      of the two slot executables; ``prefill_rows_dispatched`` /
+      ``prefill_rows_valid``: summed over prefill dispatches, the rows
+      a dispatch carried (``DecodeEngine.prefill_rows`` of its rung, a
+      whole number of pages) and those of them that were prompt tokens:
+      ``rows_dispatched / prefill_dispatches`` is the width a join
+      took, ``1 - valid / dispatched`` the share that was padding;
     - ``joins``: requests that prefilled into a slot while OTHER slots
       were mid-decode (the continuous-batching event: nobody waited for
       a cohort to finish);
@@ -441,6 +446,8 @@ class DecodeMetrics:
             self.prompt_tokens = 0
             self.tokens_out = 0
             self.prefill_dispatches = 0
+            self.prefill_rows_dispatched = 0
+            self.prefill_rows_valid = 0
             self.decode_dispatches = 0
             self.joins = 0
             self.slot_steps = 0
@@ -610,9 +617,12 @@ class DecodeMetrics:
             self.requests_completed += 1
             self.tokens_out += int(tokens)
 
-    def note_prefill(self, chunks: int = 1) -> None:
+    def note_prefill(self, dispatches: int, rows_dispatched: int,
+                     rows_valid: int) -> None:
         with self._lock:
-            self.prefill_dispatches += int(chunks)
+            self.prefill_dispatches += int(dispatches)
+            self.prefill_rows_dispatched += int(rows_dispatched)
+            self.prefill_rows_valid += int(rows_valid)
 
     def note_decode_dispatch(self, active: int, capacity: int,
                              rungs: int, table_rows: int) -> None:
@@ -653,6 +663,8 @@ class DecodeMetrics:
                 "prompt_tokens": self.prompt_tokens,
                 "tokens_out": self.tokens_out,
                 "prefill_dispatches": self.prefill_dispatches,
+                "prefill_rows_dispatched": self.prefill_rows_dispatched,
+                "prefill_rows_valid": self.prefill_rows_valid,
                 "decode_dispatches": self.decode_dispatches,
                 "decode_dispatch_rungs": self.decode_dispatch_rungs,
                 "decode_table_rows": self.decode_table_rows,

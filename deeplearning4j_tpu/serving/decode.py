@@ -22,8 +22,9 @@ recipe, implemented here:
   covers the longest running slot.
 - New requests JOIN the running batch: the prompt is prefilled into a
   free slot with the chunked dense prefill executable (matmul-bound
-  slabs, one page write a chunk into the live pool) between two
-  decode steps — nobody waits for a cohort to finish.  Finished
+  slabs of SEVERAL pages a dispatch, ``DecodeEngine.prefill_rows``,
+  written into the live pool) between two decode steps — nobody waits
+  for a cohort to finish.  Finished
   sequences (EOS or token budget) free their slot and pages mid-flight
   and the next pending request takes them.
 - ``ContinuousBatcher`` is the front-end: a background worker owns the
@@ -88,7 +89,9 @@ the pool, never an all-layer view — and writes each active slot's
 fresh rows of that layer at (layer, page, offset), in place; an
 inactive or stalled slot's rows go to the trash page 0.  A prefill
 dispatch reads one slot's pages, at the width of the request's OWN
-rung (``pick_bucket(prompt + max_tokens)``), and writes one page.
+rung (``pick_bucket(prompt + max_tokens)``), and writes the pages of
+its ``prefill_rows(rung)`` rows (a whole number of pages the engine
+derives; the page width is not the dispatch width).
 HBM holds what live tokens occupy, so admission counts free pages as
 well as free slots, and a slot whose next page cannot be had STALLS a
 dispatch instead of failing (:class:`KVPagesExhausted` only when
@@ -107,7 +110,9 @@ there is one kind, as ever).  A kind's table row is a ring of its
 ``cap`` columns, the page of positions ``j C ..`` in column ``j %
 cap``: a bounded kind REUSES a slot's oldest page for its newest rows,
 in decode steps and between prefill chunks alike, with no allocator
-call (the page's rows all lie further back than the window reads); an
+call (the page's rows all lie further back than the window reads; a
+prefill dispatch of such a family therefore carries ONE page: ``m``
+pages would need ``m - 1`` more columns in the ring, ROADMAP R1); an
 unbounded kind's ``cap`` is the longest rung's pages and never wraps.
 One algorithm with the kinds as data: ``gpt`` and ``deepseek_v2``
 declare none and run it with one unbounded kind.  A family with a
@@ -136,6 +141,14 @@ from deeplearning4j_tpu.parallel.mesh import (MODEL_AXIS, mesh_signature,
                                               model_degree)
 from deeplearning4j_tpu.runtime import compile_cache, quantize as qz, telemetry
 from deeplearning4j_tpu.runtime.metrics import decode_metrics
+
+#: the most rows ONE prefill dispatch carries (``DecodeEngine
+#: .prefill_rows``): a dispatch reads every weight once whatever its
+#: rows, and bfloat16 weights on a v5e want some 240 rows before the
+#: products cost what the read does (197 TFLOP/s over 819 GB/s).  One
+#: value for every family, fixed from chip runs of the GPT-2 and
+#: DeepSeek-V2 prefill cells at 128 and 256 (PERF.md section 6, PR 33).
+PREFILL_ROWS_MAX = 256
 
 
 def model_family(cfg):
@@ -224,8 +237,8 @@ def hold_in_compute_dtype(cfg, tree: Any, shardings: Any = None,
 #: tokens per KV page — ONE constant shared by the page allocator and
 #: the PrefixCache's chunk alignment (== gpt.PREFILL_CHUNK, drift-guarded
 #: by tests/test_serving_tier3.py): harvested prefix pages mount into
-#: slots without re-chunking, and every prefill chunk is exactly
-#: one page write
+#: slots without re-chunking (a prefill dispatch writes several of them:
+#: ``DecodeEngine.prefill_rows``)
 KV_PAGE_TOKENS = gpt.PREFILL_CHUNK
 
 
@@ -632,6 +645,11 @@ class DecodeEngine:
     ``n_slots`` sequences of the largest bucket, + the trash page; where
     the family declares several kinds of page, every kind, a bounded
     one never past ``n_slots`` rings);
+    ``prefill_chunk`` is the PAGE WIDTH (``page_tokens``: shrunk to the
+    largest width that divides every rung), and no longer the rows of a
+    prefill dispatch, which the engine derives a rung
+    (:meth:`prefill_rows`); the keyword keeps its name until the
+    benchmark's callers can be edited;
     ``paged`` selects nothing — ``True`` is the only value, kept until
     the benchmark's callers stop passing it.
 
@@ -720,13 +738,13 @@ class DecodeEngine:
             raise ValueError(
                 f"bucket {self.buckets[-1]} exceeds the model's "
                 f"max_len {cfg.max_len}")
-        # prefill slabs are written at chunk-aligned offsets, so every
-        # bucket length must be a multiple of the chunk width or the
-        # final slab of a near-full prompt would fall off the cache
-        # end.  The chunk is a perf knob, not a semantic one: shrink it
-        # to the largest width dividing every rung (>= 1 always works)
-        # rather than reject ladders like (32, 48) that max_len and
-        # default_length_buckets legitimately produce.
+        # pages are written at page-aligned offsets, so every rung must
+        # be a whole number of pages or the last page of a near-full
+        # prompt would fall off the table's end.  The width is a perf
+        # knob, not a semantic one: shrink it to the largest width
+        # dividing every rung (>= 1 always works) rather than reject
+        # ladders like (32, 48) that max_len and default_length_buckets
+        # legitimately produce.
         import math
         chunk = min(self.prefill_chunk, self.buckets[0])
         for t in self.buckets:
@@ -736,12 +754,13 @@ class DecodeEngine:
                 f"prefill_chunk must be >= 1: {self.prefill_chunk}")
         self.prefill_chunk = chunk
         self.label = label
-        # page geometry: the page width IS the (gcd-shrunk) prefill
-        # chunk, so every prefill chunk is exactly one page write and
-        # chunk-aligned prefix pages mount page-aligned.  The pool
-        # defaults to room for n_slots sequences of the largest bucket
-        # (+ the trash page); pass n_pages to shrink it — bounding HBM
-        # by live tokens is the point of the knob.
+        # page geometry: the page width is the (gcd-shrunk) value of
+        # the ``prefill_chunk`` keyword; pools, tables, allocators and
+        # prefix alignment are per page.  How many pages ONE prefill
+        # dispatch carries is derived below (``_prefill_rows``), not
+        # given.  The pool defaults to room for n_slots sequences of
+        # the largest bucket (+ the trash page); pass n_pages to shrink
+        # it — bounding HBM by live tokens is the point of the knob.
         self.page_tokens = chunk
         # the kinds of page the family's pool has (one where it states
         # none), each with its allocator and its table: ``n_pages``
@@ -772,6 +791,15 @@ class DecodeEngine:
                                  and not self._kinds[0].bounded)
         self._resident: "OrderedDict[bytes, Tuple[np.ndarray, Tuple[int, ...]]]" = OrderedDict()
         self._resident_max = max(self._kinds[0].alloc.n_pages // 2, 1)
+        # rows of a prefill dispatch, a rung: a whole number of pages,
+        # at most the rung and at most PREFILL_ROWS_MAX — and ONE page
+        # where the family has a bounded kind of page, whose ring holds
+        # only the pages one page's rows can reach back to
+        one_page = any(k.bounded for k in self._kinds)
+        self._prefill_rows = {
+            t: chunk * (1 if one_page
+                        else max(1, min(t, PREFILL_ROWS_MAX) // chunk))
+            for t in self.buckets}
         cfg_d = None
         self._draft_cfg = self._draft_params = None
         if draft is not None:
@@ -1055,6 +1083,14 @@ class DecodeEngine:
         raise ValueError(
             f"request needs {total_len} positions; largest bucket is "
             f"{self.buckets[-1]} (model max_len {self.cfg.max_len})")
+
+    def prefill_rows(self, bucket: int) -> int:
+        """Rows ONE prefill dispatch of rung ``bucket`` carries: a whole
+        number of pages, at most the rung, at most
+        :data:`PREFILL_ROWS_MAX`; one page for a family that declares a
+        bounded kind of page.  Derived from what the engine observes
+        (page width, rung, the family's kinds), no option."""
+        return self._prefill_rows[bucket]
 
     def free_slot(self) -> Optional[int]:
         for i, o in enumerate(self._slots.owners):
@@ -1450,7 +1486,7 @@ class DecodeEngine:
         t0 = time.perf_counter()
         with telemetry.span("decode.warmup", buckets=len(self.buckets)):
             for t in self.buckets:
-                toks = np.zeros((self.prefill_chunk,), np.int32)
+                toks = np.zeros((self.prefill_rows(t),), np.int32)
                 # all warmup dispatches run with ZERO page tables
                 # and all-inactive masks: every write lands in the
                 # trash page, the allocator is untouched, and the
@@ -1553,11 +1589,17 @@ class DecodeEngine:
             if hit is not None:
                 hit_len, host_pages = hit
         h = hit_len // C
+        # the join walks the prompt from page h (a prefix hit stays
+        # page-aligned) in dispatches of ``rows`` rows, a whole number
+        # of pages; the last one is padded (``n_valid``)
+        rows = self.prefill_rows(bucket)
+        n_rows = prompt.size - h * C        # what no prefix hit covers
         with telemetry.span("decode.prefill",
                             counter=(decode_metrics, "prefill_s"),
                             rid=rid, bucket=bucket, slot=slot,
                             prompt_tokens=int(prompt.size),
-                            chunks=n_chunks, prefix_hit_tokens=hit_len):
+                            chunks=n_chunks, rows=rows,
+                            prefix_hit_tokens=hit_len):
             # only a RESIDENT hit reuses pages by reference (a family
             # that mounts has one kind of page); a host-store hit copies
             # into fresh pool pages, so it needs the full n_chunks
@@ -1585,26 +1627,20 @@ class DecodeEngine:
                     self._pool = pool = self._write(
                         pool, pids, *self._pad_pool_pages(host_pages, tbl))
                 ptab_s = self._tables(bucket, slot)
-                for c in range(h, n_chunks):
-                    lo = c * C
-                    n_valid = min(C, prompt.size - lo)
-                    chunk = np.zeros((C,), np.int32)
-                    chunk[:n_valid] = prompt[lo:lo + n_valid]
+                for chunk, lo, n_valid in self._prompt_dispatches(
+                        prompt, h, rows):
                     pool, first = self._prefill(
                         params, pool, ptab_s, chunk,
                         np.int32(lo), np.int32(n_valid),
                         np.float32(temperature), np.uint32(seed))
                     self._pool = pool
                 if self.draft is not None:
-                    # draft prefills EVERY chunk: host-store hits carry
+                    # draft prefills EVERY page: host-store hits carry
                     # no draft KV, and re-writing a resident page's
                     # draft rows recomputes identical values (same
                     # tokens, same draft params) — harmless either way
-                    for c in range(n_chunks):
-                        lo = c * C
-                        n_valid = min(C, prompt.size - lo)
-                        chunk = np.zeros((C,), np.int32)
-                        chunk[:n_valid] = prompt[lo:lo + n_valid]
+                    for chunk, lo, n_valid in self._prompt_dispatches(
+                            prompt, 0, rows):
                         self._dpool = self._draft_prefill(
                             self._draft_params, self._dpool, ptab_s, chunk,
                             np.int32(lo), np.int32(n_valid))
@@ -1623,7 +1659,8 @@ class DecodeEngine:
             with telemetry.span("decode.prefill.sync",
                                 counter=(decode_metrics, "prefill_sync_s")):
                 first_tok = int(first)          # join-time sync, once
-        decode_metrics.note_prefill(n_chunks - h)
+        dispatched = -(-n_rows // rows)
+        decode_metrics.note_prefill(dispatched, dispatched * rows, n_rows)
         if hit_len:
             decode_metrics.note_prefix_hit(hit_len)
             telemetry.event("decode.prefix_hit", bucket=bucket, slot=slot,
@@ -1659,6 +1696,17 @@ class DecodeEngine:
         b.owners[slot] = owner
         b.rung[slot] = bucket
         return slot, first_tok
+
+    def _prompt_dispatches(self, prompt: np.ndarray, first_page: int,
+                           rows: int):
+        """What the prefill dispatches of ``prompt`` from page
+        ``first_page`` on take, ``rows`` rows (a whole number of pages)
+        each: (tokens [rows] zero-padded, start position, valid rows)."""
+        for lo in range(first_page * self.page_tokens, prompt.size, rows):
+            n_valid = min(rows, prompt.size - lo)
+            chunk = np.zeros((rows,), np.int32)
+            chunk[:n_valid] = prompt[lo:lo + n_valid]
+            yield chunk, lo, n_valid
 
     def _stage(self, span: int):
         """What one dispatch for every running slot takes: the pages
