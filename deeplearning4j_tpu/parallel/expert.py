@@ -285,6 +285,27 @@ def route_topk_renorm(scores: Array, top_k: int) -> Tuple[Array, Array]:
                      0.0), chosen
 
 
+def route_sigmoid_bias(scores: Array, bias: Array, top_k: int, scale: float
+                       ) -> Tuple[Array, Array]:
+    """DeepSeek-V3's routing with one group (``scoring_func`` sigmoid,
+    ``n_group = topk_group = 1``, ``norm_topk_prob`` true): ``scores``
+    [N, E] float32 are the SIGMOIDS of the router's logits, each expert's
+    own and no distribution over them.  The ``top_k`` largest of
+    ``scores + bias`` a token are taken (``bias`` [E], the selection bias
+    that balances load and takes no part in the result), each weighted
+    ``scale`` times its UNBIASED score's share of the taken ones' sum.
+    Returns what :func:`route_group_limited` does: (weights [N, E]
+    float32, zero off the chosen experts and summing to ``scale`` a
+    token; chosen [N, E] bool)."""
+    N, E = scores.shape
+    _, top_experts = lax.top_k(scores + bias[None, :], top_k)   # [N, k]
+    chosen = jnp.zeros((N, E), jnp.bool_).at[
+        jnp.arange(N)[:, None], top_experts].set(True)
+    taken = jnp.where(chosen, scores, 0.0)
+    return taken * (scale / (taken.sum(axis=-1, keepdims=True) + 1e-20)
+                    ), chosen
+
+
 def gated_ffn(x: Array, w_gate: Array, w_up: Array, w_down: Array) -> Array:
     """``W_down(silu(W_gate x) * W_up x)``: operands in ``x``'s type,
     products accumulated and returned in float32."""

@@ -118,6 +118,23 @@ One algorithm with the kinds as data: ``gpt`` and ``deepseek_v2``
 declare none and run it with one unbounded kind.  A family with a
 bounded kind mounts no prefix by reference (a later slot would be
 handed pages since written over).
+
+SPECULATIVE ROUNDS.  ``advance_spec()`` is a round that commits more
+than one token a slot.  With a SECOND MODEL as the draft
+(``draft=(cfg_d, params_d)``, ``models/gpt.py`` only) the draft proposes
+``draft_k`` tokens in one dispatch over a second pool behind the same
+tables and the target verifies them in another.  With the model's OWN
+block as the draft (``draft="self"``: a family that has
+``paged_self_draft_round``, ``models/exaone_moe.py``'s MTP block) the
+round is ONE dispatch: every slot feeds its current token and its
+pending draft, the family verifies, commits and drafts again, and the
+next drafts come back with the tokens in the one fetch; the draft
+block's cache rows live in the family's own pool behind the slot's own
+tables, and the join's prefill dispatches fill them.  Rows written
+ahead of a slot's committed frontier into a bounded kind's ring are
+held to THE RING RULE (:class:`_PageKind`).  Either way the committed
+stream is, token for token, the plain ``advance()``'s: sampling keys
+are of (seed, position), not of a step.
 """
 
 from __future__ import annotations
@@ -162,8 +179,13 @@ def model_family(cfg):
     another family's), ``DECODE_COUNTERS`` (names of the counts its
     ``paged_decode`` appends to the step's tokens), ``page_kinds(cfg,
     page_tokens)`` (the KINDS OF PAGE its pool has, each ``(name, the
-    most pages a slot may hold or None for a rung's worth)``: see
-    :class:`_PageKind`; a family that states none has one kind) and
+    most pages a slot may hold or None for a rung's worth)`` and, for a
+    bounded kind that takes speculative rows, the rows a dispatch may
+    write ahead of the committed frontier: see
+    :class:`_PageKind`; a family that states none has one kind),
+    ``paged_self_draft_prefill`` / ``paged_self_draft_round`` /
+    ``self_draft_depth(cfg)`` (its own draft block:
+    ``DecodeEngine(draft="self")``) and
     ``COMPUTE_DTYPE_LEAVES`` (the leaves its steps read only through
     ``.astype(cfg.compute_dtype)``, each by its keys from the root:
     :func:`hold_in_compute_dtype`)."""
@@ -374,15 +396,29 @@ class _PageKind:
     ``j % cap``, so a sequence that outgrows ``cap`` pages writes its
     newest rows over its oldest page, and a kind without a bound
     (``cap`` columns cover the longest rung) never wraps: the one
-    table of PR 31."""
+    table of PR 31.
 
-    __slots__ = ("name", "bounded", "cap", "alloc", "ptab", "n_pages")
+    THE RING RULE.  A speculative round writes ``k + 1`` rows a slot,
+    ``k`` of them AHEAD of the slot's committed frontier ``p``, and the
+    page a row at ``p + a`` opens lies over the page ``cap`` before it.
+    That page must hold no row the frontier still reads, so a bounded
+    kind states ``ahead``, the most rows past the frontier a dispatch
+    may write into its ring (what its ``cap`` pages leave over the rows
+    a layer of the kind reads back; 0 where the family states none), and
+    the engine refuses ``k > ahead`` at construction.  Inside that bound
+    a rejected draft's row harms nothing: the slot's next round writes
+    the committed token's row over it, and no row attends it before,
+    because every mask is by position."""
+
+    __slots__ = ("name", "bounded", "cap", "ahead", "alloc", "ptab",
+                 "n_pages")
 
     def __init__(self, name: str, bound: Optional[int], table_pages: int,
-                 n_slots: int, n_pages: Optional[int]):
+                 n_slots: int, n_pages: Optional[int], ahead: int = 0):
         self.name = name
         self.bounded = bound is not None
         self.cap = min(bound, table_pages) if self.bounded else table_pages
+        self.ahead = int(ahead)
         size = n_slots * self.cap + 1               # + the trash page
         if n_pages and not (self.bounded and n_pages > size):
             size = int(n_pages)
@@ -615,7 +651,7 @@ class DecodeEngine:
     is an argument, not an import: the engine
     takes its pool and its two dispatches from the family of ``cfg``
     (:func:`model_family`: ``models/gpt.py``, ``models/deepseek_v2.py``,
-    ``models/mellum.py``)
+    ``models/mellum.py``, ``models/exaone_moe.py``)
     and holds, for each ``params`` tree it is given, the tree its
     executables take (``current_params()``), made once per tree: the
     leaves the family names in ``COMPUTE_DTYPE_LEAVES`` (those its steps
@@ -629,9 +665,12 @@ class DecodeEngine:
     caller keeps its own tree (the engine drops its reference to a
     static one).  The draft's tree is held the same way by its own
     config.  ``quantize`` takes this step's place where it is set.  The
-    mesh, quantization, int8 pools, speculative decoding and the
+    mesh, quantization, int8 pools, a second model as the draft and the
     prefix store are ``models/gpt.py``'s; a family
-    that has none of them says so and the engine raises.  NOT
+    that has none of them says so and the engine raises
+    (``draft="self"`` is a family's own draft block:
+    ``models/exaone_moe.py``; ``draft_k`` defaults to 4 proposals of a
+    second model, to what the family's own blocks draft for "self").  NOT
     thread-safe: exactly one thread (normally the
     ``ContinuousBatcher`` worker) may drive ``start``/``advance``/
     ``release``; construction and ``warmup()`` happen before serving.
@@ -670,8 +709,8 @@ class DecodeEngine:
                  kv_dtype: Optional[str] = None,
                  prefix_cache: Any = None,
                  paged: bool = True, n_pages: Optional[int] = None,
-                 draft: Optional[Tuple[Any, Any]] = None,
-                 draft_k: int = 4):
+                 draft: Any = None,
+                 draft_k: Optional[int] = None):
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1: {n_slots}")
         self.cfg = cfg
@@ -700,7 +739,28 @@ class DecodeEngine:
         #: its tokens (``decode_metrics.note_family_counts``)
         self._decode_counters = tuple(getattr(fam, "DECODE_COUNTERS", ()))
         self.draft = draft
-        self.draft_k = int(draft_k)
+        #: the draft is the model's OWN block (``draft="self"``: a family
+        #: with ``paged_self_draft_round``), not a second model: proposal
+        #: and verify ride in the one dispatch a round makes
+        self._self_draft = isinstance(draft, str)
+        if self._self_draft:
+            if draft != "self" or not hasattr(fam, "paged_self_draft_round"):
+                raise ValueError(
+                    f"DecodeEngine(draft={draft!r}): the {fam_name} family "
+                    f"has no draft of its own; pass (draft_cfg, "
+                    f"draft_params)")
+            depth = int(fam.self_draft_depth(cfg))
+            draft_k = depth if draft_k is None else draft_k
+            if not 1 <= draft_k <= depth:
+                raise ValueError(
+                    f"draft_k must be 1..{depth}, the tokens "
+                    f"{type(cfg).__name__}'s own blocks draft a round: "
+                    f"{draft_k}")
+        elif draft is not None and fam is not gpt:
+            raise ValueError(
+                f"the {fam_name} family takes no second model as its "
+                f"draft: pass draft=\"self\"")
+        self.draft_k = int(4 if draft_k is None else draft_k)
         if draft is not None and self.draft_k < 1:
             raise ValueError(f"draft_k must be >= 1: {draft_k}")
         #: graceful-brownout knobs (the AutoscalingRouter pressure
@@ -768,9 +828,18 @@ class DecodeEngine:
         declared = getattr(fam, "page_kinds", None)
         self._kinds: Tuple[_PageKind, ...] = tuple(
             _PageKind(name, bound, self.buckets[-1] // chunk, self.n_slots,
-                      n_pages)
-            for name, bound in (declared(cfg, chunk) if declared
-                                else (("kv", None),)))
+                      n_pages, *ahead)
+            for name, bound, *ahead in (declared(cfg, chunk) if declared
+                                        else (("kv", None),)))
+        if draft is not None:
+            # the ring rule (:class:`_PageKind`)
+            for k in self._kinds:
+                if k.bounded and self.draft_k > k.ahead:
+                    raise ValueError(
+                        f"draft_k={self.draft_k} rows ahead of the "
+                        f"committed frontier do not fit the {k.name!r} "
+                        f"kind's ring of {k.cap} page(s) of {chunk}: a "
+                        f"round may write {k.ahead} ahead")
         #: names of the kinds a family DECLARED (their counters and span
         #: attributes are noted; a one-kind family's round notes nothing
         #: it did not before)
@@ -802,7 +871,11 @@ class DecodeEngine:
             for t in self.buckets}
         cfg_d = None
         self._draft_cfg = self._draft_params = None
-        if draft is not None:
+        #: a self-draft's pending proposals, one row a slot (host mirror,
+        #: as ``tokens_h``: every round takes them and hands back the next)
+        self._drafts_h = (np.zeros((self.n_slots, self.draft_k), np.int32)
+                          if self._self_draft else None)
+        if draft is not None and not self._self_draft:
             cfg_d, self._draft_params = draft
             self._draft_cfg = cfg_d
             if not getattr(cfg_d, "causal", False):
@@ -829,7 +902,21 @@ class DecodeEngine:
             return fam.paged_decode(cfg, params, pool, ptab, tokens,
                                     pos, active, temperature, seeds)
 
-        if draft is not None:
+        if self._self_draft:
+            # the join fills the draft block's cache and brings the first
+            # draft back with the first token; a round is ONE program
+            def prefill_fn(params, pool, ptab_s, toks, nxt, start, n_valid,  # noqa: F811 — the self-draft's prefill takes the plain one's place AND its name: the benchmark finds the program by it
+                           temperature, seed):
+                return fam.paged_self_draft_prefill(
+                    cfg, params, pool, ptab_s, toks, nxt, start, n_valid,
+                    temperature, seed)
+
+            def spec_fn(params, pool, ptab, tokens, pos, active,
+                        temperature, seeds, drafts):
+                return fam.paged_self_draft_round(
+                    cfg, params, pool, ptab, tokens, pos, active,
+                    temperature, seeds, drafts)
+        elif draft is not None:
             def verify_fn(params, pool, ptab, tokens, pos, active,
                           temperature, seeds, drafts):
                 return gpt.paged_verify(cfg, params, pool, ptab,
@@ -865,7 +952,8 @@ class DecodeEngine:
         # served to a full-precision engine or vice versa
         geo = (self.n_slots, self.prefill_chunk, mesh_signature(mesh),
                self.quantize, self.kv_dtype, ("paged", self.n_kv_pages),
-               (repr(cfg_d), self.draft_k) if draft is not None else None)
+               ((draft if self._self_draft else repr(cfg_d), self.draft_k)
+                if draft is not None else None))
         shard_kw_prefill: Dict[str, Any] = {}
         shard_kw_decode: Dict[str, Any] = {}
         shard_kw_read: Dict[str, Any] = {}
@@ -919,7 +1007,7 @@ class DecodeEngine:
                                  out_shardings=page_sh)
             shard_kw_write = dict(in_shardings=(poolsh, repl) + page_sh,
                                   out_shardings=poolsh)
-            if draft is not None:
+            if cfg_d is not None:
                 shard_kw_verify = dict(
                     in_shardings=(psh, poolsh) + (repl,) * 7,
                     out_shardings=(poolsh, repl, repl))
@@ -940,7 +1028,7 @@ class DecodeEngine:
                 shard_kw_dprefill = dict(
                     in_shardings=(dpsh, dpoolsh) + (repl,) * 4,
                     out_shardings=dpoolsh)
-        if draft is not None:
+        if cfg_d is not None:
             self._draft_params = self._hold_draft(self._draft_params)
         self._prefill = compile_cache.cached_jit(
             prefill_fn, key=(key, geo, "prefill"),
@@ -951,7 +1039,12 @@ class DecodeEngine:
             label=f"{label}.step", donate_argnums=(1,),
             **shard_kw_decode)
         self._verify = self._draft_fn = self._draft_prefill = None
-        if draft is not None:
+        self._spec = None
+        if self._self_draft:
+            self._spec = compile_cache.cached_jit(
+                spec_fn, key=(key, geo, "spec"),
+                label=f"{label}.spec", donate_argnums=(1,))
+        elif draft is not None:
             k_steps = self.draft_k
 
             def draft_fn(params_d, dpool, ptab, tokens, pos, active):
@@ -1002,7 +1095,7 @@ class DecodeEngine:
         #: live tokens, not bucket length
         self.pool_bytes = int(fam.pages_bytes(
             cfg, self.n_kv_pages, self.page_tokens, self.kv_dtype))
-        if draft is not None:
+        if cfg_d is not None:
             self.pool_bytes += int(gpt.pages_bytes(
                 cfg_d, self.n_kv_pages, self.page_tokens, self.kv_dtype))
         # prefix harvesting is ASYNC: the page read dispatches on the
@@ -1131,7 +1224,7 @@ class DecodeEngine:
             if self._pool_shardings is not None:
                 pool = jax.device_put(pool, self._pool_shardings)
             self._pool = pool
-        if self.draft is not None and self._dpool is None:
+        if self._draft_cfg is not None and self._dpool is None:
             # the draft pool is indexed by the SAME page tables as the
             # target's (same positions, same allocator) — one allocator
             # covers both models
@@ -1231,16 +1324,18 @@ class DecodeEngine:
         decode_metrics.note_pages_leaked(self.pages_unaccounted())
 
     def _note_kinds(self, written_at: np.ndarray = np.zeros((0,), np.int32),
-                    decode: bool = False) -> None:
+                    held_at: Optional[np.ndarray] = None) -> None:
         """The per-kind counters of a family that declared its kinds of
         page, after rows were written at positions ``written_at`` (a
-        decode dispatch's, one a slot that ran, or the starts of a
+        decode dispatch's, one a slot that ran; a speculative round's
+        COMMITTED rows, each position once; or the starts of a
         prefill's chunks): the gauges ``pages_in_use_<kind>``;
         ``<kind>_pages_reused`` of a bounded kind grows by the rows that
         opened a page past the ring, written over the slot's oldest;
         after a decode dispatch ``kv_rows_held_<kind>`` grows by the
-        rows a layer of the kind then holds for those slots (a ring of
-        ``cap`` pages holds the newest)."""
+        rows a layer of the kind then holds for the slots that ran,
+        ``held_at`` the last row each committed (a ring of ``cap`` pages
+        holds the newest)."""
         C = self.page_tokens
         gauges, counts = {}, {}
         for k in self._kinds:
@@ -1249,10 +1344,10 @@ class DecodeEngine:
                 counts[f"{k.name}_pages_reused"] = int(
                     ((written_at % C == 0) & (written_at // C >= k.cap)
                      ).sum())
-            if decode:
-                behind = np.maximum(0, written_at // C + 1 - k.cap) * C
+            if held_at is not None:
+                behind = np.maximum(0, held_at // C + 1 - k.cap) * C
                 counts[f"kv_rows_held_{k.name}"] = int(
-                    (written_at + 1 - behind).sum())
+                    (held_at + 1 - behind).sum())
         decode_metrics.note_page_kinds(gauges, counts)
 
     def _drop_pool(self) -> None:
@@ -1476,7 +1571,9 @@ class DecodeEngine:
         if self._prefix is not None:
             labels += [f"{self.label}.prefix_read",
                        f"{self.label}.prefix_write"]
-        if self.draft is not None:
+        if self._self_draft:
+            labels += [f"{self.label}.spec"]
+        elif self.draft is not None:
             labels += [f"{self.label}.draft",
                        f"{self.label}.draft_prefill",
                        f"{self.label}.verify"]
@@ -1496,14 +1593,19 @@ class DecodeEngine:
                     self._idle_step_args(t)
                 ptab_s = jax.tree.map(lambda a: a[0], ptab)
                 pool, _ = self._prefill(
-                    params, pool, ptab_s, toks, np.int32(0),
+                    params, pool, ptab_s, toks,
+                    *((toks,) if self._self_draft else ()), np.int32(0),
                     np.int32(1), np.float32(0.0), np.uint32(0))
                 self._pool = pool
                 if self._prefix is not None:
                     pages = self._read(pool, ptab_s)
                     self._pool = pool = self._write(pool, ptab_s,
                                                     *pages)
-                if self.draft is not None:
+                if self._self_draft:
+                    self._pool, _ = self._spec(
+                        params, pool, ptab, tokens, pos, idle, temps, seeds,
+                        self._drafts_h)
+                elif self.draft is not None:
                     self._dpool = self._draft_prefill(
                         self._draft_params, self._dpool, ptab_s,
                         toks, np.int32(0), np.int32(1))
@@ -1532,15 +1634,22 @@ class DecodeEngine:
                 "warmup_ms": round(wall_ms, 1)}
 
     def _lower_decode(self, bucket: int):
-        """The decode step lowered for this engine at ``bucket``'s
-        table width (traced again; compiling it hits the cache): for
-        set-up, never for the serving thread."""
+        """The program a round dispatches (the decode step; a
+        self-draft's speculative round) lowered for this engine at
+        ``bucket``'s table width (traced again; compiling it hits the
+        cache): for set-up, never for the serving thread."""
+        if self._self_draft:
+            return self._spec.jitted.lower(
+                self.current_params(), self._pool_state(),
+                *self._idle_step_args(bucket), self._drafts_h)
         return self._decode.jitted.lower(
             self.current_params(), self._pool_state(),
             *self._idle_step_args(bucket))
 
     def decode_hlo(self, bucket: int) -> str:
-        """Optimized HLO text of the decode step at ``bucket``'s table
+        """Optimized HLO text of the program a round dispatches (the
+        decode step; the speculative round of a self-draft) at
+        ``bucket``'s table
         width as compiled for this engine, every instruction with XLA's
         ``op_name`` (the ``jax.named_scope`` path it was traced under).
         A device trace names its ops by instruction and carries no
@@ -1629,12 +1738,21 @@ class DecodeEngine:
                 ptab_s = self._tables(bucket, slot)
                 for chunk, lo, n_valid in self._prompt_dispatches(
                         prompt, h, rows):
+                    after = ()
+                    if self._self_draft:
+                        # the token behind each row; -1 behind the
+                        # prompt's last, which this dispatch samples
+                        nxt = np.zeros((rows,), np.int32)
+                        rest = prompt[lo + 1:lo + n_valid + 1]
+                        nxt[:rest.size] = rest
+                        nxt[rest.size:n_valid] = -1
+                        after = (nxt,)
                     pool, first = self._prefill(
-                        params, pool, ptab_s, chunk,
+                        params, pool, ptab_s, chunk, *after,
                         np.int32(lo), np.int32(n_valid),
                         np.float32(temperature), np.uint32(seed))
                     self._pool = pool
-                if self.draft is not None:
+                if self._draft_cfg is not None:
                     # draft prefills EVERY page: host-store hits carry
                     # no draft KV, and re-writing a resident page's
                     # draft rows recomputes identical values (same
@@ -1658,7 +1776,11 @@ class DecodeEngine:
                 raise
             with telemetry.span("decode.prefill.sync",
                                 counter=(decode_metrics, "prefill_sync_s")):
-                first_tok = int(first)          # join-time sync, once
+                if self._self_draft:            # [first, its draft]
+                    first_tok, self._drafts_h[slot, 0] = (
+                        int(t) for t in np.asarray(first))  # jaxlint: disable=host-sync-on-serving-worker — the join-time sync, once
+                else:
+                    first_tok = int(first)      # join-time sync, once
         dispatched = -(-n_rows // rows)
         decode_metrics.note_prefill(dispatched, dispatched * rows, n_rows)
         if hit_len:
@@ -1730,6 +1852,14 @@ class DecodeEngine:
         return (self._tables(w), b.tokens_h.copy(), b.pos_h.copy(), run, w,
                 rungs)
 
+    def _width_attrs(self, w: int, rungs: int) -> Dict[str, int]:
+        """What a ``decode.advance`` span says of its dispatch's table:
+        the width, the rungs carried, a declared kind's own width."""
+        return {"width": w, "rungs": rungs,
+                **{f"width_{name}": n * self.page_tokens
+                   for name, n in zip(self._kind_names,
+                                      self._table_widths(w))}}
+
     def advance(self) -> np.ndarray:
         """ONE decode dispatch for the engine: every active slot, of
         whatever rung, emits its next token, at the narrowest table
@@ -1744,11 +1874,7 @@ class DecodeEngine:
             params = self.current_params()
             with telemetry.span("decode.stage"):
                 ptab, tokens, pos, run, w, rungs = self._stage(0)
-                sp.set(width=w, rungs=rungs)
-                if self._kind_names:
-                    sp.set(**{f"width_{name}": n * self.page_tokens
-                              for name, n in zip(self._kind_names,
-                                                 self._table_widths(w))})
+                sp.set(**self._width_attrs(w, rungs))
                 pool = self._pool_state()
             with telemetry.span("decode.dispatch"):
                 try:
@@ -1772,7 +1898,7 @@ class DecodeEngine:
             decode_metrics.note_pages(self._alloc.in_use(),
                                       self._live_rows(), self.page_tokens)
             if self._kind_names:
-                self._note_kinds(pos[run], decode=True)
+                self._note_kinds(pos[run], held_at=pos[run])
             return toks
 
     @staticmethod
@@ -1797,6 +1923,8 @@ class DecodeEngine:
         to non-speculative decode; sampled targets stay identical too,
         because sampling keys are POSITION-keyed (gpt._slot_key), not
         step-keyed."""
+        if self._self_draft:
+            return self._advance_self_draft()
         if self._draft_fn is None:
             raise RuntimeError("engine built without draft=")
         b = self._slots
@@ -1837,8 +1965,65 @@ class DecodeEngine:
                 n_run, self.n_slots, rungs, self.n_slots * w)
             decode_metrics.note_spec(k * n_run,
                                      int(np.maximum(n_c - 1, 0).sum()))
+            sp.set(committed=int(n_c.sum()))
             decode_metrics.note_pages(self._alloc.in_use(),
                                       self._live_rows(), self.page_tokens)
+            return toks, n_c
+
+    def _advance_self_draft(self) -> Tuple[np.ndarray, np.ndarray]:
+        """:meth:`advance_spec` where the draft is the model's own block
+        (``draft="self"``): ONE dispatch a round.  Every running slot
+        feeds its current token and its pending draft (``_drafts_h``);
+        the family's round verifies, commits and drafts again, and the
+        tokens, the commit counts, the next drafts and the family's
+        counters (of all ``k + 1`` rows a slot, the rejected draft's
+        among them) come back in the one fetch.  The draft block's cache
+        rows live behind the slot's own tables, so there is no second
+        pool, and its prefill was the join's."""
+        b = self._slots
+        k, S = self.draft_k, self.n_slots
+        with telemetry.span("decode.advance",
+                            counter=(decode_metrics, "advance_s"),
+                            active=self.n_active(), k=k) as sp:
+            params = self.current_params()
+            with telemetry.span("decode.stage"):
+                ptab, tokens, pos, run, w, rungs = self._stage(k)
+                sp.set(**self._width_attrs(w, rungs))
+                pool = self._pool_state()
+            with telemetry.span("decode.dispatch"):
+                try:
+                    pool, out = self._spec(params, pool, ptab, tokens, pos,
+                                           run, b.temps, b.seeds,
+                                           self._drafts_h.copy())
+                except Exception:
+                    self._drop_pool()           # donated into the failure
+                    raise
+                self._pool = pool
+            flat = self._fetch(out)
+            toks, n_c, nxt, counts = np.split(
+                flat, [S * (k + 1), S * (k + 2), S * (2 * k + 2)])
+            toks, nxt = toks.reshape(S, k + 1), nxt.reshape(S, k)
+            if self._decode_counters:
+                decode_metrics.note_family_counts(self._decode_counters,
+                                                  counts)
+            idx = np.flatnonzero(n_c)
+            b.tokens_h[idx] = toks[idx, n_c[idx] - 1]
+            b.pos_h += n_c
+            self._drafts_h[idx] = nxt[idx]
+            n_run = int(run.sum())
+            decode_metrics.note_decode_dispatch(
+                n_run, S, rungs, S * w)
+            decode_metrics.note_spec(k * n_run,
+                                     int(np.maximum(n_c - 1, 0).sum()))
+            sp.set(committed=int(n_c.sum()))
+            decode_metrics.note_pages(self._alloc.in_use(),
+                                      self._live_rows(), self.page_tokens)
+            if self._kind_names:
+                # every committed position once: a rejected draft's is
+                # the next round's own
+                rows = pos[:, None] + np.arange(k + 1)
+                self._note_kinds(rows[np.arange(k + 1) < n_c[:, None]],
+                                 held_at=pos[idx] + n_c[idx] - 1)
             return toks, n_c
 
     def release(self, slot: int) -> None:
