@@ -288,6 +288,15 @@ class DecodeMetrics:
       whole number of pages) and those of them that were prompt tokens:
       ``rows_dispatched / prefill_dispatches`` is the width a join
       took, ``1 - valid / dispatched`` the share that was padding;
+    - ``decode_dispatches_ahead``: decode dispatches issued while the
+      step before was still uncollected, their tokens taken from its
+      output on the device (``DecodeEngine.dispatch_step(after=)``): over
+      ``decode_dispatches`` the share of steps that overlapped the
+      host's deliver, expire, admit and stage (0 for speculative
+      rounds, which stay in series); ``decode_overshoot_steps``: slot
+      steps whose token was discarded when it landed because the
+      request had ended meanwhile (an ``eos_id`` is known one step
+      late; a deadline or an eviction with a step in flight);
     - ``joins``: requests that prefilled into a slot while OTHER slots
       were mid-decode (the continuous-batching event: nobody waited for
       a cohort to finish);
@@ -373,16 +382,21 @@ class DecodeMetrics:
     the counts their means are taken over:
 
     - ``rounds`` / ``round_s`` (``decode.round``): passes of the
-      batcher's loop that admitted or advanced anything;
+      batcher's loop that admitted or dispatched anything (a pass that
+      only lands the last step in flight adds its seconds, not a
+      round);
     - ``admissions`` / ``queue_wait_s``: requests taken off the queue
       and the time each had spent since its submit, booked where the
       wait ends; ``prefill_s`` (``decode.prefill``): their joins, every
       chunk's dispatch and the wait for the first token, and
       ``prefill_sync_s`` (``decode.prefill.sync``) that wait alone;
-    - ``advance_s`` (``decode.advance``): ``engine.advance`` calls, one
-      per ``decode_dispatches`` and, since the engine keeps one slot
-      table, one a round; ``fetch_s`` (``decode.fetch``): the
-      part of them spent waiting for the step's tokens.
+    - ``advance_s`` (``decode.advance``): the engine's decode steps,
+      one per ``decode_dispatches`` and, since the engine keeps one slot
+      table, one a round.  A plain step is two spans of that name, its
+      dispatch (``dispatch_step``) and, one step later under a batcher,
+      its collection (``collect``); ``fetch_s`` (``decode.fetch``): the
+      part of the second spent waiting for the step's tokens, which is
+      what of the device's time the host's other work did not cover.
 
     The tree a ``DecodeEngine`` holds for its executables
     (``serving.decode.hold_in_compute_dtype``, span
@@ -449,6 +463,8 @@ class DecodeMetrics:
             self.prefill_rows_dispatched = 0
             self.prefill_rows_valid = 0
             self.decode_dispatches = 0
+            self.decode_dispatches_ahead = 0
+            self.decode_overshoot_steps = 0
             self.joins = 0
             self.slot_steps = 0
             self.slot_capacity_steps = 0
@@ -625,13 +641,19 @@ class DecodeMetrics:
             self.prefill_rows_valid += int(rows_valid)
 
     def note_decode_dispatch(self, active: int, capacity: int,
-                             rungs: int, table_rows: int) -> None:
+                             rungs: int, table_rows: int,
+                             ahead: bool = False) -> None:
         with self._lock:
             self.decode_dispatches += 1
+            self.decode_dispatches_ahead += bool(ahead)
             self.slot_steps += int(active)
             self.slot_capacity_steps += int(capacity)
             self.decode_dispatch_rungs += int(rungs)
             self.decode_table_rows += int(table_rows)
+
+    def note_overshoot(self, slot_steps: int) -> None:
+        with self._lock:
+            self.decode_overshoot_steps += int(slot_steps)
 
     def note_queue_depth(self, depth: int) -> None:
         with self._lock:
@@ -666,6 +688,8 @@ class DecodeMetrics:
                 "prefill_rows_dispatched": self.prefill_rows_dispatched,
                 "prefill_rows_valid": self.prefill_rows_valid,
                 "decode_dispatches": self.decode_dispatches,
+                "decode_dispatches_ahead": self.decode_dispatches_ahead,
+                "decode_overshoot_steps": self.decode_overshoot_steps,
                 "decode_dispatch_rungs": self.decode_dispatch_rungs,
                 "decode_table_rows": self.decode_table_rows,
                 "joins": self.joins,

@@ -621,7 +621,7 @@ class _SlotTable:
     rows may grow to)."""
 
     __slots__ = ("active", "temps", "seeds", "owners", "rung",
-                 "tokens_h", "pos_h", "ran")
+                 "tokens_h", "pos_h", "ran", "epoch")
 
     def __init__(self, n_slots: int):
         self.active = np.zeros((n_slots,), np.bool_)
@@ -629,13 +629,50 @@ class _SlotTable:
         self.seeds = np.zeros((n_slots,), np.uint32)
         self.owners: List[Any] = [None] * n_slots
         self.rung = np.zeros((n_slots,), np.int32)
-        # host mirrors of tokens/pos (deterministic from the fetched
-        # stream: every dispatch, the draft's too, takes them); ``ran``
-        # is the last dispatch's progress mask (a slot stalls when its
-        # next page cannot be allocated)
+        # host mirrors of tokens/pos.  ``pos_h`` is a COUNT: a plain
+        # step moves every slot that ran by one, at dispatch, whatever
+        # its token turns out to be, so the next dispatch's pages, width
+        # and positions never wait for a fetch.  ``tokens_h`` is the
+        # fetched stream: it lags a step that is still uncollected
+        # (:class:`_Step`), whose tokens the next dispatch then takes ON
+        # THE DEVICE, and feeds only the slots that step did not run (a
+        # slot that joined since, a slot that stalled on pages).
+        # ``ran`` is the last dispatch's progress mask (a slot stalls
+        # when its next page cannot be allocated)
         self.ran = np.zeros((n_slots,), np.bool_)
         self.tokens_h = np.zeros((n_slots,), np.int32)
         self.pos_h = np.zeros((n_slots,), np.int32)
+        # bumped when a slot is started or released: a step in flight
+        # knows by it whether a slot still holds the sequence it ran
+        self.epoch = np.zeros((n_slots,), np.int64)
+
+
+class _Step:
+    """One plain decode dispatch on its way: what
+    :meth:`DecodeEngine.dispatch_step` hands back and
+    :meth:`DecodeEngine.collect` takes.  ``out`` is the step's output
+    ON THE DEVICE (the ``[S]`` tokens, a family's counters behind them;
+    its copy to the host was asked for at dispatch), ``run`` the slots
+    it moved, ``epoch`` each slot's occupancy stamp then, ``pos`` the
+    positions it fed, ``w`` / ``rungs`` its table width and the rungs it
+    carried, ``pages`` the pages in use and the rows live behind it.
+    ``after`` is the uncollected step whose tokens it took on the device
+    (None: all from the host mirror), kept until this step is collected:
+    a step may run ONE ahead of the fetch, never two."""
+
+    __slots__ = ("out", "run", "epoch", "pos", "w", "rungs", "pages",
+                 "after", "collected")
+
+    def __init__(self, out, run, epoch, pos, w, rungs, pages, after):
+        self.out = out
+        self.run = run
+        self.epoch = epoch
+        self.pos = pos
+        self.w = w
+        self.rungs = rungs
+        self.pages = pages
+        self.after = after
+        self.collected = False
 
 
 class DecodeEngine:
@@ -902,6 +939,16 @@ class DecodeEngine:
             return fam.paged_decode(cfg, params, pool, ptab, tokens,
                                     pos, active, temperature, seeds)
 
+        n_slots = self.n_slots  # the compile engine keeps what it jits,
+        #                         closure and all: never close over self
+
+        def tokens_ahead_fn(prev_out, tokens_h, from_host):
+            # the tokens of a step dispatched while the step before it is
+            # still uncollected: that step's output where it ran the
+            # slot, the host mirror elsewhere (a family's counters ride
+            # behind the S tokens of ``prev_out``)
+            return jnp.where(from_host, tokens_h, prev_out[:n_slots])
+
         if self._self_draft:
             # the join fills the draft block's cache and brings the first
             # draft back with the first token; a round is ONE program
@@ -961,6 +1008,7 @@ class DecodeEngine:
         shard_kw_verify: Dict[str, Any] = {}
         shard_kw_draft: Dict[str, Any] = {}
         shard_kw_dprefill: Dict[str, Any] = {}
+        shard_kw_ahead: Dict[str, Any] = {}
         self._param_shardings = None
         self._pool_shardings = None
         self._dpool_shardings = None
@@ -997,6 +1045,8 @@ class DecodeEngine:
             shard_kw_decode = dict(
                 in_shardings=(psh, poolsh) + (repl,) * 6,
                 out_shardings=(poolsh, repl))
+            shard_kw_ahead = dict(in_shardings=(repl,) * 3,
+                                  out_shardings=repl)
             # prefix pages [L, TBL, C, NH, D] shard over heads like
             # the pool rows they copy; int8 scale pages replicated
             page_sh = (NamedSharding(
@@ -1038,6 +1088,11 @@ class DecodeEngine:
             decode_fn, key=(key, geo, "step"),
             label=f"{label}.step", donate_argnums=(1,),
             **shard_kw_decode)
+        # its name matches none of the patterns a benchmark cell finds
+        # the step programs by (jit_decode_fn, jit_prefill_fn, jit_spec_fn)
+        self._tokens_ahead = compile_cache.cached_jit(
+            tokens_ahead_fn, key=(key, geo, "tokens_ahead"),
+            label=f"{label}.tokens_ahead", **shard_kw_ahead)
         self._verify = self._draft_fn = self._draft_prefill = None
         self._spec = None
         if self._self_draft:
@@ -1619,6 +1674,12 @@ class DecodeEngine:
                 pool, out = self._decode(
                     params, self._pool, ptab, tokens, pos, idle, temps,
                     seeds)
+                # and as a step ONE AHEAD of its fetch takes it: the
+                # tokens a device array, merged from the step before
+                pool, out = self._decode(
+                    params, pool, ptab,
+                    self._tokens_ahead(out, tokens, ~idle), pos, idle,
+                    temps, seeds)
                 self._pool = pool
                 jax.block_until_ready(out)
             # warmup scribbled on the shared pools; re-init lazily so
@@ -1817,6 +1878,7 @@ class DecodeEngine:
         b.seeds[slot] = np.uint32(seed)
         b.owners[slot] = owner
         b.rung[slot] = bucket
+        b.epoch[slot] += 1
         return slot, first_tok
 
     def _prompt_dispatches(self, prompt: np.ndarray, first_page: int,
@@ -1861,12 +1923,38 @@ class DecodeEngine:
                                       self._table_widths(w))}}
 
     def advance(self) -> np.ndarray:
-        """ONE decode dispatch for the engine: every active slot, of
-        whatever rung, emits its next token, at the narrowest table
-        width that covers the longest running one (:meth:`_stage`).
-        Returns the [S] token array (entries for inactive slots are
-        stale and must be ignored via the caller's ownership map;
-        stalled ones via :meth:`last_ran`)."""
+        """ONE decode step for the engine, dispatched and collected back
+        to back: every active slot, of whatever rung, emits its next
+        token, at the narrowest table width that covers the longest
+        running one (:meth:`_stage`).  It is
+        ``collect(dispatch_step())``, the two halves a
+        ``ContinuousBatcher`` orders ONE STEP APART.  Returns the [S]
+        token array (entries for inactive slots are stale and must be
+        ignored via the caller's ownership map; stalled ones via
+        :meth:`last_ran`)."""
+        return self.collect(self.dispatch_step())
+
+    def dispatch_step(self, after: Optional[_Step] = None) -> _Step:
+        """The first half of a plain decode step: stage and dispatch,
+        fetch nothing.  Nothing the host needs for the NEXT dispatch
+        depends on a token's value: ``pos_h`` moves by one for every
+        slot that ran, here, and pages and table width follow from it.
+        So a caller may dispatch the next step before it collects this
+        one, handing this one as ``after``: the next step then takes its
+        ``tokens`` from this step's output ON THE DEVICE for every slot
+        this step ran and that has not been released or restarted
+        since, and from ``tokens_h`` for the rest, merged by one tiny
+        program of its own (``jit_tokens_ahead_fn``; the step program,
+        its arguments and its text are what they were).  ONE step ahead,
+        no more: ``tokens_h`` must hold the tokens of every step before
+        ``after``, so dispatching behind a step whose own ``after`` is
+        still uncollected raises.  A failure of the device may now
+        surface in :meth:`collect`."""
+        if (after is not None and after.after is not None  # jaxlint: disable=host-sync-in-hot-path — a _Step is a host record: the test reads no device value
+                and not after.after.collected):
+            raise RuntimeError(
+                "a decode step runs ONE step ahead of its fetch: collect "
+                "the step before last first")
         b = self._slots
         with telemetry.span("decode.advance",
                             counter=(decode_metrics, "advance_s"),
@@ -1878,34 +1966,63 @@ class DecodeEngine:
                 pool = self._pool_state()
             with telemetry.span("decode.dispatch"):
                 try:
+                    if after is not None:  # jaxlint: disable=host-sync-in-hot-path — a host record, as above
+                        tokens = self._tokens_ahead(
+                            after.out, tokens,
+                            ~(after.run & (after.epoch == b.epoch)))
                     pool, out = self._decode(params, pool, ptab, tokens,
                                              pos, run, b.temps, b.seeds)
+                    # asked for now, so the copy is not queued behind
+                    # the step a caller dispatches next
+                    out.copy_to_host_async()
                 except Exception:
                     self._drop_pool()           # donated into the failure
                     raise
                 self._pool = pool
-            toks = self._fetch(out)
+            b.pos_h[run] += 1
+            return _Step(out, run, b.epoch.copy(), pos, w, rungs,
+                         (self._alloc.in_use(), self._live_rows()), after)
+
+    def collect(self, step: _Step) -> np.ndarray:
+        """The second half of a plain decode step: fetch its [S] tokens
+        and book it.  The tokens land in ``tokens_h`` for the slots that
+        still hold the sequence the step ran (a slot released or
+        restarted since keeps what its new occupant wrote)."""
+        b = self._slots
+        run = step.run
+        with telemetry.span("decode.advance",
+                            counter=(decode_metrics, "advance_s")):
+            try:
+                toks = self._fetch(step.out)
+            except Exception:
+                self._drop_pool()       # the step failed on the device
+                raise
+            # the step it ran behind is long collected: let it go
+            ahead, step.after, step.collected = (step.after is not None,
+                                                 None, True)
             if self._decode_counters:
                 # the family's counts came back behind the S tokens, in
                 # the one fetch a step makes
                 toks, counts = toks[:self.n_slots], toks[self.n_slots:]
                 decode_metrics.note_family_counts(self._decode_counters,
                                                   counts)
-            b.tokens_h[run] = toks[run]
-            b.pos_h[run] += 1
+            keep = run & (step.epoch == b.epoch)
+            b.tokens_h[keep] = toks[keep]
             decode_metrics.note_decode_dispatch(
-                int(run.sum()), self.n_slots, rungs, self.n_slots * w)
-            decode_metrics.note_pages(self._alloc.in_use(),
-                                      self._live_rows(), self.page_tokens)
+                int(run.sum()), self.n_slots, step.rungs,
+                self.n_slots * step.w, ahead=ahead)
+            decode_metrics.note_pages(*step.pages, self.page_tokens)
             if self._kind_names:
-                self._note_kinds(pos[run], held_at=pos[run])
+                self._note_kinds(step.pos[run], held_at=step.pos[run])
             return toks
 
     @staticmethod
     def _fetch(out: Any) -> np.ndarray:
         """The per-step stream sync: each active request's next token
-        must land on host to stream — this ONE [S]-int fetch per
-        dispatch is the product, not a stall."""
+        must land on host to stream.  This ONE [S]-int fetch per
+        dispatch is the product; since a plain step runs one ahead it is
+        no longer a stall of the device either: the next step is already
+        dispatched when a batcher waits here."""
         with telemetry.span("decode.fetch",
                             counter=(decode_metrics, "fetch_s")):
             return np.asarray(out)  # jaxlint: disable=host-sync-on-serving-worker — the per-step token fetch IS the stream
@@ -2036,6 +2153,7 @@ class DecodeEngine:
         b.active[slot] = False
         b.owners[slot] = None
         b.rung[slot] = 0
+        b.epoch[slot] += 1
         self._release_pages(slot)
 
 
@@ -2239,7 +2357,24 @@ class ContinuousBatcher:
     decode steps), advances every occupied slot one token per
     iteration in ONE dispatch, recycles slots on EOS/budget, and
     resolves ``DecodeRequest`` handles.  ``close()`` drains: accepted requests
-    run to completion, then the worker exits."""
+    run to completion, then the worker exits.
+
+    ONE STEP AHEAD.  A plain decode step is dispatched BEFORE the step
+    before it is collected (``DecodeEngine.dispatch_step(after=)``): a
+    pass dispatches step n+1, then fetches step n and delivers its
+    tokens while n+1 runs, and the next pass's expire and admit run
+    under it too.  At most one step is ever uncollected.  A step's
+    tokens go to the requests that held its slots WHEN IT WAS
+    DISPATCHED, and a token whose request has ended meanwhile is
+    dropped.  An end by count is known at dispatch, so the slot is
+    released there and its successor is admitted while the last token
+    is in flight (the device runs its programs in order, so the
+    successor's prefill writes the released pages after the step that
+    still reads them); an end by ``eos_id`` is known one step late, and
+    that slot runs ONE step past it, whose token is dropped and whose
+    row, at the slot's own next position, is never attended.  A
+    speculative round advances a slot by a count that IS data, so it
+    stays in series with its fetch."""
 
     #: a request is requeued at most this many times after failed
     #: dispatches before its error resolves the future — an injected
@@ -2261,6 +2396,13 @@ class ContinuousBatcher:
         self._admitting: List[DecodeRequest] = []
         #: slot -> the request it holds
         self._placed: Dict[int, DecodeRequest] = {}
+        #: requests whose LAST token is in flight: their end by count
+        #: was known when that step was dispatched, so their slot is
+        #: already released (and may hold a successor)
+        self._landing: List[DecodeRequest] = []
+        #: the step dispatched and not yet collected, with the slot ->
+        #: request map it was dispatched for (the worker's own)
+        self._flying: Optional[Tuple[_Step, Dict[int, DecodeRequest]]] = None
         self._open = True
         #: health surface the router's monitor polls (plain reads of
         #: worker-written fields — a torn read costs one poll):
@@ -2336,7 +2478,7 @@ class ContinuousBatcher:
         racing submit slip past the shed bound."""
         with self._cv:
             return (len(self._pending) + len(self._admitting)
-                    + len(self._placed))
+                    + len(self._placed) + len(self._landing))
 
     # -- health surface (router monitor) -----------------------------------
     def worker_alive(self) -> bool:
@@ -2363,10 +2505,11 @@ class ContinuousBatcher:
         with self._cv:
             self._open = False
             reqs = (list(self._pending) + list(self._admitting)
-                    + list(self._placed.values()))
+                    + list(self._placed.values()) + list(self._landing))
             self._pending.clear()
             self._admitting.clear()
             self._placed.clear()
+            self._landing.clear()
             self._cv.notify_all()
         out = []
         for r in reqs:
@@ -2441,9 +2584,15 @@ class ContinuousBatcher:
                       n_out: int) -> bool:
         if (req.eos_id is not None and tok == req.eos_id) \
                 or n_out >= req.max_tokens:
-            self.engine.release(slot)
             with self._cv:
-                self._placed.pop(slot, None)
+                holds = self._placed.get(slot) is req
+            if holds:       # else released when its last step went out
+                self.engine.release(slot)
+            with self._cv:
+                if holds:
+                    self._placed.pop(slot, None)
+                elif req in self._landing:
+                    self._landing.remove(req)
             decode_metrics.note_complete(n_out)
             req._finish()
             telemetry.event("decode.complete", rid=req.rid, slot=slot,
@@ -2452,56 +2601,52 @@ class ContinuousBatcher:
             return True
         return False
 
-    def _advance_all(self) -> int:
-        """ONE dispatch for every running slot, whatever their rungs,
-        and its tokens delivered; returns how many dispatches ran (0 or
-        1)."""
-        if not self.engine.n_active():
-            return 0
-        spec = self.engine.draft is not None and self.engine.spec_enabled
-        try:
-            if spec:
-                out, n_c = self.engine.advance_spec()
-            else:
-                toks = self.engine.advance()
-        except KVPagesExhausted as e:
-            # page deadlock breaker: the pool cannot advance ANY slot —
-            # evict the named victim (typed error to its client; its
-            # pages free the others)
-            if e.slot is None:
-                raise
+    def _dispatch_ahead(self, flying) -> None:
+        """Dispatch the next plain step for every running slot, behind
+        the step still in flight (``flying``, which the caller lands
+        afterwards).  A slot whose request this step completes BY COUNT
+        is released here, its request left to land its last token, so
+        the slot is never run again for it and the next pass may admit
+        a successor under the step."""
+        step = self.engine.dispatch_step(after=flying and flying[0])
+        with self._cv:
+            owners = {slot: r for slot, r in self._placed.items()
+                      if step.run[slot]}
+            self._flying = (step, owners)
+            # delivered, + this step's, + the one still in flight
+            landing = flying[1] if flying else {}
+            done = [(slot, r) for slot, r in owners.items()
+                    if len(r._tokens) + 1 + (landing.get(slot) is r)
+                    >= r.max_tokens]
+            for slot, r in done:
+                del self._placed[slot]
+                self._landing.append(r)
+        for slot, _ in done:
+            self.engine.release(slot)
+
+    def _land(self, step: _Step, owners: Dict[int, DecodeRequest]) -> None:
+        """Collect a dispatched step and deliver its tokens to the
+        requests its slots held when it went out."""
+        toks = self.engine.collect(step)
+        self.dispatch_error_streak = 0
+        with telemetry.span("decode.deliver"):
             with self._cv:
-                r = self._placed.pop(e.slot, None)
-            self.engine.release(e.slot)
-            if r is not None:
-                r._finish(e)
-            return 0
-        except Exception as e:
-            # a failed dispatch poisons in-flight device state (it
-            # was donated): the failure drops the pool, so EVERY slot's
-            # KV is gone.  Free every slot (the page reclaim is
-            # host-side bookkeeping and stays valid) and REPLAY the
-            # requests instead of dooming them: re-admitted as (prompt +
-            # emitted), each continues bit-identically.  Past the
-            # replay budget the error resolves the future — a
-            # deterministic dispatch bug must not requeue forever.
-            self.dispatch_error_streak += 1
-            with self._cv:
-                affected = list(self._placed.items())
-                self._placed.clear()
-            replay = []
-            for slot, r in affected:
-                self.engine.release(slot)
-                if r._replays >= self.MAX_REPLAYS:
-                    r._finish(e)
-                else:
-                    r._replays += 1
-                    replay.append(r)
-                    decode_metrics.note_request_replayed()
-            if replay:
-                with self._cv:
-                    self._pending[:0] = replay
-            return 0
+                self._last_progress = time.perf_counter()
+            dropped = 0
+            for slot, r in owners.items():
+                if r.done():    # ended (eos, deadline, eviction) since
+                    dropped += 1
+                    continue
+                tok = int(toks[slot])
+                r._push(tok)
+                self._maybe_finish(slot, r, tok, n_out=len(r._tokens))
+            if dropped:
+                decode_metrics.note_overshoot(dropped)
+
+    def _spec_round(self) -> None:
+        """One speculative round: dispatched, fetched and delivered in
+        series (its commit counts are data the next dispatch needs)."""
+        out, n_c = self.engine.advance_spec()
         self.dispatch_error_streak = 0
         with telemetry.span("decode.deliver"):
             ran = self.engine.last_ran()
@@ -2511,18 +2656,88 @@ class ContinuousBatcher:
             for slot, r in owned:
                 if not ran[slot]:
                     continue        # stalled on pages; retried next pass
-                if spec:
-                    for j in range(int(n_c[slot])):
-                        tok = int(out[slot, j])
-                        r._push(tok)
-                        if self._maybe_finish(slot, r, tok,
-                                              n_out=len(r._tokens)):
-                            break
-                else:
-                    tok = int(toks[slot])
+                for j in range(int(n_c[slot])):
+                    tok = int(out[slot, j])
                     r._push(tok)
-                    self._maybe_finish(slot, r, tok, n_out=len(r._tokens))
-        return 1
+                    if self._maybe_finish(slot, r, tok,
+                                          n_out=len(r._tokens)):
+                        break
+
+    def _replay_all(self, e: Exception) -> None:
+        """A failed dispatch poisons in-flight device state (it was
+        donated): the failure drops the pool, so EVERY slot's KV is
+        gone.  Free every slot (the page reclaim is host-side
+        bookkeeping and stays valid) and REPLAY the requests instead of
+        dooming them: re-admitted as (prompt + emitted), each continues
+        bit-identically; the tokens of a step that never landed were
+        never delivered, so the replay makes them again.  Past the
+        replay budget the error resolves the future — a deterministic
+        dispatch bug must not requeue forever."""
+        self.dispatch_error_streak += 1
+        with self._cv:
+            placed = list(self._placed.items())
+            lost = [r for _, r in placed] + self._landing
+            self._placed.clear()
+            self._landing = []
+            self._flying = None
+        for slot, _ in placed:
+            self.engine.release(slot)
+        replay = []
+        for r in lost:
+            if r.done():
+                continue
+            if r._replays >= self.MAX_REPLAYS:
+                r._finish(e)
+            else:
+                r._replays += 1
+                replay.append(r)
+                decode_metrics.note_request_replayed()
+        if replay:
+            with self._cv:
+                self._pending[:0] = replay
+
+    def _advance_all(self) -> Tuple[int, int]:
+        """ONE dispatch for every running slot, whatever their rungs,
+        and the tokens of the step before it delivered while it runs;
+        returns (dispatches made, steps landed), each 0 or 1."""
+        eng = self.engine
+        flying, self._flying = self._flying, None
+        spec = eng.draft is not None and eng.spec_enabled
+        dispatched = 0
+        try:
+            if spec and flying is not None:
+                # a round's commit count is data: in series, and behind
+                # whatever plain step a brownout left in flight
+                self._land(*flying)
+            with self._cv:
+                # what THIS batcher placed: an evacuated one's slots stay
+                # active in an engine that is discarded with it
+                running = bool(self._placed)
+            if running:
+                try:
+                    if spec:
+                        self._spec_round()
+                    else:
+                        self._dispatch_ahead(flying)
+                    dispatched = 1
+                except KVPagesExhausted as e:
+                    # page deadlock breaker: the pool cannot advance ANY
+                    # slot — evict the named victim (typed error to its
+                    # client; its pages free the others).  Raised while
+                    # staging: nothing was dispatched
+                    if e.slot is None:
+                        raise
+                    with self._cv:
+                        r = self._placed.pop(e.slot, None)
+                    eng.release(e.slot)
+                    if r is not None:
+                        r._finish(e)
+            if flying is not None and not spec:
+                self._land(*flying)
+        except Exception as e:
+            self._replay_all(e)
+            return 0, 0
+        return dispatched, int(flying is not None)
 
     def _expire(self) -> None:
         """Free every deadline-expired request (worker thread): queued
@@ -2554,11 +2769,11 @@ class ContinuousBatcher:
         while True:
             with self._cv:
                 while self._open and not self._pending \
-                        and not self._placed:
+                        and not self._placed and self._flying is None:
                     with telemetry.span("decode.wait"):
                         self._cv.wait()
                 if not self._open and not self._pending \
-                        and not self._placed:
+                        and not self._placed and self._flying is None:
                     return
             with telemetry.span("decode.round",
                                 counter=(decode_metrics, "round_s")) as sp:
@@ -2566,14 +2781,14 @@ class ContinuousBatcher:
                     self._expire()
                 with telemetry.span("decode.admit"):
                     admitted = self._admit()
-                advanced = self._advance_all()
+                advanced, landed = self._advance_all()
                 if admitted or advanced:
                     decode_metrics.note_round()
-                else:
+                elif not landed:
                     sp.discard()        # a pass that found nothing to do
             with self._cv:
                 if self._open and not admitted and not self._placed \
-                        and self._pending:
+                        and self._pending and self._flying is None:
                     # capacity-stalled: nothing is placed to advance
                     # and nothing pending fits — a timed wait instead
                     # of a hot spin (submit/close notifies early; the

@@ -606,6 +606,310 @@ def test_speculative_rounds_share_the_one_table(params):
     assert decode_metrics.snapshot()["draft_proposed"] > 0
 
 
+# -- one step ahead of the fetch ---------------------------------------------
+# A batcher dispatches step n+1 from step n's tokens ON THE DEVICE, then
+# fetches and delivers step n while n+1 runs.  The streams are the ones
+# ``advance()`` gives token by token, collected at once (the same two
+# halves back to back), for every family that serves.
+
+AHEAD_LADDER = (32, 64)
+
+
+class _Ahead(NamedTuple):
+    name: str
+    cfg: Any
+    params: Any
+    engine: DecodeEngine
+    make: Callable            # (**engine keywords) -> an engine like it
+
+
+def _toy_model(name):
+    from deeplearning4j_tpu.models import exaone_moe as ex, mellum as ml
+
+    if name == "gpt":
+        return CFG, gpt.init_params(jax.random.key(7), CFG)
+    mod = {"deepseek_v2": ds, "mellum": ml, "exaone_moe": ex}[name]
+    cfg = mod.tiny_config(compute_dtype="float32",
+                          **({"max_len": 64} if name == "deepseek_v2"
+                             else {}))
+    return cfg, mod.init_params(jax.random.key(0), cfg, std=0.3)
+
+
+@pytest.fixture(scope="module",
+                params=["gpt", "deepseek_v2", "mellum", "exaone_moe"])
+def ahead(request):
+    cfg, params = _toy_model(request.param)
+
+    def make(**kw):
+        eng = DecodeEngine(cfg, params, n_slots=3, buckets=AHEAD_LADDER,
+                           prefill_chunk=8,
+                           label=f"ahead-{request.param}", **kw)
+        eng.warmup()
+        return eng
+    eng = make()
+    yield _Ahead(request.param, cfg, params, eng, make)
+    assert _every_page_is_back(eng)
+
+
+def _sync_stream(eng, prompt, n, temperature=0.0, seed=0):
+    """The reference: ``n`` tokens of one request alone, every step
+    collected before the next is dispatched."""
+    slot, first = eng.start(prompt, max_tokens=n, temperature=temperature,
+                            seed=seed)
+    toks = [first] + [int(eng.advance()[slot]) for _ in range(n - 1)]
+    eng.release(slot)
+    return toks
+
+
+_AHEAD_KEYS = ("decode_dispatches", "decode_dispatches_ahead",
+               "decode_overshoot_steps", "requests_replayed",
+               "deadline_expirations", "rounds")
+
+
+def _ahead_counts():
+    snap = decode_metrics.snapshot()
+    return {**{k: snap[k] for k in _AHEAD_KEYS},
+            "slot_steps": decode_metrics.slot_steps}
+
+
+def _ahead_delta(before):
+    return {k: v - before[k] for k, v in _ahead_counts().items()}
+
+
+_backend_compiles = []
+
+
+def _xla_compiles():
+    """Lowerings handed to the backend so far (a recompile without a
+    retrace shows only here; the listener is registered once)."""
+    if not _backend_compiles:
+        import jax.monitoring as mon
+
+        _backend_compiles.append(0)
+
+        def on_duration(event, secs, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                _backend_compiles[0] += 1
+        mon.register_event_duration_secs_listener(on_duration)
+    return _backend_compiles[0]
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8],
+                         ids=["greedy", "sampled"])
+def test_streams_one_step_ahead_are_the_synchronous_ones(ahead, temperature):
+    """Mixed lengths across both rungs, more requests than slots, budgets
+    of one and two tokens among them: every stream is, token for token,
+    what ``advance()`` gives one step at a time; no slot runs a step past
+    its budget; nothing traces or compiles."""
+    eng = ahead.engine
+    lengths = (5, 20, 9, 30, 12, 3, 17, 26)
+    budgets = (10, 30, 4, 25, 2, 1, 12, 7)
+    prompts = [_prompt(ahead, n, 40 + i)
+               for i, n in enumerate(lengths)]
+    assert {eng.pick_bucket(n + m) for n, m in zip(lengths, budgets)} \
+        == set(AHEAD_LADDER)
+    decode_metrics.mark_compiles()
+    xla, before = _xla_compiles(), _ahead_counts()
+    with ContinuousBatcher(eng) as cb:
+        handles = [cb.submit(p, max_tokens=m, temperature=temperature,
+                             seed=100 + i)
+                   for i, (p, m) in enumerate(zip(prompts, budgets))]
+        got = [h.result(120).tolist() for h in handles]
+    d = _ahead_delta(before)
+    assert decode_metrics.snapshot()["compile_delta_since_mark"] == 0
+    assert _xla_compiles() == xla
+    for i, (p, m) in enumerate(zip(prompts, budgets)):
+        assert got[i] == _sync_stream(eng, p, m, temperature, 100 + i), i
+    # the first token is the prefill's: a request of m tokens is m - 1
+    # slot steps, and not one more for a slot whose budget has ended
+    assert d["slot_steps"] == sum(m - 1 for m in budgets)
+    assert d["decode_overshoot_steps"] == 0
+    assert 0 < d["decode_dispatches_ahead"] < d["decode_dispatches"]
+    assert eng.pages_unaccounted() == 0 and _every_page_is_back(eng)
+
+
+def test_a_budget_that_ends_frees_the_slot_for_its_successor(ahead):
+    """Nine requests of five tokens queued on three slots: a slot is
+    released when its last step is DISPATCHED, so its successor joins
+    while that token is in flight and no slot sits out a round: three
+    waves of four steps are twelve dispatches, every one full.  The
+    successor's first token and stream are the reference's."""
+    eng = ahead.engine
+    prompts = [_prompt(ahead, 6 + i, 60 + i) for i in range(9)]
+    before = _ahead_counts()
+    with ContinuousBatcher(eng) as cb:
+        with cb._cv:        # all nine queued before the worker's first pass
+            handles = [cb.submit(p, max_tokens=5) for p in prompts]
+        got = [h.result(120).tolist() for h in handles]
+    d = _ahead_delta(before)
+    want = [_sync_stream(eng, p, 5) for p in prompts]
+    assert [g[0] for g in got] == [w[0] for w in want]
+    assert got == want
+    assert d["decode_dispatches"] == 12 and d["slot_steps"] == 36
+    assert _every_page_is_back(eng)
+
+
+def test_eos_ends_the_stream_at_the_eos_token_one_step_late(ahead):
+    """An end by ``eos_id`` is known when the token lands, one step
+    after the next was dispatched: the stream ends AT the eos token, the
+    one step past it is counted and its token dropped, and its row costs
+    no page."""
+    eng = ahead.engine
+    prompt = _prompt(ahead, 11, 71)
+    # a sampled stream, so that some token past the second is its first
+    # of that value (the toy models' greedy streams repeat one token)
+    ref = _sync_stream(eng, prompt, 14, 1.0, 3)
+    k = next(k for k in range(2, 13) if ref[k] not in ref[:k])
+    before = _ahead_counts()
+    with ContinuousBatcher(eng) as cb:
+        got = cb.submit(prompt, max_tokens=14, temperature=1.0, seed=3,
+                        eos_id=ref[k]).result(120)
+    d = _ahead_delta(before)
+    assert got.tolist() == ref[:k + 1]
+    assert d["decode_overshoot_steps"] == 1
+    assert d["slot_steps"] == k + 1
+    assert eng.pages_unaccounted() == 0 and _every_page_is_back(eng)
+
+
+class _Gate:
+    """Holds the worker at the entry of its ``n``-th ``dispatch_step``
+    (the step before it dispatched and not collected) until the test
+    has done what it came to do."""
+
+    def __init__(self, eng, n):
+        self.eng, self.n, self.calls = eng, n, 0
+        self.reached, self.go = threading.Event(), threading.Event()
+        real = eng.dispatch_step
+
+        def gated(*a, **kw):
+            self.calls += 1
+            if self.calls == self.n:
+                self.reached.set()
+                assert self.go.wait(60)
+            return real(*a, **kw)
+        eng.dispatch_step = gated
+
+    def open(self):
+        self.go.set()
+
+    def remove(self):
+        self.go.set()
+        self.eng.__dict__.pop("dispatch_step", None)
+
+
+@pytest.mark.parametrize("what", ["deadline", "evacuate",
+                                  "dispatch_failure", "collect_failure"])
+def test_an_end_with_a_step_in_flight_loses_no_page_and_no_token(ahead,
+                                                                 what):
+    """With step 4 dispatched and not collected: a deadline frees the
+    slot and drops that step's token; an ``evacuate()`` hands every
+    request over, the last token in flight with it; a dispatch that
+    fails (at the call, or on the device, where it now shows at the
+    fetch) replays every request.  Whatever is delivered is the
+    reference's, token for token, and every page comes back."""
+    from deeplearning4j_tpu.parallel.chaos import ServingChaos
+    from deeplearning4j_tpu.serving.decode import (DeadlineExceeded,
+                                                   _ReplayRequest)
+
+    eng = ahead.engine
+    served = ahead.make() if what == "evacuate" else eng
+    pa, pb = _prompt(ahead, 9, 81), _prompt(ahead, 21, 82)
+    ref_a = _sync_stream(eng, pa, 16, 0.8, 5)
+    ref_b = _sync_stream(eng, pb, 12, 0.0, 6)
+    before = _ahead_counts()
+    gate = _Gate(served, 5)
+    try:
+        with ContinuousBatcher(served) as cb:
+            a = cb.submit(pa, max_tokens=16, temperature=0.8, seed=5)
+            b = cb.submit(pb, max_tokens=12, seed=6)
+            assert gate.reached.wait(60)
+            if what == "deadline":
+                a._deadline = time.perf_counter() - 1.0
+                a.deadline_ms = 1.0
+            elif what == "dispatch_failure":
+                ServingChaos(cb).poison_dispatch(1)
+            elif what == "collect_failure":
+                def failing_once(out):
+                    del served._fetch
+                    raise RuntimeError("injected: the step failed on "
+                                       "the device")
+                served._fetch = failing_once
+            else:
+                moved = cb.evacuate()
+                assert {r.rid for r in moved} == {a.rid, b.rid}
+            gate.open()
+            if what == "evacuate":
+                with ContinuousBatcher(eng) as adopter:
+                    for r in moved:
+                        adopter.resubmit(_ReplayRequest(r))
+                    got_a, got_b = a.result(120), b.result(120)
+            elif what == "deadline":
+                with pytest.raises(DeadlineExceeded):
+                    a.result(120)
+                got_a, got_b = a._snapshot_tokens(), b.result(120)
+            else:
+                got_a, got_b = a.result(120), b.result(120)
+    finally:
+        gate.remove()
+    d = _ahead_delta(before)
+    if what == "deadline":
+        assert 4 <= len(got_a) < 16 and d["deadline_expirations"] == 1
+        assert d["decode_overshoot_steps"] == 1     # the step in flight
+        assert got_a.tolist() == ref_a[:len(got_a)]
+    else:
+        assert got_a.tolist() == ref_a
+    assert got_b.tolist() == ref_b
+    if what.endswith("failure"):
+        assert d["requests_replayed"] == 2
+    assert eng.pages_unaccounted() == 0 and _every_page_is_back(eng)
+
+
+def test_a_steady_run_is_ahead_and_two_steps_ahead_raises(ahead):
+    """Three long requests in step: every dispatch but the first takes
+    its tokens from the step before it on the device.  The engine lets a
+    step run ONE ahead of its fetch, never two."""
+    eng = ahead.engine
+    prompts = [_prompt(ahead, 8, 90 + i) for i in range(3)]
+    before = _ahead_counts()
+    with ContinuousBatcher(eng) as cb:
+        for h in [cb.submit(p, max_tokens=40) for p in prompts]:
+            assert len(h.result(120)) == 40
+    d = _ahead_delta(before)
+    assert d["decode_dispatches_ahead"] > 0.9 * d["decode_dispatches"] > 0
+    assert d["decode_dispatches"] == d["rounds"]
+
+    slot, _ = eng.start(prompts[0], max_tokens=8)
+    first = eng.dispatch_step()
+    second = eng.dispatch_step(after=first)
+    with pytest.raises(RuntimeError, match="ONE step ahead"):
+        eng.dispatch_step(after=second)
+    one = int(eng.collect(first)[slot])
+    third = eng.dispatch_step(after=second)
+    two, three = int(eng.collect(second)[slot]), int(eng.collect(third)[slot])
+    eng.release(slot)
+    assert [one, two, three] == _sync_stream(eng, prompts[0], 4)[1:]
+
+
+def test_a_speculative_round_stays_in_series():
+    """``draft="self"``: a round's commit count is data the next
+    dispatch needs, so no dispatch is ever ahead of a fetch."""
+    cfg, params = _toy_model("exaone_moe")
+    eng = DecodeEngine(cfg, params, n_slots=2, buckets=(32,),
+                       prefill_chunk=8, draft="self",
+                       label="ahead-self-draft")
+    eng.warmup()
+    prompts = [np.arange(1, 8, dtype=np.int32),
+               np.arange(3, 14, dtype=np.int32)]
+    before = _ahead_counts()
+    with ContinuousBatcher(eng) as cb:
+        outs = [h.result(120) for h in
+                [cb.submit(p, max_tokens=12) for p in prompts]]
+    d = _ahead_delta(before)
+    assert [len(o) for o in outs] == [12, 12]
+    assert d["decode_dispatches"] > 0 and d["decode_dispatches_ahead"] == 0
+    assert _every_page_is_back(eng)
+
+
 # -- the shared engine, last ------------------------------------------------
 
 def test_shared_engine_has_every_page_back(params, engine):
