@@ -396,7 +396,30 @@ class DecodeMetrics:
       dispatch (``dispatch_step``) and, one step later under a batcher,
       its collection (``collect``); ``fetch_s`` (``decode.fetch``): the
       part of the second spent waiting for the step's tokens, which is
-      what of the device's time the host's other work did not cover.
+      what of the device's time the host's other work did not cover;
+      ``stage_s`` (``decode.stage``) and ``dispatch_s``
+      (``decode.dispatch``): the two parts of a dispatch, the host's
+      tables and masks and the calls into the runtime, of a plain step
+      and of a speculative round alike;
+    - ``expire_s`` (``decode.expire``), ``admit_s`` (``decode.admit``:
+      the queue's scan, every join, the first token's delivery),
+      ``deliver_s`` (``decode.deliver``): the other children of a
+      round, so ``round_s`` less ``expire_s + admit_s + advance_s +
+      deliver_s`` is the time of a round that no named span holds (a
+      pass that found nothing to do books none of them);
+    - ``prefix_s`` (``decode.prefix.lookup`` and
+      ``decode.prefix.register``): what prefix reuse costs the joins of
+      a family that mounts prefixes, whether or not anything hit;
+      ``pages_held_resident``: gauge, the pages in use that the
+      resident-prefix registry alone holds (no slot's table has them,
+      and ``can_admit`` cannot hand them out until the registry lets
+      go), set at every join, release and ``drop_residents``;
+    - ``slot_turnovers`` / ``slot_vacant_s`` / ``slot_vacant_queued_s``
+      (``decode.slot_vacant``): placements on a slot that was released
+      before, the time from that release to the placement, and the part
+      of it in which the request placed was already submitted (the
+      worker had not got to it), the rest being time in which no
+      request existed for the slot.
 
     The tree a ``DecodeEngine`` holds for its executables
     (``serving.decode.hold_in_compute_dtype``, span
@@ -437,7 +460,9 @@ class DecodeMetrics:
     MAX_SAMPLES = 8192
     #: the cumulative-seconds counters a span may name
     SECONDS = ("round_s", "queue_wait_s", "prefill_s", "prefill_sync_s",
-               "advance_s", "fetch_s")
+               "advance_s", "fetch_s", "expire_s", "admit_s", "stage_s",
+               "dispatch_s", "deliver_s", "prefix_s", "slot_vacant_s",
+               "slot_vacant_queued_s")
     #: counts a model family's decode step returns behind its tokens
     #: (``note_family_counts``)
     FAMILY_COUNTS = ("moe_assignments", "moe_assignments_held",
@@ -495,6 +520,8 @@ class DecodeMetrics:
             self.pages_leaked = 0
             self.rounds = 0
             self.admissions = 0
+            self.slot_turnovers = 0
+            self.pages_held_resident = 0
             self.params_held_casts = 0
             self.params_held_bytes = 0
             for key in self.SECONDS:
@@ -552,6 +579,16 @@ class DecodeMetrics:
         with self._lock:
             self.admissions += 1
             self.queue_wait_s += queue_wait_s
+
+    def note_slot_turnover(self, vacant_s: float, queued_s: float) -> None:
+        with self._lock:
+            self.slot_turnovers += 1
+            self.slot_vacant_s += vacant_s
+            self.slot_vacant_queued_s += queued_s
+
+    def note_pages_held_resident(self, n: int) -> None:
+        with self._lock:
+            self.pages_held_resident = int(n)
 
     def note_request(self, prompt_tokens: int) -> None:
         with self._lock:
@@ -725,6 +762,8 @@ class DecodeMetrics:
                 "ttft_p99_ms": ServingMetrics._pct(ttft, 0.99),
                 "rounds": self.rounds,
                 "admissions": self.admissions,
+                "slot_turnovers": self.slot_turnovers,
+                "pages_held_resident": self.pages_held_resident,
                 "params_held_casts": self.params_held_casts,
                 "params_held_bytes": self.params_held_bytes,
                 **{key: getattr(self, key) for key in self.SECONDS},

@@ -23,8 +23,8 @@ region, touches a tracer value, or forces a device sync):
   that cumulative-seconds counter on exit, so a span and the counter
   read from it cannot disagree and no interval is timed twice.
 - :class:`Tracer` — a run-scoped, thread-safe span recorder.  Spans nest
-  via a thread-local stack (context manager or :func:`traced` decorator),
-  carry per-span attributes, and land in a bounded ring buffer (oldest
+  via a thread-local stack, carry per-span attributes, and land in a
+  bounded ring buffer (oldest
   records drop first; ``dropped`` counts the loss so a truncated journal
   is self-announcing).  ``ts`` is monotonic seconds since tracer
   creation; ``t_unix_ns`` is the same instant on the clock the profiler
@@ -60,7 +60,7 @@ import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from jax.profiler import TraceAnnotation
 
@@ -96,10 +96,13 @@ class Span:
     context manager.  ``set(**attrs)`` adds attributes mid-flight (e.g.
     byte counts known only after the work ran); ``discard()`` says the
     interval turned out not to be what the name says (a loop pass that
-    found nothing to do): no record is kept and no counter moves."""
+    found nothing to do): no record is kept and no counter moves.  On
+    a span that is already over it takes back what the exit booked,
+    the counter's seconds and the record, so a parent that discards
+    itself can take the children that ended inside it along."""
 
-    __slots__ = ("_tracer", "_ann", "_kept", "name", "counter", "sid",
-                 "parent", "tid", "t0", "dur_s", "attrs")
+    __slots__ = ("_tracer", "_ann", "_kept", "_over", "name", "counter",
+                 "sid", "parent", "tid", "t0", "dur_s", "attrs")
 
     def __init__(self, tracer: "Optional[Tracer]", name: str,
                  counter: Optional[Tuple[Any, str]],
@@ -107,6 +110,7 @@ class Span:
         self._tracer = tracer
         self._ann = None
         self._kept = True
+        self._over = False
         self.name = name
         self.counter = counter
         self.sid = self.parent = None
@@ -120,6 +124,12 @@ class Span:
         return self
 
     def discard(self) -> None:
+        if self._kept and self._over:
+            if self.counter is not None:
+                source, key = self.counter
+                source.add_seconds(key, -self.dur_s)
+            if self._tracer is not None:
+                self._tracer._drop(self)
         self._kept = False
 
     def __enter__(self) -> "Span":
@@ -142,28 +152,8 @@ class Span:
             if exc_type is not None:
                 self.attrs.setdefault("error", exc_type.__name__)
             self._tracer._pop(self)
+        self._over = True
         return False
-
-
-class _NoopSpan:
-    """What a ``tr.span(...) if tr is not None else NOOP_SPAN`` site
-    takes while the tracer is off: nothing at all, the profiler
-    annotation included.  :func:`span` has no use for it."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NoopSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-    def set(self, **attrs: Any) -> "_NoopSpan":
-        return self
-
-
-#: the one no-op span every disabled call site shares
-NOOP_SPAN = _NoopSpan()
 
 
 class Tracer:
@@ -227,20 +217,6 @@ class Tracer:
             "attrs": attrs,
         })
 
-    def traced(self, name: Optional[str] = None) -> Callable:
-        """Decorator form: ``@tracer.traced("load")`` wraps the call in a
-        span named after the function unless overridden."""
-        def deco(fn: Callable) -> Callable:
-            label = name or getattr(fn, "__name__", "span")
-
-            def wrapper(*args, **kwargs):
-                with self.span(label):
-                    return fn(*args, **kwargs)
-            wrapper.__name__ = getattr(fn, "__name__", label)
-            wrapper.__doc__ = fn.__doc__
-            return wrapper
-        return deco
-
     # -- internals ---------------------------------------------------------
     def _push(self, span: Span) -> None:
         stack = getattr(self._local, "stack", None)
@@ -258,6 +234,7 @@ class Tracer:
         elif stack and span in stack:       # mis-nested exit: heal
             stack.remove(span)
         if not span._kept:
+            self._drop(span)
             return
         self._append({
             "type": "span", "name": span.name, "sid": span.sid,
@@ -265,6 +242,28 @@ class Tracer:
             "dur_ms": span.dur_s * 1e3,
             "attrs": span.attrs,
         })
+
+    def _drop(self, span: Span) -> None:
+        """Take back what was recorded under a discarded span: its own
+        record, where it ended before it was discarded, and those of
+        the spans that ended inside it (events stay: they happened).  A
+        child's record lies before its parent's, so one pass from the
+        newest record back to the span's start finds the whole family."""
+        gone = {span.sid}
+        t_lo = span.t0 - self._t0 - 1e-6
+        with self._lock:
+            kept = []
+            while self._buf:
+                rec = self._buf[-1]
+                if rec["ts"] + rec.get("dur_ms", 0.0) / 1e3 < t_lo:
+                    break
+                self._buf.pop()
+                if rec["type"] == "span" and (rec["sid"] in gone
+                                              or rec["parent"] in gone):
+                    gone.add(rec["sid"])
+                else:
+                    kept.append(rec)
+            self._buf.extend(reversed(kept))
 
     def _append(self, rec: Dict[str, Any]) -> None:
         with self._lock:
@@ -311,26 +310,16 @@ class Tracer:
                                    default=str) + "\n")
         return path
 
-    def export_chrome_trace(self, path: str) -> str:
-        """Write a ``chrome://tracing``/Perfetto-compatible trace JSON
-        (the "JSON Array Format" with a ``traceEvents`` wrapper)."""
-        payload = chrome_trace(self.records(), run_id=self.run_id)
-        d = os.path.dirname(os.path.abspath(path))
-        os.makedirs(d, exist_ok=True)
-        with open(path, "w") as f:
-            # default=str: same attr-value leniency as export_journal —
-            # a numpy-scalar span attribute must not crash either exporter
-            json.dump(payload, f, default=str)
-        return path
-
 
 def chrome_trace(records: List[Dict[str, Any]],
                  run_id: str = "run") -> Dict[str, Any]:
     """Convert journal records (span/event dicts) to the chrome trace
     event format Perfetto loads: complete slices (``ph: "X"``, µs
     timestamps/durations) for spans, thread-scoped instants (``ph: "i"``)
-    for events, plus process/thread metadata.  Shared by the tracer's
-    exporter and the ``cli.py telemetry --export-trace`` conversion.
+    for events, plus process/thread metadata.  ``cli.py telemetry
+    --export-trace`` and ``tools/telemetry_gate.py`` write it out; a
+    caller that dumps live records passes ``default=str`` (an
+    attribute may be a numpy scalar).
 
     Multi-run journals (append-only export contract) map each run
     SEGMENT to its own Perfetto process: runs restart both sids and
@@ -442,21 +431,6 @@ def completed(name: str, t_start: float, t_end: float,
     t = _TRACER
     if t is not None:
         t.completed(name, t_start, t_end, **attrs)
-
-
-def traced(name: Optional[str] = None) -> Callable:
-    """Decorator: :func:`span` around the call — resolved PER CALL, so
-    functions decorated at import time honor a tracer enabled later."""
-    def deco(fn: Callable) -> Callable:
-        label = name or getattr(fn, "__name__", "span")
-
-        def wrapper(*args, **kwargs):
-            with span(label):
-                return fn(*args, **kwargs)
-        wrapper.__name__ = getattr(fn, "__name__", label)
-        wrapper.__doc__ = fn.__doc__
-        return wrapper
-    return deco
 
 
 # ---------------------------------------------------------------------------

@@ -222,23 +222,20 @@ class DynamicBatcher:
             # evidence the bench row reports
             serving_metrics.note_batch(len(batch))
             tr = telemetry.get_tracer()
+            cohort = {}
             if tr is not None:
                 # queue age of the cohort = how long its OLDEST request
                 # waited for the window to close (the coalescing latency
                 # the max_delay_ms knob trades throughput against).
                 # Computed ONLY under an active tracer: the disabled
                 # path must stay free of per-cohort bookkeeping.
-                rows = sum(r.rows for r in batch)
                 age_ms = (time.perf_counter()
                           - min(r.t_submit for r in batch)) * 1e3
-                tr.event("serving.cohort_formed", n_requests=len(batch),
-                         rows=rows, queue_age_ms=round(age_ms, 3))
-                cohort_sp = tr.span("serving.cohort",
-                                    n_requests=len(batch), rows=rows,
-                                    queue_age_ms=round(age_ms, 3))
-            else:
-                cohort_sp = telemetry.NOOP_SPAN
-            with cohort_sp:
+                cohort = {"n_requests": len(batch),
+                          "rows": sum(r.rows for r in batch),
+                          "queue_age_ms": round(age_ms, 3)}
+                tr.event("serving.cohort_formed", **cohort)
+            with telemetry.span("serving.cohort", **cohort):
                 try:
                     xs = np.concatenate([r.x for r in batch], axis=0) \
                         if len(batch) > 1 else batch[0].x

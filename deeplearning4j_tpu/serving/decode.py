@@ -621,7 +621,7 @@ class _SlotTable:
     rows may grow to)."""
 
     __slots__ = ("active", "temps", "seeds", "owners", "rung",
-                 "tokens_h", "pos_h", "ran", "epoch")
+                 "tokens_h", "pos_h", "ran", "epoch", "released_at")
 
     def __init__(self, n_slots: int):
         self.active = np.zeros((n_slots,), np.bool_)
@@ -645,6 +645,9 @@ class _SlotTable:
         # bumped when a slot is started or released: a step in flight
         # knows by it whether a slot still holds the sequence it ran
         self.epoch = np.zeros((n_slots,), np.int64)
+        # ``time.perf_counter()`` of a slot's last release (0: never
+        # released): where its vacancy starts
+        self.released_at = np.zeros((n_slots,), np.float64)
 
 
 class _Step:
@@ -1374,6 +1377,8 @@ class DecodeEngine:
         b.tokens_h[slot] = 0
         b.pos_h[slot] = 0
         decode_metrics.note_pages(self._alloc.in_use(), 0, 0)
+        if self._resident:      # its pages may be the registry's alone now
+            self._note_resident_held()
         if self._kind_names:
             self._note_kinds()
         decode_metrics.note_pages_leaked(self.pages_unaccounted())
@@ -1448,17 +1453,18 @@ class DecodeEngine:
                 return k * C, ent[1]
         return 0, None
 
-    def _resident_register(self, prompt: np.ndarray, slot: int) -> None:
+    def _resident_register(self, prompt: np.ndarray, slot: int) -> int:
         """Register the slot's chunk-aligned prompt prefix pages as
         pool-resident at every chunk boundary (so a partial prefix
         match still hits).  The registry holds its own reference on
         each page — the pages outlive the harvesting slot and return
         to the pool when the LRU bound (or a weight swap) evicts the
-        entry and the last sharer releases."""
+        entry and the last sharer releases.  Returns the entries the
+        bound evicted."""
         C = self.page_tokens
         m = C * ((prompt.size - 1) // C)
         if m < C or not self._mounts_prefixes:
-            return
+            return 0
         digs = PrefixCache._boundary_digests(prompt, C, m // C,
                                              self._prefix_space)
         ptab = self._kinds[0].ptab      # the one kind of a family that mounts
@@ -1469,10 +1475,24 @@ class DecodeEngine:
                 self._alloc.free(old[1])
             self._alloc.share(ids)
             self._resident[digs[k - 1]] = (prompt[:k * C].copy(), ids)
+        evicted = 0
         while (sum(len(v[1]) for v in self._resident.values())
                > self._resident_max and self._resident):
             _, (_, ids) = self._resident.popitem(last=False)
             self._alloc.free(ids)
+            evicted += 1
+        return evicted
+
+    def _note_resident_held(self) -> int:
+        """Set the gauge ``pages_held_resident``: the pages in use that
+        no slot's table has, which the registry alone keeps from
+        ``can_admit`` (a family that mounts has one kind of page).
+        Called wherever that number moves: a join, a release, a drop."""
+        k = self._kinds[0]
+        in_slots = k.ptab[np.arange(k.cap) < k.n_pages[:, None]]
+        held = self._alloc.in_use() - np.unique(in_slots).size
+        decode_metrics.note_pages_held_resident(held)
+        return held
 
     def drop_residents(self) -> None:
         """Evict every pool-resident prefix registration, releasing the
@@ -1486,6 +1506,7 @@ class DecodeEngine:
         while self._resident:
             _, (_, ids) = self._resident.popitem(last=False)
             self._alloc.free(ids)
+        decode_metrics.note_pages_held_resident(0)
         decode_metrics.note_pages_leaked(self.pages_unaccounted())
 
     # -- hot checkpoint swap -----------------------------------------------
@@ -1751,13 +1772,21 @@ class DecodeEngine:
         # the page table BY REFERENCE — no copy, no dispatch; (2) the
         # host PrefixCache (shared across replicas) copies pages into
         # freshly-allocated pool pages
-        hit_len, hit_ids = self._resident_lookup(prompt)
-        resident_hit = hit_len > 0
-        host_pages = None
-        if not resident_hit and self._prefix is not None:
-            hit = self._prefix.lookup(prompt, C, self._prefix_space)
-            if hit is not None:
-                hit_len, host_pages = hit
+        # (a family that mounts none and has no store opens neither of
+        # the two ``decode.prefix.*`` spans: they would always be empty)
+        reuses = self._mounts_prefixes or self._prefix is not None
+        hit_len, hit_ids, host_pages = 0, None, None
+        if reuses:
+            with telemetry.span("decode.prefix.lookup",
+                                counter=(decode_metrics, "prefix_s"),
+                                rid=rid, pages=(prompt.size - 1) // C) as sp:
+                hit_len, hit_ids = self._resident_lookup(prompt)
+                if not hit_len and self._prefix is not None:
+                    hit = self._prefix.lookup(prompt, C, self._prefix_space)
+                    if hit is not None:
+                        hit_len, host_pages = hit
+                sp.set(hit_tokens=hit_len)
+        resident_hit = hit_ids is not None
         h = hit_len // C
         # the join walks the prompt from page h (a prefix hit stays
         # page-aligned) in dispatches of ``rows`` rows, a whole number
@@ -1852,22 +1881,30 @@ class DecodeEngine:
         else:
             decode_metrics.note_prefix_miss()
         m_store = C * ((prompt.size - 1) // C)
-        if m_store > hit_len and m_store >= C and self.harvest_enabled:
+        if (reuses and m_store > hit_len and m_store >= C
+                and self.harvest_enabled):
             # harvest: register the prefix pages pool-resident (no
             # dispatch — the registry just refs the page ids) and, with
             # a host store attached, enqueue the cross-replica fetch
-            self._resident_register(prompt, slot)
-            if self._prefix is not None:
-                pids = np.zeros((tbl,), np.int32)
-                pids[:m_store // C] = self._kinds[0].ptab[slot,
-                                                          :m_store // C]
-                full = self._read(pool, pids)
-                self._ensure_harvester()
-                try:
-                    self._harvest_q.put_nowait(
-                        (full, prompt[:m_store].copy(), C))
-                except queue.Full:
-                    pass            # backpressure: drop, opportunistic
+            with telemetry.span("decode.prefix.register",
+                                counter=(decode_metrics, "prefix_s"),
+                                rid=rid, pages=m_store // C) as sp:
+                evicted = self._resident_register(prompt, slot)
+                if self._prefix is not None:
+                    pids = np.zeros((tbl,), np.int32)
+                    pids[:m_store // C] = self._kinds[0].ptab[
+                        slot, :m_store // C]
+                    full = self._read(pool, pids)
+                    self._ensure_harvester()
+                    try:
+                        self._harvest_q.put_nowait(
+                            (full, prompt[:m_store].copy(), C))
+                    except queue.Full:
+                        pass        # backpressure: drop, opportunistic
+                sp.set(entries=len(self._resident), evicted=evicted,
+                       pages_held=self._note_resident_held())
+        elif self._mounts_prefixes:
+            self._note_resident_held()
         decode_metrics.note_pages(self._alloc.in_use(), 0, 0)
         if self._kind_names:
             self._note_kinds(np.arange(h, n_chunks) * C)
@@ -1960,11 +1997,13 @@ class DecodeEngine:
                             counter=(decode_metrics, "advance_s"),
                             active=self.n_active()) as sp:
             params = self.current_params()
-            with telemetry.span("decode.stage"):
+            with telemetry.span("decode.stage",
+                                counter=(decode_metrics, "stage_s")):
                 ptab, tokens, pos, run, w, rungs = self._stage(0)
-                sp.set(**self._width_attrs(w, rungs))
+                sp.set(n_run=int(run.sum()), **self._width_attrs(w, rungs))
                 pool = self._pool_state()
-            with telemetry.span("decode.dispatch"):
+            with telemetry.span("decode.dispatch",
+                                counter=(decode_metrics, "dispatch_s")):
                 try:
                     if after is not None:  # jaxlint: disable=host-sync-in-hot-path — a host record, as above
                         tokens = self._tokens_ahead(
@@ -2050,15 +2089,18 @@ class DecodeEngine:
                             counter=(decode_metrics, "advance_s"),
                             active=self.n_active(), k=k) as sp:
             params = self.current_params()
-            with telemetry.span("decode.stage"):
+            with telemetry.span("decode.stage",
+                                counter=(decode_metrics, "stage_s")):
                 # the draft is handed the verified frontier: its rows
                 # below it hold exactly the committed tokens' KV
                 # (accepted proposals consumed them), so no re-sync
                 # dispatch is ever needed
                 ptab, tokens, pos, run, w, rungs = self._stage(k)
-                sp.set(width=w, rungs=rungs)
+                n_run = int(run.sum())
+                sp.set(n_run=n_run, width=w, rungs=rungs)
                 pool = self._pool_state()
-            with telemetry.span("decode.dispatch"):
+            with telemetry.span("decode.dispatch",
+                                counter=(decode_metrics, "dispatch_s")):
                 try:
                     self._dpool, props = self._draft_fn(
                         self._draft_params, self._dpool, ptab, tokens,
@@ -2077,7 +2119,6 @@ class DecodeEngine:
             idx = np.flatnonzero(n_c)
             b.tokens_h[idx] = toks[idx, n_c[idx] - 1]
             b.pos_h += n_c.astype(np.int32)
-            n_run = int(run.sum())
             decode_metrics.note_decode_dispatch(
                 n_run, self.n_slots, rungs, self.n_slots * w)
             decode_metrics.note_spec(k * n_run,
@@ -2103,11 +2144,14 @@ class DecodeEngine:
                             counter=(decode_metrics, "advance_s"),
                             active=self.n_active(), k=k) as sp:
             params = self.current_params()
-            with telemetry.span("decode.stage"):
+            with telemetry.span("decode.stage",
+                                counter=(decode_metrics, "stage_s")):
                 ptab, tokens, pos, run, w, rungs = self._stage(k)
-                sp.set(**self._width_attrs(w, rungs))
+                n_run = int(run.sum())
+                sp.set(n_run=n_run, **self._width_attrs(w, rungs))
                 pool = self._pool_state()
-            with telemetry.span("decode.dispatch"):
+            with telemetry.span("decode.dispatch",
+                                counter=(decode_metrics, "dispatch_s")):
                 try:
                     pool, out = self._spec(params, pool, ptab, tokens, pos,
                                            run, b.temps, b.seeds,
@@ -2127,7 +2171,6 @@ class DecodeEngine:
             b.tokens_h[idx] = toks[idx, n_c[idx] - 1]
             b.pos_h += n_c
             self._drafts_h[idx] = nxt[idx]
-            n_run = int(run.sum())
             decode_metrics.note_decode_dispatch(
                 n_run, S, rungs, S * w)
             decode_metrics.note_spec(k * n_run,
@@ -2154,7 +2197,13 @@ class DecodeEngine:
         b.owners[slot] = None
         b.rung[slot] = 0
         b.epoch[slot] += 1
+        b.released_at[slot] = time.perf_counter()
         self._release_pages(slot)
+
+    def vacant_since(self, slot: int) -> Optional[float]:
+        """When ``slot`` was last released (``time.perf_counter()``);
+        None for a slot that never was."""
+        return float(self._slots.released_at[slot]) or None
 
 
 class DecodeRequest:
@@ -2524,21 +2573,27 @@ class ContinuousBatcher:
         how many were admitted.  Runs on the worker thread only."""
         admitted = 0
         while True:
-            with self._cv:
-                req = None
-                for i, r in enumerate(self._pending):
-                    # a REPLAYED request re-prefills prompt + emitted
-                    # (len(r._tokens) is worker-written only — this IS
-                    # the worker); its rung is unchanged because
-                    # emitted tokens move from budget to prompt 1:1
-                    if self.engine.can_admit(
-                            r.prompt.size + len(r._tokens)):
-                        req = self._pending.pop(i)
-                        self._admitting.append(req)
-                        break
-                if req is None:
-                    decode_metrics.note_queue_depth(len(self._pending))
-                    return admitted
+            # the lock's wait is the pick's: the callers' threads submit
+            # and read the depth under it
+            with telemetry.span("decode.admit.pick") as pick:
+                with self._cv:
+                    pick.set(pending=len(self._pending))
+                    req = None
+                    for i, r in enumerate(self._pending):
+                        # a REPLAYED request re-prefills prompt + emitted
+                        # (len(r._tokens) is worker-written only — this
+                        # IS the worker); its rung is unchanged because
+                        # emitted tokens move from budget to prompt 1:1
+                        if self.engine.can_admit(
+                                r.prompt.size + len(r._tokens)):
+                            req = self._pending.pop(i)
+                            self._admitting.append(req)
+                            pick.set(rid=req.rid)
+                            break
+                    if req is None:
+                        decode_metrics.note_queue_depth(len(self._pending))
+            if req is None:
+                return admitted
             # the wait ends here, so it is counted here; a replayed
             # request's runs from its first submit, as its caller's does
             now = time.perf_counter()
@@ -2572,13 +2627,27 @@ class ContinuousBatcher:
                             prompt_tokens=int(eff_prompt.size),
                             mid_flight=joined, replayed=bool(emitted.size))
             admitted += 1
+            t_free = self.engine.vacant_since(slot)
+            if t_free is not None:
+                # the slot's vacancy ends where the request's wait did,
+                # before the join (that is ``prefill_s``'s); ``queued``
+                # is the part in which this request already existed
+                queued = max(0.0, now - max(t_free, req._t_submit))
+                decode_metrics.note_slot_turnover(max(0.0, now - t_free),
+                                                  queued)
+                telemetry.completed("decode.slot_vacant", t_free, now,
+                                    slot=slot, rid=req.rid,
+                                    queued_ms=queued * 1e3)
             with self._cv:
                 self._last_progress = time.perf_counter()
                 if req in self._admitting:   # evacuate() may have
                     self._admitting.remove(req)  # adopted it mid-start
                 self._placed[slot] = req
-            req._push(first)
-            self._maybe_finish(slot, req, first, n_out=len(req._tokens))
+            with telemetry.span("decode.first_token", rid=req.rid,
+                                slot=slot):
+                req._push(first)
+                self._maybe_finish(slot, req, first,
+                                   n_out=len(req._tokens))
 
     def _maybe_finish(self, slot: int, req: DecodeRequest, tok: int,
                       n_out: int) -> bool:
@@ -2629,7 +2698,8 @@ class ContinuousBatcher:
         requests its slots held when it went out."""
         toks = self.engine.collect(step)
         self.dispatch_error_streak = 0
-        with telemetry.span("decode.deliver"):
+        with telemetry.span("decode.deliver",
+                            counter=(decode_metrics, "deliver_s")):
             with self._cv:
                 self._last_progress = time.perf_counter()
             dropped = 0
@@ -2648,7 +2718,8 @@ class ContinuousBatcher:
         series (its commit counts are data the next dispatch needs)."""
         out, n_c = self.engine.advance_spec()
         self.dispatch_error_streak = 0
-        with telemetry.span("decode.deliver"):
+        with telemetry.span("decode.deliver",
+                            counter=(decode_metrics, "deliver_s")):
             ran = self.engine.last_ran()
             with self._cv:
                 self._last_progress = time.perf_counter()
@@ -2699,7 +2770,8 @@ class ContinuousBatcher:
     def _advance_all(self) -> Tuple[int, int]:
         """ONE dispatch for every running slot, whatever their rungs,
         and the tokens of the step before it delivered while it runs;
-        returns (dispatches made, steps landed), each 0 or 1."""
+        returns (dispatches made, whether the pass touched the engine
+        at all: a step landed, or a dispatch failed), each 0 or 1."""
         eng = self.engine
         flying, self._flying = self._flying, None
         spec = eng.draft is not None and eng.spec_enabled
@@ -2736,7 +2808,7 @@ class ContinuousBatcher:
                 self._land(*flying)
         except Exception as e:
             self._replay_all(e)
-            return 0, 0
+            return 0, 1     # its spans booked their seconds: no idle pass
         return dispatched, int(flying is not None)
 
     def _expire(self) -> None:
@@ -2777,15 +2849,23 @@ class ContinuousBatcher:
                     return
             with telemetry.span("decode.round",
                                 counter=(decode_metrics, "round_s")) as sp:
-                with telemetry.span("decode.expire"):
+                with telemetry.span(
+                        "decode.expire",
+                        counter=(decode_metrics, "expire_s")) as sp_e:
                     self._expire()
-                with telemetry.span("decode.admit"):
+                with telemetry.span(
+                        "decode.admit",
+                        counter=(decode_metrics, "admit_s")) as sp_a:
                     admitted = self._admit()
-                advanced, landed = self._advance_all()
+                advanced, touched = self._advance_all()
                 if admitted or advanced:
                     decode_metrics.note_round()
-                elif not landed:
-                    sp.discard()        # a pass that found nothing to do
+                elif not touched:
+                    # a pass that found nothing to do is no round, and
+                    # its children book nothing either, or their sum
+                    # could pass ``round_s``
+                    for idle in (sp_e, sp_a, sp):
+                        idle.discard()
             with self._cv:
                 if self._open and not admitted and not self._placed \
                         and self._pending and self._flying is None:

@@ -243,12 +243,7 @@ class InferenceEngine:
         n = x.shape[0]
         bucket = pick_bucket(n, self.buckets)
         serving_metrics.note_dispatch(bucket)
-        # per-request hot path: guard BEFORE building the attr kwargs —
-        # the conditional only evaluates tr.span(...) when tracing
-        tr = telemetry.get_tracer()
-        sp = tr.span("serving.dispatch", bucket=bucket, rows=n) \
-            if tr is not None else telemetry.NOOP_SPAN
-        with sp:
+        with telemetry.span("serving.dispatch", bucket=bucket, rows=n):
             out = self._call_forward(params, pad_rows(x, bucket))
         if bucket == n:
             return out
@@ -268,10 +263,7 @@ class InferenceEngine:
         n = x.shape[0]
         if count_request:
             serving_metrics.note_request(n)
-        tr = telemetry.get_tracer()
-        sp = tr.span("serving.infer", rows=n) if tr is not None \
-            else telemetry.NOOP_SPAN
-        with sp:
+        with telemetry.span("serving.infer", rows=n):
             p = self.current_params(params)
             cap = self.buckets[-1]
             if n <= cap:

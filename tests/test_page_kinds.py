@@ -205,14 +205,17 @@ def test_one_kind_families_run_as_the_parent_did(family, monkeypatch):
     # own counters stay at 0 for a family that declares none, and the
     # rows of the 9 one-page dispatches are counted (PR 33); a step that
     # is collected before the next is dispatched is never ahead of its
-    # fetch and never a step past a request's end (PR 36)
+    # fetch and never a step past a request's end (PR 36); no batcher
+    # places a request here and every registered page is still in a
+    # slot's table at the last join (PR 37)
     assert {k: got["snapshot"][k] for k in want["snapshot"]} \
         == want["snapshot"]
     new = set(got["snapshot"]) - set(want["snapshot"])
     rows = {"prefill_rows_dispatched", "prefill_rows_valid"}
     ahead = {"decode_dispatches_ahead", "decode_overshoot_steps"}
-    assert new == rows | ahead | set(decode_metrics.KIND_GAUGES
-                                     + decode_metrics.KIND_COUNTS)
+    vacancy = {"slot_turnovers", "pages_held_resident"}
+    assert new == rows | ahead | vacancy | set(
+        decode_metrics.KIND_GAUGES + decode_metrics.KIND_COUNTS)
     assert not any(got["snapshot"][k] for k in new - rows)
     assert (got["snapshot"]["prefill_rows_dispatched"],
             got["snapshot"]["prefill_rows_valid"]) == (9 * C, 5 + 21 + 40)

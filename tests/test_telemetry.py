@@ -125,27 +125,6 @@ def test_ring_buffer_bounds_and_counts_drops():
     assert t._header()["dropped"] == 15
 
 
-def test_decorator_form():
-    t = Tracer()
-
-    @t.traced("compute")
-    def add(a, b):
-        return a + b
-
-    assert add(2, 3) == 5
-    assert t.records()[0]["name"] == "compute"
-
-    # module-level decorator resolves the tracer PER CALL
-    @telemetry.traced()
-    def mul(a, b):
-        return a * b
-
-    assert mul(2, 3) == 6                 # disabled: no tracer, no record
-    tr = telemetry.enable()
-    assert mul(4, 5) == 20
-    assert tr.records()[0]["name"] == "mul"
-
-
 def test_disabled_module_api_records_nothing():
     """Off means: a span is still a span (it times itself, feeds its
     counter and is a profiler annotation), but no tracer exists, none is
@@ -289,7 +268,8 @@ def test_chrome_trace_is_valid_perfetto_json(tmp_path):
             pass
         t.event("mark", n=1)
     out = str(tmp_path / "trace.json")
-    t.export_chrome_trace(out)
+    with open(out, "w") as f:
+        json.dump(chrome_trace(t.records(), run_id=t.run_id), f)
     with open(out) as f:
         payload = json.load(f)            # valid JSON by construction
     events = payload["traceEvents"]
@@ -310,14 +290,16 @@ def test_chrome_trace_is_valid_perfetto_json(tmp_path):
 
 
 def test_chrome_trace_export_survives_numpy_attrs(tmp_path):
-    """Both exporters accept the same attr values: a numpy scalar span
-    attribute must not crash the Perfetto export (export_journal already
-    stringifies via default=str)."""
+    """Both exports accept the same attr values: a numpy scalar span
+    attribute must not crash the Perfetto export of live records
+    (``default=str``, as export_journal stringifies them)."""
     t = Tracer()
     with t.span("np.block", n=np.int32(3), f=np.float32(1.5)):
         pass
     jpath = t.export_journal(str(tmp_path / "np.jsonl"))
-    tpath = t.export_chrome_trace(str(tmp_path / "np_trace.json"))
+    tpath = str(tmp_path / "np_trace.json")
+    with open(tpath, "w") as f:
+        json.dump(chrome_trace(t.records()), f, default=str)
     with open(tpath) as f:
         payload = json.load(f)
     (sl,) = [e for e in payload["traceEvents"] if e.get("ph") == "X"]
@@ -659,8 +641,13 @@ def test_decode_loop_counts_its_phases_where_they_happen():
     for req in reqs:
         mine = [r["name"] for r in recs
                 if r["attrs"].get("rid") == req.rid]
-        assert sorted(mine) == ["decode.complete", "decode.join",
-                                "decode.prefill", "decode.queue_wait"]
+        # a join registers only pages no prefix hit covered, and a
+        # slot's first placement ends no vacancy
+        always = {"decode.complete", "decode.join", "decode.prefill",
+                  "decode.queue_wait", "decode.admit.pick",
+                  "decode.prefix.lookup", "decode.first_token"}
+        assert always <= set(mine) <= always | {
+            "decode.prefix.register", "decode.slot_vacant"}, mine
     assert len({r.rid for r in reqs}) == len(reqs)
 
 
