@@ -612,8 +612,9 @@ def paged_prefill(cfg: ExaoneMoeConfig, params: PyTree, pool: PagedGQA,
                   ptab_s: Tuple[Array, Array], toks: Array, start: Array,
                   n_valid: Array, temperature: Array, seed: Array
                   ) -> Tuple[PagedGQA, Array]:
-    """One prefill dispatch's rows ``toks`` [W] (ONE page: the engine
-    sends a family with a bounded kind of page no more) of the sequence
+    """One prefill dispatch's rows ``toks`` [W] (ONE page: this
+    family's ring of ``page_kinds`` leaves room for no more,
+    ``DecodeEngine.prefill_rows``) of the sequence
     whose page tables are ``ptab_s`` ([TBL] full, [R] window), at
     page-aligned ``start``.  The MTP block's cache is left alone: an
     engine without the draft never reads it.  Returns (pool', the token

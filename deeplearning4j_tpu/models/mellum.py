@@ -33,10 +33,11 @@ of a sequence for as long as it runs, a window layer never reads one
 more than ``sliding_window - 1`` positions back.  :func:`page_kinds`
 tells ``serving.decode.DecodeEngine`` so; it keeps an allocator and a
 page table a kind, and a slot's window table is a RING of
-``ceil((sliding_window - 1) / C) + 1`` columns (the page of positions
-``j C ..`` in column ``j % columns``), so a window layer holds and
-reads at most that many pages a slot however long the sequence, and
-both paged paths find a column's positions from the slot's own.
+``ceil((sliding_window - 1) / C) + RING_PREFILL_PAGES`` columns (the
+page of positions ``j C ..`` in column ``j % columns``), so a window
+layer holds and reads at most that many pages a slot however long the
+sequence, and both paged paths find a column's positions from the
+slot's own.
 
 The layer loop is a ``lax.scan`` over periods (one traced period
 whatever the depth); each layer's fresh rows are written in place at
@@ -86,6 +87,15 @@ UNSUPPORTED_ENGINE_OPTIONS = ("mesh", "kv_dtype", "quantize", "draft",
 
 #: weights arrive in the compute type: nothing to hold cast
 COMPUTE_DTYPE_LEAVES = ()
+
+#: pages of ONE prefill dispatch the window ring leaves room for
+#: (:func:`page_kinds`).  A dispatch reads every weight once whatever its
+#: rows (all 64 experts of every layer are hit from 128 rows on: 10 GB at
+#: the published widths), so a second page halves the reads a join
+#: makes; its price is one ring column, 2.36 MB a slot at those widths
+#: and pages of 128, which every decode step's window layers read too.
+#: No third: two pages of 128 are ``serving.decode.PREFILL_ROWS_MAX``.
+RING_PREFILL_PAGES = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -401,14 +411,21 @@ class PagedGQA(NamedTuple):
 
 
 def page_kinds(cfg: MellumConfig, page_tokens: int
-               ) -> Tuple[Tuple[str, Optional[int]], ...]:
+               ) -> Tuple[Tuple[Any, ...], ...]:
     """The kinds of page of this family's pool, in the order its paged
     functions take page counts and tables: ``full``, a rung's worth a
-    slot; ``window``, at most the pages ``sliding_window`` consecutive
-    positions can touch, ``ceil((sliding_window - 1) / C) + 1``
-    (``sliding_window / C + 1`` where C divides the window)."""
+    slot; ``window``, a ring of ``cap = ceil((sliding_window - 1) / C) +
+    RING_PREFILL_PAGES`` pages (the pages the frontier's row reads back
+    to, less its own, and those of one prefill dispatch), and behind the
+    bound the rows a dispatch may write AHEAD of a slot's committed
+    frontier into that ring (``DecodeEngine``'s ring rule, as
+    ``exaone_moe.page_kinds`` states it): ``(cap - 1) C -
+    (sliding_window - 1)``, from which the engine derives the pages of a
+    prefill dispatch, ``1 + ahead // C``."""
+    cap = -(-(cfg.sliding_window - 1) // page_tokens) + RING_PREFILL_PAGES
     return (("full", None),
-            ("window", -(-(cfg.sliding_window - 1) // page_tokens) + 1))
+            ("window", cap,
+             (cap - 1) * page_tokens - (cfg.sliding_window - 1)))
 
 
 def _slab_shapes(cfg: MellumConfig, n_pages: Tuple[int, int],
@@ -458,12 +475,20 @@ def _paged_stack(cfg: MellumConfig, params: PyTree, pool: PagedGQA,
                  row_ok: Array) -> Tuple[PagedGQA, Array, Array]:
     """The layer stack over the pool, W rows a sequence: row w of
     sequence s feeds ``toks_w[s, w]`` at position ``posw[s, w]`` (decode:
-    S slots, W = 1; a prefill dispatch: S = 1, W = C rows of ONE page,
-    which is what the ring below assumes of a dispatch's rows).
+    S slots, W = 1; a prefill dispatch: S = 1, W rows of whole pages
+    from a page-aligned start, as many as the ring leaves room for).
     ``ptabs``: the full kind's table [S, TBL] (column j the page of
     positions ``j C ..``) and the window kind's [S, R], a ring (that page
-    in column ``j % R``; what a column holds is read off the slot's
-    newest position: the newest page ``<=`` its own that falls in it).
+    in column ``j % R``).  What a ring column holds is read off the
+    dispatch's NEWEST row of the slot: the newest page ``<=`` that row's
+    which falls in the column.  Where a dispatch's rows lie on several
+    pages, an older one's page is then the newest in ITS column; and
+    where its newest page was not written (the padding of a last
+    dispatch goes to the trash page, yet counts as the newest row), what
+    that page's column still holds of the page ``R`` before it is
+    labelled with positions past every valid row, so the mask by
+    position leaves it out, and by the ring rule no valid row reads back
+    to that page.
     Layer by layer the fresh rows are written at (layer of its kind,
     page, offset) and that kind's pages of the sequence read back, the
     fresh rows among them.  Rows where ``row_ok`` [S, W] is False (an
@@ -556,13 +581,12 @@ def paged_prefill(cfg: MellumConfig, params: PyTree, pool: PagedGQA,
                   ) -> Tuple[PagedGQA, Array]:
     """One prefill dispatch's rows ``toks`` [W] of the sequence whose
     page tables are ``ptab_s`` ([TBL] full, [R] window), at page-aligned
-    ``start``.  W is ONE page: the engine sends a family with a bounded
-    kind of page no more (``DecodeEngine.prefill_rows``), because the
-    window ring holds the pages one page's rows reach back to and ``m``
-    pages would need ``m - 1`` more (the full kind's row scatter takes
-    any W).  Its rows are written into one page of each kind (those
+    ``start``.  W is a whole number of pages, no more than the window
+    ring leaves room for (``DecodeEngine.prefill_rows`` derives it from
+    what :func:`page_kinds` declares; the full kind's row scatter takes
+    any W).  Its rows are written into their pages of each kind (those
     past ``n_valid`` into the trash pages; on the window kind over the
-    slot's oldest page once the ring is full) and it attends its
+    slot's oldest pages once the ring is full) and it attends its
     context through the tables.  Returns (pool', the token sampled after
     row ``n_valid - 1``)."""
     W = toks.shape[0]

@@ -101,19 +101,20 @@ KINDS OF PAGE (PR 32).  A family whose layers do not all keep a row
 equally long declares the kinds of page its pool has
 (``page_kinds(cfg, page_tokens)``; ``models/mellum.py``: ``full``, a
 rung's worth a slot over the full-attention layers' slab, and
-``window``, ``sliding_window / C + 1`` pages a slot at most over the
-sliding-window layers' slab).  The engine keeps an allocator and a page
-table a kind (:class:`_PageKind`), admits on all of them, grows and
-releases all of them, audits all of them, and hands the family's two
-dispatches the tables in the declared order (the bare array where
-there is one kind, as ever).  A kind's table row is a ring of its
+``window``, a ring of some ``sliding_window / C + 2`` pages a slot at
+most over the sliding-window layers' slab).  The engine keeps an
+allocator and a page table a kind (:class:`_PageKind`), admits on all
+of them, grows and releases all of them, audits all of them, and hands
+the family's two dispatches the tables in the declared order (the bare
+array where there is one kind, as ever).  A kind's table row is a ring of its
 ``cap`` columns, the page of positions ``j C ..`` in column ``j %
 cap``: a bounded kind REUSES a slot's oldest page for its newest rows,
 in decode steps and between prefill chunks alike, with no allocator
 call (the page's rows all lie further back than the window reads; a
-prefill dispatch of such a family therefore carries ONE page: ``m``
-pages would need ``m - 1`` more columns in the ring, ROADMAP R1); an
-unbounded kind's ``cap`` is the longest rung's pages and never wraps.
+prefill dispatch of such a family therefore carries no more pages than
+the ring leaves room for, ``1 + ahead // C``: THE RING RULE,
+:class:`_PageKind`); an unbounded kind's ``cap`` is the longest rung's
+pages and never wraps.
 One algorithm with the kinds as data: ``gpt`` and ``deepseek_v2``
 declare none and run it with one unbounded kind.  A family with a
 bounded kind mounts no prefix by reference (a later slot would be
@@ -180,9 +181,10 @@ def model_family(cfg):
     ``paged_decode`` appends to the step's tokens), ``page_kinds(cfg,
     page_tokens)`` (the KINDS OF PAGE its pool has, each ``(name, the
     most pages a slot may hold or None for a rung's worth)`` and, for a
-    bounded kind that takes speculative rows, the rows a dispatch may
-    write ahead of the committed frontier: see
-    :class:`_PageKind`; a family that states none has one kind),
+    bounded kind that takes speculative rows or several pages a prefill
+    dispatch, the rows a dispatch may write ahead of the committed
+    frontier: see :class:`_PageKind`; a family that states none has one
+    kind),
     ``paged_self_draft_prefill`` / ``paged_self_draft_round`` /
     ``self_draft_depth(cfg)`` (its own draft block:
     ``DecodeEngine(draft="self")``) and
@@ -408,7 +410,10 @@ class _PageKind:
     the engine refuses ``k > ahead`` at construction.  Inside that bound
     a rejected draft's row harms nothing: the slot's next round writes
     the committed token's row over it, and no row attends it before,
-    because every mask is by position."""
+    because every mask is by position.  A PREFILL dispatch is held to
+    the same rule: it starts page-aligned at the frontier and its
+    ``m``-th page opens ``(m - 1) C`` rows past it, so it carries at
+    most ``1 + ahead // C`` pages (``DecodeEngine.prefill_rows``)."""
 
     __slots__ = ("name", "bounded", "cap", "ahead", "alloc", "ptab",
                  "n_pages")
@@ -901,13 +906,14 @@ class DecodeEngine:
         self._resident: "OrderedDict[bytes, Tuple[np.ndarray, Tuple[int, ...]]]" = OrderedDict()
         self._resident_max = max(self._kinds[0].alloc.n_pages // 2, 1)
         # rows of a prefill dispatch, a rung: a whole number of pages,
-        # at most the rung and at most PREFILL_ROWS_MAX — and ONE page
-        # where the family has a bounded kind of page, whose ring holds
-        # only the pages one page's rows can reach back to
-        one_page = any(k.bounded for k in self._kinds)
+        # at most the rung and at most PREFILL_ROWS_MAX — and no more
+        # pages than a bounded kind's ring leaves room for (the ring
+        # rule, :class:`_PageKind`: the ``m``-th page of a dispatch
+        # opens ``(m - 1) C`` rows past the frontier)
+        room = [1 + k.ahead // chunk for k in self._kinds if k.bounded]
         self._prefill_rows = {
-            t: chunk * (1 if one_page
-                        else max(1, min(t, PREFILL_ROWS_MAX) // chunk))
+            t: chunk * min([max(1, min(t, PREFILL_ROWS_MAX) // chunk),
+                            *room])
             for t in self.buckets}
         cfg_d = None
         self._draft_cfg = self._draft_params = None
@@ -1238,9 +1244,11 @@ class DecodeEngine:
     def prefill_rows(self, bucket: int) -> int:
         """Rows ONE prefill dispatch of rung ``bucket`` carries: a whole
         number of pages, at most the rung, at most
-        :data:`PREFILL_ROWS_MAX`; one page for a family that declares a
-        bounded kind of page.  Derived from what the engine observes
-        (page width, rung, the family's kinds), no option."""
+        :data:`PREFILL_ROWS_MAX`, and at most ``1 + ahead // C`` pages
+        where the family declares a bounded kind of page (what its ring
+        leaves room for: :class:`_PageKind`).  Derived from what the
+        engine observes (page width, rung, the kinds' declared
+        ``ahead``), no option."""
         return self._prefill_rows[bucket]
 
     def free_slot(self) -> Optional[int]:

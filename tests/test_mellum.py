@@ -37,7 +37,7 @@ from deeplearning4j_tpu.serving.decode import (ContinuousBatcher,  # noqa: E402
 F32_TOL = 2e-4
 WINDOW = 16          # tiny_config's
 C = 8                # page and prefill chunk of the engines here
-RING = WINDOW // C + 1
+RING = WINDOW // C + ml.RING_PREFILL_PAGES     # page_kinds' cap
 
 
 def published_keys(cfg):
@@ -102,12 +102,14 @@ def test_the_published_config_is_the_tiny_ones_shape():
     cfg = ml.MellumConfig()
     assert (cfg.n_periods, cfg.layers_of(ml.WINDOW), cfg.layers_of(ml.FULL),
             cfg.kv_width) == (7, 21, 7, 512)
-    assert ml.page_kinds(cfg, 128) == (("full", None), ("window", 9))
+    # the ring: the 8 pages the frontier's row reads back to and the 2
+    # of a prefill dispatch; 10 x 128 - 128 - 1,023 = 129 rows ahead
+    assert ml.page_kinds(cfg, 128) == (("full", None), ("window", 10, 129))
     # a window that is no whole number of pages touches one page more
     assert ml.page_kinds(dataclasses.replace(cfg, sliding_window=1025),
-                         128)[1] == ("window", 9)
+                         128)[1] == ("window", 10, 128)
     assert ml.page_kinds(dataclasses.replace(cfg, sliding_window=1026),
-                         128)[1] == ("window", 10)
+                         128)[1] == ("window", 11, 255)
     with pytest.raises(ValueError, match="whole periods"):
         ml.MellumConfig(n_layers=6)
 
@@ -189,10 +191,11 @@ stack = jax.jit(ml._paged_stack, static_argnums=0)
 readout = jax.jit(ml._readout, static_argnums=0)
 
 
-def paged_logits(cfg, params, row, n_prompt):
+def paged_logits(cfg, params, row, n_prompt, pages=1):
     """Logits at every position of ``row`` as the serving path computes
-    them: the prompt in chunks of C as ``paged_prefill`` calls the stack,
-    then a token a step as ``paged_decode`` does, the sequence in slot 1
+    them: the prompt in chunks of ``pages`` pages of C as
+    ``paged_prefill`` calls the stack, then a token a step as
+    ``paged_decode`` does, the sequence in slot 1
     of 3 with scattered pages: a full table of every page and a window
     RING of ``page_kinds``' size, column ``j % ring`` for page ``j``."""
     S, TBL = 3, -(-len(row) // C)
@@ -204,10 +207,11 @@ def paged_logits(cfg, params, row, n_prompt):
     ptab_f[1] = 1 + rng.permutation(S * TBL)[:TBL]
     ptab_w[1] = 1 + rng.permutation(S * ring)[:ring]
     out = []
-    at = np.arange(C, dtype=np.int32)
-    for lo in range(0, n_prompt, C):
-        n_valid = min(C, n_prompt - lo)
-        chunk = np.zeros((C,), np.int32)
+    W = pages * C
+    at = np.arange(W, dtype=np.int32)
+    for lo in range(0, n_prompt, W):
+        n_valid = min(W, n_prompt - lo)
+        chunk = np.zeros((W,), np.int32)
         chunk[:n_valid] = row[lo:lo + n_valid]
         pool, x, _ = stack(cfg, params, pool, (ptab_f[1][None],
                                                ptab_w[1][None]),
@@ -224,18 +228,25 @@ def paged_logits(cfg, params, row, n_prompt):
     return np.concatenate(out), np.asarray(counts), pool
 
 
-@pytest.mark.parametrize("length,n_prompt", [
-    (12, 5),        # shorter than the window
-    (16, 11),       # the window itself
-    (17, 16),       # one past it
-    (88, 53),       # several windows: the ring goes round in the prompt
-    (88, 3),        # ... and in the decode steps
+@pytest.mark.parametrize("length,n_prompt,pages", [
+    (12, 5, 1),     # shorter than the window
+    (16, 11, 1),    # the window itself
+    (17, 16, 1),    # one past it
+    (88, 53, 1),    # several windows: the ring goes round in the prompt
+    (88, 3, 1),     # ... and in the decode steps
+    # two pages a chunk, what the ring leaves room for: the prompt ends
+    # in the chunk's first page twice round the ring (its second page is
+    # padding, never written, and lies over a page of the lap before),
+    (88, 69, 2),
+    (88, 77, 2),    # ... in its second page,
+    (88, 64, 2),    # ... and on a chunk's edge
 ])
-def test_chunked_prefill_then_decode_matches_reference_logits(length,
-                                                              n_prompt):
+def test_chunked_prefill_then_decode_matches_reference_logits(
+        length, n_prompt, pages):
     cfg, params = model()
+    assert pages <= 1 + ml.page_kinds(cfg, C)[1][2] // C
     row = some_ids(cfg, (length,), seed=3)
-    got, counts, pool = paged_logits(cfg, params, row, n_prompt)
+    got, counts, pool = paged_logits(cfg, params, row, n_prompt, pages)
     want = reference_logits(cfg, params, row)[0]
     assert close(got, want)
     # one active slot, eight expert layers, two experts a token: the idle
@@ -246,13 +257,32 @@ def test_chunked_prefill_then_decode_matches_reference_logits(length,
     assert pool.full_k.shape == (2, 1 + 3 * -(-length // C), C, 16)
 
 
+@pytest.mark.parametrize("n_prompt", [77, 88])
+def test_a_page_more_than_the_ring_leaves_room_for_fails_the_comparison(
+        n_prompt):
+    """The control of the ring rule on the stack itself: chunks of three
+    pages where the ring's four columns leave room for two.  The third
+    page lies over a page the chunk's first row still reads (written
+    over at 88 tokens; at 77, where it is padding, labelled away)."""
+    cfg, params = model()
+    pages = 2 + ml.page_kinds(cfg, C)[1][2] // C
+    row = some_ids(cfg, (96,), seed=3)
+    want = reference_logits(cfg, params, row)[0]
+    got, _, _ = paged_logits(cfg, params, row, n_prompt, pages)
+    assert close(got[:pages * C], want[:pages * C])
+    assert not close(got[:n_prompt], want[:n_prompt])
+
+
 @pytest.mark.parametrize("window", [WINDOW - 1, WINDOW + 1])
 def test_a_window_mask_off_by_one_fails_the_comparison(window):
     """15 or 17 keys where the model has 16: both paged paths, and the
     cache-less forward, against the reference of the model itself."""
     cfg, params = model()
     off = dataclasses.replace(cfg, sliding_window=window)
-    assert ml.page_kinds(off, C) == ml.page_kinds(cfg, C)
+    # the same ring; what it leaves ahead moves with the window
+    assert [k[:2] for k in ml.page_kinds(off, C)] == [
+        k[:2] for k in ml.page_kinds(cfg, C)]
+    assert ml.page_kinds(off, C)[1][2] == (RING - 1) * C - (window - 1)
     row = some_ids(cfg, (56,), seed=3)
     want = reference_logits(cfg, params, row)[0]
     got, _, _ = paged_logits(off, params, row, n_prompt=29)

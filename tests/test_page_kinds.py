@@ -55,11 +55,13 @@ def held(eng, slot):
 @pytest.mark.parametrize("family", FAMILIES)
 def test_a_slot_holds_a_rungs_pages_or_a_ring(family):
     """A run 6 windows long (96 positions of mellum's 16): a page of an
-    unbounded kind every C positions, never more than ``window / C + 1``
-    of a bounded one."""
+    unbounded kind every C positions, never more than the ring
+    (``window / C`` pages and those of a prefill dispatch) of a bounded
+    one."""
     cfg, eng = engine(family)
     bounds = {k.name: k.cap for k in eng._kinds if k.bounded}
-    assert bounds == ({"window": 16 // C + 1} if family == "mellum" else {})
+    assert bounds == ({"window": 16 // C + ml.RING_PREFILL_PAGES}
+                      if family == "mellum" else {})
     prompt = np.arange(1, 42, dtype=np.int32) % cfg.vocab_size
     slot, _ = eng.start(prompt, max_tokens=56)
     for _ in range(55):
@@ -237,19 +239,77 @@ def test_per_kind_counters_are_what_the_tables_hold():
     decode_metrics.reset()
     slot, _ = eng.start(np.ones(40, np.int32), max_tokens=30)
     snap = decode_metrics.snapshot()
-    # 5 chunks into a ring of 3: two written over
+    # 5 pages into a ring of 4: one written over
     assert (snap["pages_in_use_full"], snap["pages_in_use_window"],
-            snap["window_pages_reused"]) == (5, 3, 2)
+            snap["window_pages_reused"]) == (5, 4, 1)
     for _ in range(9):
         eng.advance()
     snap = decode_metrics.snapshot()
     # positions 40..48 written: a full layer holds 41..49 rows; position
-    # 40 opens page 5 over page 2 (the ring holds rows from 24) and
-    # position 48 page 6 over page 3 (rows from 32)
+    # 40 opens page 5 over page 1 (the ring holds rows from 16) and
+    # position 48 page 6 over page 2 (rows from 24)
     assert snap["kv_rows_held_full"] == sum(range(41, 50))
-    assert snap["kv_rows_held_window"] == sum(range(17, 25)) + 17
-    assert snap["window_pages_reused"] == 4
-    assert (snap["pages_in_use_full"], snap["pages_in_use_window"]) == (7, 3)
+    assert snap["kv_rows_held_window"] == sum(range(25, 33)) + 25
+    assert snap["window_pages_reused"] == 3
+    assert (snap["pages_in_use_full"], snap["pages_in_use_window"]) == (7, 4)
     eng.release(slot)
     snap = decode_metrics.snapshot()
     assert snap["pages_in_use_full"] == snap["pages_in_use_window"] == 0
+
+
+def _overwritten_and_read(window, c, cap, m):
+    """A prefill dispatch of ``m`` pages from the page-aligned frontier
+    ``p`` into a ring of ``cap`` columns, every ``p`` of two laps: does a
+    page it opens lie over a page that a row of the dispatch reads?
+    Counted on the ring itself, no formula: column ``j % cap`` holds page
+    ``j``, and the dispatch's row at ``x`` reads positions ``x - (window
+    - 1) .. x``."""
+    for q in range(cap, 3 * cap + 1):
+        opened = range(q, q + m)
+        lost = {j - cap for j in opened}
+        # the oldest page any row of the dispatch reads is the
+        # frontier's own row's; every page from there to the newest
+        read = set(range(max(0, q * c - (window - 1)) // c, q + m))
+        if lost & read:
+            return True
+    return False
+
+
+@pytest.mark.parametrize("c", [1, 3, 8, 32, 128])
+def test_the_width_the_ring_rule_gives_is_the_widest_that_is_safe(c):
+    """``1 + ahead // C`` pages a prefill dispatch (``DecodeEngine
+    .prefill_rows``), ``ahead`` as the families state it: over a sweep
+    of windows and rings no page such a dispatch opens lies over a page
+    its rows read, and with one page more one always does."""
+    swept = 0
+    for window in sorted({1, 2, c - 1, c, c + 1, 2 * c, 5 * c - 1, 5 * c,
+                          5 * c + 1, 5 * c + 2, 1024} - {0, -1}):
+        least = -(-(window - 1) // c) + 1       # the ring of one page
+        for cap in range(least, least + 4):
+            ahead = (cap - 1) * c - (window - 1)
+            assert ahead >= 0
+            m = 1 + ahead // c
+            assert m == cap - least + 1
+            assert not _overwritten_and_read(window, c, cap, m)
+            assert _overwritten_and_read(window, c, cap, m + 1)
+            swept += 1
+    assert swept >= 28
+
+
+@pytest.mark.parametrize("family,page,cap,ahead,pages", [
+    ("mellum", 128, 10, 129, 2), ("mellum", 64, 18, 65, 2),
+    ("exaone_moe", 128, 2, 1, 1), ("exaone_moe", 32, 5, 1, 1)])
+def test_what_the_families_declare_and_the_pages_it_gives(
+        family, page, cap, ahead, pages):
+    """The published configurations: Mellum's ring leaves room for two
+    pages a prefill dispatch at either page width, K-EXAONE's stays
+    ``("window", 2, 1)`` and gives one."""
+    from deeplearning4j_tpu.models import exaone_moe as ex
+
+    fam, cfg = ((ml, ml.MellumConfig()) if family == "mellum"
+                else (ex, ex.ExaoneMoeConfig()))
+    assert fam.page_kinds(cfg, page) == (("full", None),
+                                         ("window", cap, ahead))
+    assert 1 + ahead // page == pages
+    assert not _overwritten_and_read(cfg.sliding_window, page, cap, pages)
+    assert _overwritten_and_read(cfg.sliding_window, page, cap, pages + 1)

@@ -1,12 +1,14 @@
 """A prefill dispatch carries several pages of prompt (PR 33): the rows
 of a dispatch are derived by ``DecodeEngine`` (``prefill_rows``: a whole
-number of pages, at most the rung, at most ``PREFILL_ROWS_MAX``, ONE
-page for a family with a bounded kind of page); the page width stays
-what the ``prefill_chunk`` keyword gives.  Every test takes the family
-as a parameter and compares an engine whose dispatches carry ``M``
-pages with one whose dispatches carry one (the module constant set to
-the page width): the same rows at the same pages and offsets, the same
-greedy tokens, every page back."""
+number of pages, at most the rung, at most ``PREFILL_ROWS_MAX``, and for
+a family with a bounded kind of page at most the ``1 + ahead // C``
+pages its ring leaves room for: two for Mellum, one for K-EXAONE); the
+page width stays what the ``prefill_chunk`` keyword gives.  Every test
+takes the family as a parameter and compares an engine whose dispatches
+carry ``M`` pages (or what the ring leaves of them) with one whose
+dispatches carry one (the module constant set to the page width): the
+same rows at the same pages and offsets, the same greedy tokens, every
+page back."""
 
 import hashlib
 import json
@@ -16,7 +18,8 @@ import jax
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.models import deepseek_v2 as ds, gpt, mellum as ml
+from deeplearning4j_tpu.models import (deepseek_v2 as ds, exaone_moe as ex,
+                                       gpt, mellum as ml)
 from deeplearning4j_tpu.runtime import telemetry
 from deeplearning4j_tpu.runtime.metrics import compile_metrics, decode_metrics
 from deeplearning4j_tpu.serving import decode
@@ -24,8 +27,15 @@ from deeplearning4j_tpu.serving.decode import DecodeEngine
 
 C = 8                       # the page width
 M = 4                       # pages a dispatch, where the family takes them
-FAMILIES = ["gpt", "deepseek_v2", "mellum"]
+#: ``mellum``: a window of 16, a ring of 4 columns; ``mellum-w20``: a
+#: window of 20, a ring of 5, so that the two pages of a dispatch can
+#: straddle the ring's lap (pages 4 and 5 in columns 4 and 0)
+FAMILIES = ["gpt", "deepseek_v2", "mellum", "mellum-w20"]
 MANY = ["gpt", "deepseek_v2"]           # families with no bounded kind
+#: pages a dispatch where ``PREFILL_ROWS_MAX`` gives ``M``: what a
+#: bounded kind's ring leaves room for (``exaone_moe``: a ring of 2)
+PAGES = {"gpt": M, "deepseek_v2": M, "mellum": 2, "mellum-w20": 2,
+         "exaone_moe": 1}
 #: rungs (48 is not a whole number of 4-page dispatches: a last dispatch
 #: can reach past its end with no prefix hit at all)
 LADDER = (16, 48, 64, 128)
@@ -43,7 +53,13 @@ def model(family):
     if family == "deepseek_v2":
         cfg = ds.tiny_config(compute_dtype="float32", max_len=128)
         return cfg, ds.init_params(jax.random.key(0), cfg, std=0.3)
-    cfg = ml.tiny_config(compute_dtype="float32")       # window 16
+    if family == "exaone_moe":
+        cfg = ex.tiny_config(compute_dtype="float32")   # window 8
+        return cfg, ex.init_params(jax.random.key(0), cfg, std=0.3)
+    name, _, window = family.partition("-w")
+    assert name == "mellum"
+    cfg = ml.tiny_config(compute_dtype="float32",
+                         sliding_window=int(window or 16))
     return cfg, ml.init_params(jax.random.key(0), cfg, std=0.3)
 
 
@@ -59,18 +75,42 @@ def engine(family, pages, monkeypatch, **kw):
 
 
 def live_rows(eng, slot, n):
-    """The first ``n`` rows the slot holds on every slab of the kind
-    whose table never wraps (the first declared), read through its page
-    table: [slabs, L, n, F]."""
-    kind = eng._kinds[0]
-    pids = kind.ptab[slot, :-(-n // C)]
+    """The rows the slot holds of a sequence of ``n``, read through its
+    page tables: every one on the slabs of the kind whose table never
+    wraps (the first declared), [slabs, L, n, F]; with a bounded kind
+    beside it, a tuple of that and the rows of the pages its ring holds
+    (page ``j`` in column ``j % cap``), oldest first."""
+    n_pages = -(-n // C)
     slabs = jax.tree.leaves(eng._pool_state())
-    slabs = slabs[:2] if len(eng._kinds) > 1 else slabs
-    out = []
-    for a in slabs:
-        a = np.asarray(a)[:, pids]                       # [L, n_p, C, F]
-        out.append(a.reshape(a.shape[0], -1, a.shape[-1])[:, :n])
-    return np.stack(out)
+
+    def read(slabs, pids, first_row):
+        out = []
+        for a in slabs:
+            a = np.asarray(a)[:, pids]                   # [L, n_p, C, F]
+            out.append(a.reshape(a.shape[0], -1, a.shape[-1]
+                                 )[:, :n - first_row])
+        return np.stack(out)
+
+    full = eng._kinds[0]
+    if len(eng._kinds) == 1:
+        return read(slabs, full.ptab[slot, :n_pages], 0)
+    ring = eng._kinds[1]
+    oldest = max(0, n_pages - ring.cap)
+    return (read(slabs[:2], full.ptab[slot, :n_pages], 0),
+            read(slabs[2:], ring.ptab[slot, np.arange(oldest, n_pages)
+                                      % ring.cap], oldest * C))
+
+
+def assert_rows(got, want, exact=False):
+    for a, b in zip(*(x if isinstance(x, tuple) else (x,)
+                      for x in (got, want))):
+        if exact:
+            np.testing.assert_array_equal(a, b)
+        else:
+            # the same arithmetic row for row; only how many rows share
+            # a product changes, which float32 on the CPU may round
+            # differently
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
 
 
 def prompt_of(cfg, n, seed=0):
@@ -95,12 +135,18 @@ def all_back(eng):
     assert all(not k.ptab.any() and not k.n_pages.any() for k in eng._kinds)
 
 
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", FAMILIES + ["exaone_moe"])
 def test_the_width_is_derived_from_page_rung_and_kinds(family, monkeypatch):
     cfg, eng = engine(family, M, monkeypatch)
     assert eng.page_tokens == eng.prefill_chunk == C
-    want = ({t: C for t in LADDER} if family == "mellum"
-            else {16: 16, 48: 32, 64: 32, 128: 32})
+    # as many pages as the rung and the limit give, and no more than
+    # the ring of a bounded kind leaves room for: two for Mellum at
+    # every rung that has them, one for K-EXAONE's ring of 2
+    room = [1 + k.ahead // C for k in eng._kinds if k.bounded]
+    assert room == ([] if family in MANY else [PAGES[family]])
+    want = {t: C * min(PAGES[family], t // C, M) for t in LADDER}
+    assert want[16] == min(2, PAGES[family]) * C
+    assert want[128] == PAGES[family] * C
     assert {t: eng.prefill_rows(t) for t in LADDER} == want
     # a page wider than the limit: one page a dispatch, never less
     monkeypatch.setattr(decode, "PREFILL_ROWS_MAX", 4)
@@ -118,15 +164,36 @@ def test_the_width_is_derived_from_page_rung_and_kinds(family, monkeypatch):
 #: rung 48, dispatches of 32 rows — in a last dispatch that starts at
 #: row 32 and reaches 16 rows past the rung's end
 SHAPES = [(5, 6), (16, 20), (32, 20), (61, 3), (41, 7)]
+#: what a ring makes new (dispatches of 16 rows, two pages), for the
+#: families that have one: the prompt ends in the FIRST page of its last
+#: dispatch, so that dispatch's second page is padding and is never
+#: written, at the ring's first lap (37 tokens: pages 4 and 5; in a ring
+#: of 5 they straddle the lap, page 5's column still holding page 0) and
+#: past ``cap + 2`` pages after two whole laps (115 tokens: pages 14 and
+#: 15, columns 4 and 0 of 5, 2 and 3 of 4); and in its second page past
+#: two laps (93 tokens: pages 10 and 11)
+RING_SHAPES = [(37, 9), (115, 9), (93, 12)]
 
 
-@pytest.mark.parametrize("n,max_tokens", SHAPES)
-@pytest.mark.parametrize("family", FAMILIES)
+def forward_greedy(cfg, params, prompt, toks):
+    """What Mellum's cache-less forward at float32 says the greedy
+    tokens behind ``prompt`` are, given the served ones before each:
+    the served chain is the model's own exactly if they are the same."""
+    row = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
+    logits = np.asarray(ml.forward_logits(cfg, params, row[None]))[0]
+    return logits[len(prompt) - 1:].argmax(-1).tolist()
+
+
+@pytest.mark.parametrize("family,n,max_tokens", [
+    (family, *shape) for family in FAMILIES
+    for shape in SHAPES + RING_SHAPES * (family not in MANY)])
 def test_m_pages_a_dispatch_leave_what_one_page_a_dispatch_does(
         family, n, max_tokens, monkeypatch):
-    """The pool's live rows and the greedy tokens of a prompt prefilled
-    ``M`` pages a dispatch are those of one page a dispatch, and a
-    neighbour slot's rows are untouched by the join."""
+    """The pool's live rows (of both kinds of page where there are two)
+    and the greedy tokens of a prompt prefilled ``M`` pages a dispatch
+    (what the ring leaves of them) are those of one page a dispatch
+    and, for Mellum, of the cache-less forward; a neighbour slot's rows
+    are untouched by the join."""
     got = {}
     for pages in (1, M):
         cfg, eng = engine(family, pages, monkeypatch)
@@ -136,23 +203,26 @@ def test_m_pages_a_dispatch_leave_what_one_page_a_dispatch_does(
         decode_metrics.reset()
         prompt = prompt_of(cfg, n, seed=n)
         slot, first = eng.start(prompt, max_tokens=max_tokens)
-        rows = eng.prefill_rows(eng.pick_bucket(n + max_tokens))
+        bucket = eng.pick_bucket(n + max_tokens)
+        rows = eng.prefill_rows(bucket)
+        assert rows == C * min(pages, PAGES[family], bucket // C)
         snap = decode_metrics.snapshot()
         assert snap["prefill_dispatches"] == -(-n // rows)
         assert snap["prefill_rows_dispatched"] == rows * -(-n // rows)
         assert snap["prefill_rows_valid"] == n
-        np.testing.assert_array_equal(live_rows(eng, s0, 21), before)
+        assert_rows(live_rows(eng, s0, 21), before, exact=True)
         written = live_rows(eng, slot, n)
         toks = [first] + [int(eng.advance()[slot])
                           for _ in range(max_tokens - 1)]
         got[pages] = (written, toks)
         all_back(eng)
     (rows_1, toks_1), (rows_m, toks_m) = got[1], got[M]
-    # the same arithmetic row for row; only how many rows share a
-    # product changes, which float32 on the CPU may round differently
-    np.testing.assert_allclose(rows_m, rows_1, rtol=2e-4, atol=2e-5)
+    assert_rows(rows_m, rows_1)
     assert toks_m == toks_1
     assert len(set(toks_1)) > 1 or max_tokens < 4
+    if family.startswith("mellum"):
+        cfg, params = model(family)
+        assert toks_m == forward_greedy(cfg, params, prompt, toks_m)
 
 
 @pytest.mark.parametrize("total", [60, 124])
@@ -328,26 +398,34 @@ def lowered_prefill(eng):
     return out
 
 
-def test_a_bounded_kind_family_lowers_the_parents_prefill_program():
+@pytest.mark.parametrize("family", ["deepseek_v2", "mellum"])
+def test_a_family_held_to_one_page_lowers_the_parents_prefill_program(
+        family, monkeypatch):
     """``tests/data/prefill_programs_pr32.json``: the PARENT commit's
     prefill programs of this engine (one page a dispatch), lowered on
-    this CPU.  Mellum's must still be those, whatever the limit; the
-    one-page programs of DeepSeek-V2, whose ``paged_prefill`` changed
-    in its docstring only, too."""
+    this CPU.  The one-page programs of DeepSeek-V2, whose
+    ``paged_prefill`` changed in its docstring only, are still those;
+    and Mellum's, and its pool's bytes, wherever its ring is declared
+    with room for ONE page a dispatch as the parent's was (3 columns
+    here): two pages a dispatch are the ring's own choice and nothing
+    else in the family or the engine moved."""
     with open(os.path.join(os.path.dirname(__file__), "data",
                            "prefill_programs_pr32.json")) as f:
         parent = json.load(f)
     if jax.__version__ != parent["jax"]:
         pytest.skip(f"the parent's programs were lowered by jax "
                     f"{parent['jax']}")
-    cfg, params = model("mellum")
-    eng = DecodeEngine(cfg, params, n_slots=3, buckets=LADDER,
-                       prefill_chunk=C)
     assert decode.PREFILL_ROWS_MAX > C
-    assert lowered_prefill(eng) == parent["mellum"]
-    assert eng.pool_bytes == parent["mellum_pool_bytes"]
-    cfg, params = model("deepseek_v2")
-    eng = DecodeEngine(cfg, params, n_slots=3, buckets=(16,),
-                       prefill_chunk=16)
-    assert eng.prefill_rows(16) == 16
-    assert lowered_prefill(eng) == parent["deepseek_v2"]
+    cfg, params = model(family)
+    if family == "mellum":
+        monkeypatch.setattr(ml, "RING_PREFILL_PAGES", 1)
+        assert ml.page_kinds(cfg, C)[1] == ("window", 3, 1)
+        eng = DecodeEngine(cfg, params, n_slots=3, buckets=LADDER,
+                           prefill_chunk=C)
+        assert {eng.prefill_rows(t) for t in LADDER} == {C}
+        assert eng.pool_bytes == parent["mellum_pool_bytes"]
+    else:
+        eng = DecodeEngine(cfg, params, n_slots=3, buckets=(16,),
+                           prefill_chunk=16)
+        assert eng.prefill_rows(16) == 16
+    assert lowered_prefill(eng) == parent[family]
