@@ -285,20 +285,33 @@ def route_topk_renorm(scores: Array, top_k: int) -> Tuple[Array, Array]:
                      0.0), chosen
 
 
-def route_sigmoid_bias(scores: Array, bias: Array, top_k: int, scale: float
+def route_sigmoid_bias(scores: Array, bias: Array, top_k: int, scale: float,
+                       n_group: int = 1, topk_group: int = 1
                        ) -> Tuple[Array, Array]:
-    """DeepSeek-V3's routing with one group (``scoring_func`` sigmoid,
-    ``n_group = topk_group = 1``, ``norm_topk_prob`` true): ``scores``
-    [N, E] float32 are the SIGMOIDS of the router's logits, each expert's
-    own and no distribution over them.  The ``top_k`` largest of
-    ``scores + bias`` a token are taken (``bias`` [E], the selection bias
-    that balances load and takes no part in the result), each weighted
-    ``scale`` times its UNBIASED score's share of the taken ones' sum.
-    Returns what :func:`route_group_limited` does: (weights [N, E]
-    float32, zero off the chosen experts and summing to ``scale`` a
-    token; chosen [N, E] bool)."""
+    """DeepSeek-V3's routing (``scoring_func`` sigmoid, ``norm_topk_prob``
+    true): ``scores`` [N, E] float32 are the SIGMOIDS of the router's
+    logits, each expert's own and no distribution over them.  With
+    ``n_group`` groups of consecutive experts, a group's score is the
+    sum of its two largest ``scores + bias`` and only the ``topk_group``
+    best groups stay (their experts' ``scores + bias`` kept, the others'
+    set to 0, as ``modeling_deepseek_v3.py`` does); one group keeps
+    everything and takes no group step at all.  The ``top_k`` largest of
+    ``scores + bias`` a token are taken (``bias`` [E], the selection
+    bias that balances load and takes no part in the result), each
+    weighted ``scale`` times its UNBIASED score's share of the taken
+    ones' sum.  Returns what :func:`route_group_limited` does: (weights
+    [N, E] float32, zero off the chosen experts and summing to ``scale``
+    a token; chosen [N, E] bool)."""
     N, E = scores.shape
-    _, top_experts = lax.top_k(scores + bias[None, :], top_k)   # [N, k]
+    choice = scores + bias[None, :]
+    if n_group > 1:
+        g = choice.reshape(N, n_group, E // n_group)
+        _, top_groups = lax.top_k(lax.top_k(g, 2)[0].sum(axis=-1),
+                                  topk_group)                   # [N, kg]
+        keep = jnp.zeros((N, n_group), jnp.bool_).at[
+            jnp.arange(N)[:, None], top_groups].set(True)
+        choice = jnp.where(keep[:, :, None], g, 0.0).reshape(N, E)
+    _, top_experts = lax.top_k(choice, top_k)                   # [N, k]
     chosen = jnp.zeros((N, E), jnp.bool_).at[
         jnp.arange(N)[:, None], top_experts].set(True)
     taken = jnp.where(chosen, scores, 0.0)
@@ -306,26 +319,33 @@ def route_sigmoid_bias(scores: Array, bias: Array, top_k: int, scale: float
                     ), chosen
 
 
-def gated_ffn(x: Array, w_gate: Array, w_up: Array, w_down: Array) -> Array:
+def gated_ffn(x: Array, w_gate: Array, w_up: Array, w_down: Array,
+              limit: float = 0.0) -> Array:
     """``W_down(silu(W_gate x) * W_up x)``: operands in ``x``'s type,
-    products accumulated and returned in float32."""
+    products accumulated and returned in float32.  A ``limit`` ``L > 0``
+    is the SwiGLU clamp (``expert_swiglu_limit_list``): ``silu(min(W_gate
+    x, L)) * clip(W_up x, -L, L)``; 0 clamps nothing."""
     g = jnp.einsum("nh,hf->nf", x, w_gate,
                    preferred_element_type=jnp.float32)
     u = jnp.einsum("nh,hf->nf", x, w_up,
                    preferred_element_type=jnp.float32)
+    if limit:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
     return jnp.einsum("nf,fh->nh", (jax.nn.silu(g) * u).astype(x.dtype),
                       w_down, preferred_element_type=jnp.float32)
 
 
 def held_experts_ffn(x: Array, weights: Array, chosen: Array,
-                     experts: dict) -> Tuple[Array, Array]:
+                     experts: dict, limit: float = 0.0
+                     ) -> Tuple[Array, Array]:
     """The routed part of an expert layer that the experts held here
     give.  ``x`` [N, H]; ``weights``/``chosen`` [N, E_held]: the held
     columns of :func:`route_group_limited`'s result (rows of tokens that
     do not count — an idle slot, a chunk's padding — all zero/False);
     ``experts`` ``{"w_gate": [E_held, H, F], "w_up": [E_held, H, F],
-    "w_down": [E_held, F, H]}``.  Returns (y [N, H] float32, hits: how
-    many held experts some token chose).
+    "w_down": [E_held, F, H]}``; ``limit`` each expert's SwiGLU clamp
+    (:func:`gated_ffn`).  Returns (y [N, H] float32, hits: how many held
+    experts some token chose).
 
     Only the experts HIT are touched: they are put first and a loop of
     ``hits`` steps runs each over all N rows (a handful, on the decode
@@ -342,7 +362,7 @@ def held_experts_ffn(x: Array, weights: Array, chosen: Array,
         w = [lax.dynamic_index_in_dim(experts[k], e, keepdims=False)
              for k in ("w_gate", "w_up", "w_down")]
         mine = lax.dynamic_index_in_dim(weights, e, axis=1, keepdims=True)
-        return acc + mine * gated_ffn(x, *w)
+        return acc + mine * gated_ffn(x, *w, limit=limit)
 
     y = lax.fori_loop(0, hits, one,
                       jnp.zeros(x.shape, jnp.float32))

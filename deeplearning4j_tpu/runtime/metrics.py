@@ -443,6 +443,14 @@ class DecodeMetrics:
       chose, a layer a dispatch (what the step read of the experts);
       ``moe_layer_dispatches``: expert layers run.
 
+    A SLOT STATE (an engine whose family declares one,
+    ``models/ling_hybrid.py``'s KDA layers: a fixed matrix a sequence):
+
+    - ``linear_state_resets``: joins whose first prefill dispatch started
+      the slot's state from zero (one an admission in such an engine);
+      ``state_bytes_held``: gauge, bytes of the engine's slab of
+      ``n_slots`` slots' states.
+
     Kinds of page (an engine whose family declares them,
     ``models/mellum.py``: a slab and a page table for the full-attention
     layers, another for the sliding-window layers, whose table row is a
@@ -524,6 +532,8 @@ class DecodeMetrics:
             self.pages_held_resident = 0
             self.params_held_casts = 0
             self.params_held_bytes = 0
+            self.linear_state_resets = 0
+            self.state_bytes_held = 0
             for key in self.SECONDS:
                 setattr(self, key, 0.0)
             for key in (self.FAMILY_COUNTS + self.KIND_GAUGES
@@ -570,6 +580,14 @@ class DecodeMetrics:
         with self._lock:
             self.params_held_casts += 1
             self.params_held_bytes += int(nbytes)
+
+    def note_linear_state_reset(self) -> None:
+        with self._lock:
+            self.linear_state_resets += 1
+
+    def note_state_bytes_held(self, nbytes: int) -> None:
+        with self._lock:
+            self.state_bytes_held = int(nbytes)
 
     def note_round(self) -> None:
         with self._lock:
@@ -766,6 +784,8 @@ class DecodeMetrics:
                 "pages_held_resident": self.pages_held_resident,
                 "params_held_casts": self.params_held_casts,
                 "params_held_bytes": self.params_held_bytes,
+                "linear_state_resets": self.linear_state_resets,
+                "state_bytes_held": self.state_bytes_held,
                 **{key: getattr(self, key) for key in self.SECONDS},
                 **{key: getattr(self, key) for key in (
                     self.FAMILY_COUNTS + self.KIND_GAUGES

@@ -120,6 +120,20 @@ declare none and run it with one unbounded kind.  A family with a
 bounded kind mounts no prefix by reference (a later slot would be
 handed pages since written over).
 
+SLOT STATE (PR 39).  A family whose layers keep a fixed state a
+sequence, not a row a token (``models/ling_hybrid.py``'s KDA layers: a
+[heads, 128, 128] float32 matrix and a convolution's last rows),
+declares it (``slot_state(cfg)``: the shapes of ONE slot's state) and
+the engine holds a slab of ``n_slots`` of them beside the page pool:
+the family's dispatches take the pool as ``(pages, state)``, donated
+and threaded through every dispatch like the pages, so a step in
+flight, a successor's join into its slot and the next step are ordered
+by data.  A prefill dispatch is handed its slot's index (the family
+zeroes the slot's state at ``start`` 0, a join's first dispatch:
+``linear_state_resets``); a decode dispatch leaves a slot it does not
+run as it was.  Such a family mounts no prefix (a hit would need the
+state at the page edge).
+
 SPECULATIVE ROUNDS.  ``advance_spec()`` is a round that commits more
 than one token a slot.  With a SECOND MODEL as the draft
 (``draft=(cfg_d, params_d)``, ``models/gpt.py`` only) the draft proposes
@@ -185,6 +199,8 @@ def model_family(cfg):
     dispatch, the rows a dispatch may write ahead of the committed
     frontier: see :class:`_PageKind`; a family that states none has one
     kind),
+    ``slot_state(cfg)`` (a state a slot holds, not pages: the SLOT
+    STATE, whose ``paged_prefill`` also takes the slot's index),
     ``paged_self_draft_prefill`` / ``paged_self_draft_round`` /
     ``self_draft_depth(cfg)`` (its own draft block:
     ``DecodeEngine(draft="self")``) and
@@ -890,6 +906,16 @@ class DecodeEngine:
         #: it did not before)
         self._kind_names = tuple(k.name for k in self._kinds
                                  ) if declared else ()
+        #: a state a slot holds beside the pages (the SLOT STATE), one
+        #: slot's leaves; None where the family declares none
+        declared_state = getattr(fam, "slot_state", None)
+        self._state_spec = declared_state(cfg) if declared_state else None
+        #: bytes of the slot-state slab, ``n_slots`` slots' worth
+        self.state_bytes = self.n_slots * sum(
+            int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
+            for s in jax.tree.leaves(self._state_spec))
+        #: the slots whose state a join has started and no release ended
+        self._state_held = np.zeros((self.n_slots,), np.bool_)
         #: pages of the pool: an int, or a tuple in the declared order
         self.n_kv_pages = _bare([k.alloc.n_pages for k in self._kinds])
         allocs = [k.alloc for k in self._kinds]
@@ -902,7 +928,8 @@ class DecodeEngine:
         # oldest page, so a family with one registers and looks up
         # nothing (and what a mount copies is a row of ONE table)
         self._mounts_prefixes = (len(self._kinds) == 1
-                                 and not self._kinds[0].bounded)
+                                 and not self._kinds[0].bounded
+                                 and self._state_spec is None)
         self._resident: "OrderedDict[bytes, Tuple[np.ndarray, Tuple[int, ...]]]" = OrderedDict()
         self._resident_max = max(self._kinds[0].alloc.n_pages // 2, 1)
         # rows of a prefill dispatch, a rung: a whole number of pages,
@@ -942,6 +969,14 @@ class DecodeEngine:
                        temperature, seed):
             return fam.paged_prefill(cfg, params, pool, ptab_s, toks,
                                      start, n_valid, temperature, seed)
+
+        if self._state_spec is not None:
+            # the join's dispatches are told whose state they carry
+            def prefill_fn(params, pool, ptab_s, toks, start, n_valid,  # noqa: F811 — takes the plain one's place AND its name: the benchmark finds the program by it
+                           temperature, seed, slot):
+                return fam.paged_prefill(cfg, params, pool, ptab_s, toks,
+                                         start, n_valid, temperature, seed,
+                                         slot)
 
         def decode_fn(params, pool, ptab, tokens, pos, active,
                       temperature, seeds):
@@ -1158,7 +1193,10 @@ class DecodeEngine:
         #: denominator: slots/chip at a given HBM budget is bounded by
         #: live tokens, not bucket length
         self.pool_bytes = int(fam.pages_bytes(
-            cfg, self.n_kv_pages, self.page_tokens, self.kv_dtype))
+            cfg, self.n_kv_pages, self.page_tokens, self.kv_dtype)
+        ) + self.state_bytes
+        if self._state_spec is not None:
+            decode_metrics.note_state_bytes_held(self.state_bytes)
         if cfg_d is not None:
             self.pool_bytes += int(gpt.pages_bytes(
                 cfg_d, self.n_kv_pages, self.page_tokens, self.kv_dtype))
@@ -1287,6 +1325,10 @@ class DecodeEngine:
         if self._pool is None:
             pool = self._family.init_pages(self.cfg, self.n_kv_pages,
                                            self.page_tokens, self.kv_dtype)
+            if self._state_spec is not None:
+                pool = (pool, jax.tree.map(
+                    lambda s: jnp.zeros((self.n_slots,) + s.shape, s.dtype),
+                    self._state_spec))
             if self._pool_shardings is not None:
                 pool = jax.device_put(pool, self._pool_shardings)
             self._pool = pool
@@ -1437,7 +1479,12 @@ class DecodeEngine:
         asserted zero by the chaos drill after drain)."""
         accounted = sum(int(k.n_pages.sum()) for k in self._kinds)
         accounted += sum(len(ids) for _, ids in self._resident.values())
-        return self._alloc.total_refs() - accounted
+        leaked = self._alloc.total_refs() - accounted
+        if self._state_spec is not None:
+            # and the slot state: held by exactly the slots that are taken
+            taken = np.array([o is not None for o in self._slots.owners])
+            leaked += int((self._state_held != taken).sum())
+        return leaked
 
     # -- pool-resident prefix pages ----------------------------------------
     def _resident_lookup(self, prompt: np.ndarray):
@@ -1679,7 +1726,8 @@ class DecodeEngine:
                 pool, _ = self._prefill(
                     params, pool, ptab_s, toks,
                     *((toks,) if self._self_draft else ()), np.int32(0),
-                    np.int32(1), np.float32(0.0), np.uint32(0))
+                    np.int32(1), np.float32(0.0), np.uint32(0),
+                    *self._slot_arg(0))
                 self._pool = pool
                 if self._prefix is not None:
                     pages = self._read(pool, ptab_s)
@@ -1722,6 +1770,11 @@ class DecodeEngine:
         decode_metrics.mark_compiles()
         return {"buckets": len(self.buckets), "compiles": compiles,
                 "warmup_ms": round(wall_ms, 1)}
+
+    def _slot_arg(self, slot: int) -> Tuple[np.ndarray, ...]:
+        """What a prefill dispatch takes behind its seed: the slot's
+        index where the family holds a SLOT STATE, nothing else."""
+        return () if self._state_spec is None else (np.int32(slot),)
 
     def _lower_decode(self, bucket: int):
         """The program a round dispatches (the decode step; a
@@ -1801,12 +1854,16 @@ class DecodeEngine:
         # of pages; the last one is padded (``n_valid``)
         rows = self.prefill_rows(bucket)
         n_rows = prompt.size - h * C        # what no prefix hit covers
+        # the first dispatch at position 0 starts the slot's state from
+        # zero (no prefix hit in a family that holds one)
+        state_reset = self._state_spec is not None and h == 0
         with telemetry.span("decode.prefill",
                             counter=(decode_metrics, "prefill_s"),
                             rid=rid, bucket=bucket, slot=slot,
                             prompt_tokens=int(prompt.size),
                             chunks=n_chunks, rows=rows,
-                            prefix_hit_tokens=hit_len):
+                            prefix_hit_tokens=hit_len,
+                            state_reset=state_reset):
             # only a RESIDENT hit reuses pages by reference (a family
             # that mounts has one kind of page); a host-store hit copies
             # into fresh pool pages, so it needs the full n_chunks
@@ -1848,7 +1905,8 @@ class DecodeEngine:
                     pool, first = self._prefill(
                         params, pool, ptab_s, chunk, *after,
                         np.int32(lo), np.int32(n_valid),
-                        np.float32(temperature), np.uint32(seed))
+                        np.float32(temperature), np.uint32(seed),
+                        *self._slot_arg(slot))
                     self._pool = pool
                 if self._draft_cfg is not None:
                     # draft prefills EVERY page: host-store hits carry
@@ -1881,6 +1939,9 @@ class DecodeEngine:
                     first_tok = int(first)      # join-time sync, once
         dispatched = -(-n_rows // rows)
         decode_metrics.note_prefill(dispatched, dispatched * rows, n_rows)
+        if state_reset:
+            decode_metrics.note_linear_state_reset()
+        self._state_held[slot] = self._state_spec is not None
         if hit_len:
             decode_metrics.note_prefix_hit(hit_len)
             telemetry.event("decode.prefix_hit", bucket=bucket, slot=slot,
@@ -2206,6 +2267,7 @@ class DecodeEngine:
         b.rung[slot] = 0
         b.epoch[slot] += 1
         b.released_at[slot] = time.perf_counter()
+        self._state_held[slot] = False
         self._release_pages(slot)
 
     def vacant_since(self, slot: int) -> Optional[float]:

@@ -209,14 +209,16 @@ def test_one_kind_families_run_as_the_parent_did(family, monkeypatch):
     # is collected before the next is dispatched is never ahead of its
     # fetch and never a step past a request's end (PR 36); no batcher
     # places a request here and every registered page is still in a
-    # slot's table at the last join (PR 37)
+    # slot's table at the last join (PR 37); a family with no slot state
+    # resets none and holds none (PR 39)
     assert {k: got["snapshot"][k] for k in want["snapshot"]} \
         == want["snapshot"]
     new = set(got["snapshot"]) - set(want["snapshot"])
     rows = {"prefill_rows_dispatched", "prefill_rows_valid"}
     ahead = {"decode_dispatches_ahead", "decode_overshoot_steps"}
     vacancy = {"slot_turnovers", "pages_held_resident"}
-    assert new == rows | ahead | vacancy | set(
+    state = {"linear_state_resets", "state_bytes_held"}
+    assert new == rows | ahead | vacancy | state | set(
         decode_metrics.KIND_GAUGES + decode_metrics.KIND_COUNTS)
     assert not any(got["snapshot"][k] for k in new - rows)
     assert (got["snapshot"]["prefill_rows_dispatched"],
