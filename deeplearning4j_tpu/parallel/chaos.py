@@ -231,8 +231,9 @@ class ServingChaos:
 
     Every injector ARMS a fault rather than performing it: the fault
     fires at the replica's next touch of an engine step-boundary entry
-    point (``dispatch_step``, the plain step a batcher runs one ahead of
-    its fetch, / ``advance_spec``, plus ``can_admit`` for the
+    point (``dispatch_step`` / ``dispatch_round``, the plain step and the
+    self-draft round a batcher runs one ahead of their fetch, /
+    ``advance_spec``, plus ``can_admit`` for the
     faults that are legal under the batcher's condition variable), so
     the mutation happens on the replica's OWN worker thread — the
     engine and its ``PageAllocator`` are single-driver by contract, and
@@ -256,10 +257,10 @@ class ServingChaos:
 
     #: entry points legal for faults that may fire under the batcher's
     #: condition variable (can_admit is called inside the admit scan)
-    _ANY = ("dispatch_step", "advance_spec", "can_admit")
+    _ANY = ("dispatch_step", "dispatch_round", "advance_spec", "can_admit")
     #: entry points for faults that must fire OUTSIDE every lock
     #: (sleeps) or that only make sense for a decode dispatch (poison)
-    _DISPATCH = ("dispatch_step", "advance_spec")
+    _DISPATCH = ("dispatch_step", "dispatch_round", "advance_spec")
 
     def __init__(self, batcher) -> None:
         self.batcher = batcher

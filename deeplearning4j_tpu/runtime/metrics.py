@@ -289,14 +289,17 @@ class DecodeMetrics:
       ``rows_dispatched / prefill_dispatches`` is the width a join
       took, ``1 - valid / dispatched`` the share that was padding;
     - ``decode_dispatches_ahead``: decode dispatches issued while the
-      step before was still uncollected, their tokens taken from its
-      output on the device (``DecodeEngine.dispatch_step(after=)``): over
-      ``decode_dispatches`` the share of steps that overlapped the
-      host's deliver, expire, admit and stage (0 for speculative
-      rounds, which stay in series); ``decode_overshoot_steps``: slot
-      steps whose token was discarded when it landed because the
-      request had ended meanwhile (an ``eos_id`` is known one step
-      late; a deadline or an eviction with a step in flight);
+      step (or self-draft round) before was still uncollected, their
+      tokens taken from its output on the device
+      (``DecodeEngine.dispatch_step(after=)``, ``dispatch_round(after=)``):
+      over ``decode_dispatches`` the share of steps that overlapped the
+      host's deliver, expire, admit and stage (0 for a second model's
+      speculative rounds, which stay in series);
+      ``decode_overshoot_steps``: slot steps whose tokens were discarded
+      when they landed because the request had ended meanwhile (an
+      ``eos_id`` is known one step late, an end inside a round's
+      commits one round late; a deadline or an eviction with a step in
+      flight);
     - ``joins``: requests that prefilled into a slot while OTHER slots
       were mid-decode (the continuous-batching event: nobody waited for
       a cohort to finish);

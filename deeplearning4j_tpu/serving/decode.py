@@ -149,7 +149,10 @@ tables, and the join's prefill dispatches fill them.  Rows written
 ahead of a slot's committed frontier into a bounded kind's ring are
 held to THE RING RULE (:class:`_PageKind`).  Either way the committed
 stream is, token for token, the plain ``advance()``'s: sampling keys
-are of (seed, position), not of a step.
+are of (seed, position), not of a step.  A self-draft round, like a
+plain step, runs ONE AHEAD of its fetch under a ``ContinuousBatcher``
+(:meth:`DecodeEngine.dispatch_round`): the next round takes its tokens,
+positions and drafts from the round in flight on the device.
 """
 
 from __future__ import annotations
@@ -699,6 +702,38 @@ class _Step:
         self.collected = False
 
 
+class _Round:
+    """One self-draft speculative round on its way: what
+    :meth:`DecodeEngine.dispatch_round` hands back and
+    :meth:`DecodeEngine.collect_round` takes.  ``out`` is the round's
+    flat output ON THE DEVICE, ``run`` the slots it ran, ``epoch`` each
+    slot's occupancy stamp then, ``pos`` the host's positions at
+    dispatch and ``fed`` the positions the round was handed (``pos``
+    itself, or a device array where slots took theirs from ``after``:
+    the mask ``taken``), ``w`` / ``rungs`` its table width and the rungs
+    it carried.  ``after`` is the uncollected round it took its tokens,
+    positions and drafts from, kept until this round is collected: a
+    round may run ONE ahead of the fetch, never two.  Collecting sets
+    ``at``, the positions the round wrote at, and ``n_c``, its commit
+    counts: the round behind it reads both at its own collect."""
+
+    __slots__ = ("out", "run", "epoch", "pos", "fed", "taken", "w", "rungs",
+                 "after", "collected", "at", "n_c")
+
+    def __init__(self, out, run, epoch, pos, fed, taken, w, rungs, after):
+        self.out = out
+        self.run = run
+        self.epoch = epoch
+        self.pos = pos
+        self.fed = fed
+        self.taken = taken
+        self.w = w
+        self.rungs = rungs
+        self.after = after
+        self.collected = False
+        self.at = self.n_c = None
+
+
 class DecodeEngine:
     """Slot-structured, page-pooled KV-cache decode engine for a causal
     LM: ONE table of ``n_slots`` slots, the sequences it runs at once
@@ -1007,6 +1042,25 @@ class DecodeEngine:
                 return fam.paged_self_draft_round(
                     cfg, params, pool, ptab, tokens, pos, active,
                     temperature, seeds, drafts)
+
+            k_round = self.draft_k
+            cut = np.cumsum([n_slots * (k_round + 1), n_slots,
+                             n_slots * k_round]).tolist()
+
+            def round_ahead_fn(prev_out, prev_pos, tokens_h, pos_h, drafts_h,
+                               from_host):
+                # what a round dispatched while the round before it is
+                # still uncollected feeds: where that round ran the slot,
+                # its last committed token, its position plus its commit
+                # count and its next draft; the host mirrors elsewhere
+                g = prev_out[:cut[0]].reshape(n_slots, k_round + 1)
+                n_c = prev_out[cut[0]:cut[1]]
+                nxt = prev_out[cut[1]:cut[2]].reshape(n_slots, k_round)
+                last = jnp.take_along_axis(
+                    g, jnp.maximum(n_c - 1, 0)[:, None], axis=1)[:, 0]
+                return (jnp.where(from_host, tokens_h, last),
+                        jnp.where(from_host, pos_h, prev_pos + n_c),
+                        jnp.where(from_host[:, None], drafts_h, nxt))
         elif draft is not None:
             def verify_fn(params, pool, ptab, tokens, pos, active,
                           temperature, seeds, drafts):
@@ -1138,11 +1192,15 @@ class DecodeEngine:
             tokens_ahead_fn, key=(key, geo, "tokens_ahead"),
             label=f"{label}.tokens_ahead", **shard_kw_ahead)
         self._verify = self._draft_fn = self._draft_prefill = None
-        self._spec = None
+        self._spec = self._round_ahead = None
         if self._self_draft:
             self._spec = compile_cache.cached_jit(
                 spec_fn, key=(key, geo, "spec"),
                 label=f"{label}.spec", donate_argnums=(1,))
+            # like ``tokens_ahead_fn``, named apart from the round program
+            self._round_ahead = compile_cache.cached_jit(
+                round_ahead_fn, key=(key, geo, "round_ahead"),
+                label=f"{label}.round_ahead")
         elif draft is not None:
             k_steps = self.draft_k
 
@@ -1734,9 +1792,17 @@ class DecodeEngine:
                     self._pool = pool = self._write(pool, ptab_s,
                                                     *pages)
                 if self._self_draft:
-                    self._pool, _ = self._spec(
+                    pool, out = self._spec(
                         params, pool, ptab, tokens, pos, idle, temps, seeds,
                         self._drafts_h)
+                    # and as a round ONE AHEAD of its fetch takes it:
+                    # tokens, positions and drafts device arrays, merged
+                    # from the round before
+                    toks_a, pos_a, drafts_a = self._round_ahead(
+                        out, pos, tokens, pos, self._drafts_h, ~idle)
+                    self._pool, _ = self._spec(
+                        params, pool, ptab, toks_a, pos_a, idle, temps,
+                        seeds, drafts_a)
                 elif self.draft is not None:
                     self._dpool = self._draft_prefill(
                         self._draft_params, self._dpool, ptab_s,
@@ -2147,9 +2213,12 @@ class DecodeEngine:
         inactive/stalled slots).  Greedy target ⇒ bit-identical stream
         to non-speculative decode; sampled targets stay identical too,
         because sampling keys are POSITION-keyed (gpt._slot_key), not
-        step-keyed."""
+        step-keyed.  Where the draft is the model's own block it is
+        ``collect_round(dispatch_round())``, the two halves a
+        ``ContinuousBatcher`` orders ONE ROUND APART; a second model's
+        round, two dispatches over two pools, stays in series."""
         if self._self_draft:
-            return self._advance_self_draft()
+            return self.collect_round(self.dispatch_round())
         if self._draft_fn is None:
             raise RuntimeError("engine built without draft=")
         b = self._slots
@@ -2197,51 +2266,115 @@ class DecodeEngine:
                                       self._live_rows(), self.page_tokens)
             return toks, n_c
 
-    def _advance_self_draft(self) -> Tuple[np.ndarray, np.ndarray]:
-        """:meth:`advance_spec` where the draft is the model's own block
-        (``draft="self"``): ONE dispatch a round.  Every running slot
-        feeds its current token and its pending draft (``_drafts_h``);
-        the family's round verifies, commits and drafts again, and the
-        tokens, the commit counts, the next drafts and the family's
-        counters (of all ``k + 1`` rows a slot, the rejected draft's
-        among them) come back in the one fetch.  The draft block's cache
-        rows live behind the slot's own tables, so there is no second
-        pool, and its prefill was the join's."""
+    def dispatch_round(self, after: Optional[_Round] = None) -> _Round:
+        """The first half of :meth:`advance_spec` where the draft is the
+        model's own block (``draft="self"``): stage and dispatch ONE
+        round, fetch nothing.  Every running slot feeds its current
+        token and its pending draft; the family's round verifies,
+        commits and drafts again, and the tokens, the commit counts, the
+        next drafts and the family's counters (of all ``k + 1`` rows a
+        slot, the rejected draft's among them) come back in the one
+        fetch.  The draft block's cache rows live behind the slot's own
+        tables, so there is no second pool, and its prefill was the
+        join's.
+
+        A round commits between 1 and ``k + 1`` tokens a slot, so the
+        host moves ``pos_h`` by the ONE that is certain here, for every
+        slot that ran, and by the rest at :meth:`collect_round`.  A
+        caller may dispatch the next round before it collects this one,
+        handing this one as ``after``: every slot this round ran and
+        that has not been released or restarted since then takes its
+        token, position and draft from this round's output ON THE
+        DEVICE, merged with the host mirrors by one small program of its
+        own (``jit_round_ahead_fn``; the round program, its arguments and
+        its text are what they were).  Such a slot may stand up to ``k``
+        rows past ``pos_h``, so its pages and the table's width are
+        staged for ``2 k`` rows past it, not ``k``; the ring rule is of
+        rows ahead of the frontier on the device, where positions are
+        exact, and holds as it did.  ONE round ahead, no more:
+        dispatching behind a round whose own ``after`` is still
+        uncollected raises."""
+        if (after is not None and after.after is not None  # jaxlint: disable=host-sync-in-hot-path — a _Round is a host record: the test reads no device value
+                and not after.after.collected):
+            raise RuntimeError(
+                "a speculative round runs ONE round ahead of its fetch: "
+                "collect the round before last first")
         b = self._slots
-        k, S = self.draft_k, self.n_slots
+        k = self.draft_k
         with telemetry.span("decode.advance",
                             counter=(decode_metrics, "advance_s"),
                             active=self.n_active(), k=k) as sp:
             params = self.current_params()
             with telemetry.span("decode.stage",
                                 counter=(decode_metrics, "stage_s")):
-                ptab, tokens, pos, run, w, rungs = self._stage(k)
-                n_run = int(run.sum())
-                sp.set(n_run=n_run, **self._width_attrs(w, rungs))
+                ptab, tokens, pos, run, w, rungs = self._stage(
+                    k if after is None else 2 * k)
+                sp.set(n_run=int(run.sum()), **self._width_attrs(w, rungs))
                 pool = self._pool_state()
             with telemetry.span("decode.dispatch",
                                 counter=(decode_metrics, "dispatch_s")):
+                fed, drafts, taken = pos, self._drafts_h.copy(), None
                 try:
-                    pool, out = self._spec(params, pool, ptab, tokens, pos,
-                                           run, b.temps, b.seeds,
-                                           self._drafts_h.copy())
+                    if after is not None:  # jaxlint: disable=host-sync-in-hot-path — a host record, as above
+                        taken = after.run & (after.epoch == b.epoch)
+                        tokens, fed, drafts = self._round_ahead(
+                            after.out, after.fed, tokens, pos, drafts,
+                            ~taken)
+                    pool, out = self._spec(params, pool, ptab, tokens, fed,
+                                           run, b.temps, b.seeds, drafts)
+                    # asked for now, so the copy is not queued behind
+                    # the round a caller dispatches next
+                    out.copy_to_host_async()
                 except Exception:
                     self._drop_pool()           # donated into the failure
                     raise
                 self._pool = pool
-            flat = self._fetch(out)
+            b.pos_h[run] += 1
+            return _Round(out, run, b.epoch.copy(), pos, fed, taken, w,
+                          rungs, after)
+
+    def collect_round(self, rnd: _Round) -> Tuple[np.ndarray, np.ndarray]:
+        """The second half of a self-draft round: fetch it and book it.
+        Returns ``(out [S, k+1], n_commit [S])``: slot s committed
+        ``out[s, :n_commit[s]]`` (0 for a slot the round did not run).
+        For the slots that still hold the sequence the round ran, ``pos_h``
+        moves by the commits past the one :meth:`dispatch_round` counted,
+        and the last committed token and the next draft land in the host
+        mirrors.  The round's rows are booked at the positions it wrote
+        them: where it took a slot's from ``after``, that round's
+        positions plus its commits."""
+        b = self._slots
+        k, S = self.draft_k, self.n_slots
+        prev = rnd.after
+        if prev is not None and not prev.collected:
+            raise RuntimeError(
+                "a speculative round is collected after the round it ran "
+                "behind")
+        with telemetry.span("decode.advance",
+                            counter=(decode_metrics, "advance_s"),
+                            k=k) as sp:
+            try:
+                flat = self._fetch(rnd.out)
+            except Exception:
+                self._drop_pool()       # the round failed on the device
+                raise
             toks, n_c, nxt, counts = np.split(
                 flat, [S * (k + 1), S * (k + 2), S * (2 * k + 2)])
             toks, nxt = toks.reshape(S, k + 1), nxt.reshape(S, k)
             if self._decode_counters:
                 decode_metrics.note_family_counts(self._decode_counters,
                                                   counts)
-            idx = np.flatnonzero(n_c)
-            b.tokens_h[idx] = toks[idx, n_c[idx] - 1]
-            b.pos_h += n_c
-            self._drafts_h[idx] = nxt[idx]
+            at = rnd.pos if prev is None else np.where(
+                rnd.taken, prev.at + prev.n_c, rnd.pos)
+            # the round it ran behind is collected: let it go
+            rnd.after, rnd.collected, rnd.at, rnd.n_c = None, True, at, n_c
+            keep = np.flatnonzero(rnd.run & (rnd.epoch == b.epoch))
+            b.pos_h[keep] += n_c[keep] - 1
+            b.tokens_h[keep] = toks[keep, n_c[keep] - 1]
+            self._drafts_h[keep] = nxt[keep]
+            n_run = int(rnd.run.sum())
             decode_metrics.note_decode_dispatch(
-                n_run, S, rungs, S * w)
+                n_run, S, rnd.rungs, S * rnd.w, ahead=prev is not None)
             decode_metrics.note_spec(k * n_run,
                                      int(np.maximum(n_c - 1, 0).sum()))
             sp.set(committed=int(n_c.sum()))
@@ -2250,9 +2383,10 @@ class DecodeEngine:
             if self._kind_names:
                 # every committed position once: a rejected draft's is
                 # the next round's own
-                rows = pos[:, None] + np.arange(k + 1)
+                idx = np.flatnonzero(n_c)
+                rows = at[:, None] + np.arange(k + 1)
                 self._note_kinds(rows[np.arange(k + 1) < n_c[:, None]],
-                                 held_at=pos[idx] + n_c[idx] - 1)
+                                 held_at=at[idx] + n_c[idx] - 1)
             return toks, n_c
 
     def release(self, slot: int) -> None:
@@ -2491,9 +2625,20 @@ class ContinuousBatcher:
     successor's prefill writes the released pages after the step that
     still reads them); an end by ``eos_id`` is known one step late, and
     that slot runs ONE step past it, whose token is dropped and whose
-    row, at the slot's own next position, is never attended.  A
-    speculative round advances a slot by a count that IS data, so it
-    stays in series with its fetch."""
+    row, at the slot's own next position, is never attended.
+
+    A SELF-DRAFT ROUND runs one round ahead the same way
+    (``DecodeEngine.dispatch_round(after=)``): its commit count is data,
+    but the device merges it into the next round's tokens, positions and
+    drafts, and the host stages the pages for the most it can be.  A
+    round commits at least one token a slot, so the end by count that is
+    CERTAIN at dispatch (delivered, + this round's one, + one for a
+    round in flight) releases the slot there; any other end (a round
+    that commits more, an ``eos_id``) is seen when the round lands, and
+    the slot's round behind it is dropped.  A brownout flip of
+    ``spec_enabled`` lands what is in flight before the other kind of
+    dispatch goes out, and a second model's round (two dispatches) stays
+    in series."""
 
     #: a request is requeued at most this many times after failed
     #: dispatches before its error resolves the future — an injected
@@ -2740,19 +2885,23 @@ class ContinuousBatcher:
             return True
         return False
 
-    def _dispatch_ahead(self, flying) -> None:
-        """Dispatch the next plain step for every running slot, behind
-        the step still in flight (``flying``, which the caller lands
-        afterwards).  A slot whose request this step completes BY COUNT
-        is released here, its request left to land its last token, so
-        the slot is never run again for it and the next pass may admit
-        a successor under the step."""
-        step = self.engine.dispatch_step(after=flying and flying[0])
+    def _dispatch_ahead(self, flying, rounds: bool) -> None:
+        """Dispatch the next plain step (or, where ``rounds``, the next
+        self-draft round) for every running slot, behind
+        the step or round still in flight (``flying``, which the caller
+        lands afterwards).  A slot whose request this dispatch completes
+        BY COUNT for certain (each step and each round commits at least
+        one token) is released here, its request left to land its last
+        token, so the slot is never run again for it and the next pass
+        may admit a successor under the dispatch."""
+        after = flying and flying[0]
+        step = (self.engine.dispatch_round(after=after) if rounds
+                else self.engine.dispatch_step(after=after))
         with self._cv:
             owners = {slot: r for slot, r in self._placed.items()
                       if step.run[slot]}
             self._flying = (step, owners)
-            # delivered, + this step's, + the one still in flight
+            # delivered, + this dispatch's least, + the one still in flight
             landing = flying[1] if flying else {}
             done = [(slot, r) for slot, r in owners.items()
                     if len(r._tokens) + 1 + (landing.get(slot) is r)
@@ -2763,10 +2912,14 @@ class ContinuousBatcher:
         for slot, _ in done:
             self.engine.release(slot)
 
-    def _land(self, step: _Step, owners: Dict[int, DecodeRequest]) -> None:
-        """Collect a dispatched step and deliver its tokens to the
-        requests its slots held when it went out."""
-        toks = self.engine.collect(step)
+    def _land(self, step, owners: Dict[int, DecodeRequest]) -> None:
+        """Collect a dispatched step or round and deliver its tokens (a
+        round's commits, in order) to the requests its slots held when
+        it went out."""
+        if isinstance(step, _Round):
+            toks, n_c = self.engine.collect_round(step)
+        else:
+            toks, n_c = self.engine.collect(step)[:, None], None
         self.dispatch_error_streak = 0
         with telemetry.span("decode.deliver",
                             counter=(decode_metrics, "deliver_s")):
@@ -2777,15 +2930,17 @@ class ContinuousBatcher:
                 if r.done():    # ended (eos, deadline, eviction) since
                     dropped += 1
                     continue
-                tok = int(toks[slot])
-                r._push(tok)
-                self._maybe_finish(slot, r, tok, n_out=len(r._tokens))
+                for tok in toks[slot, :1 if n_c is None else n_c[slot]]:
+                    r._push(int(tok))
+                    if self._maybe_finish(slot, r, int(tok),
+                                          n_out=len(r._tokens)):
+                        break
             if dropped:
                 decode_metrics.note_overshoot(dropped)
 
     def _spec_round(self) -> None:
-        """One speculative round: dispatched, fetched and delivered in
-        series (its commit counts are data the next dispatch needs)."""
+        """One speculative round of a second model as the draft:
+        dispatched, fetched and delivered in series."""
         out, n_c = self.engine.advance_spec()
         self.dispatch_error_streak = 0
         with telemetry.span("decode.deliver",
@@ -2839,28 +2994,38 @@ class ContinuousBatcher:
 
     def _advance_all(self) -> Tuple[int, int]:
         """ONE dispatch for every running slot, whatever their rungs,
-        and the tokens of the step before it delivered while it runs;
-        returns (dispatches made, whether the pass touched the engine
-        at all: a step landed, or a dispatch failed), each 0 or 1."""
+        and the tokens of the step (or round) before it delivered while
+        it runs; returns (dispatches made, whether the pass touched the
+        engine at all: a step landed, or a dispatch failed), each 0 or
+        1."""
         eng = self.engine
         flying, self._flying = self._flying, None
+        # what the engine observes decides, once a pass (the brownout
+        # ladder flips ``spec_enabled`` from the router's thread): a
+        # self-draft round, a second model's round in series, or a step
         spec = eng.draft is not None and eng.spec_enabled
+        rounds = spec and eng.draft == "self"
+        series = spec and not rounds
+        touched = int(flying is not None)
         dispatched = 0
         try:
-            if spec and flying is not None:
-                # a round's commit count is data: in series, and behind
-                # whatever plain step a brownout left in flight
+            if flying is not None and (
+                    series or isinstance(flying[0], _Round) != rounds):
+                # a second model's round is in series, and a brownout
+                # flip lands what is in flight before the other kind of
+                # dispatch goes out
                 self._land(*flying)
+                flying = None
             with self._cv:
                 # what THIS batcher placed: an evacuated one's slots stay
                 # active in an engine that is discarded with it
                 running = bool(self._placed)
             if running:
                 try:
-                    if spec:
+                    if series:
                         self._spec_round()
                     else:
-                        self._dispatch_ahead(flying)
+                        self._dispatch_ahead(flying, rounds)
                     dispatched = 1
                 except KVPagesExhausted as e:
                     # page deadlock breaker: the pool cannot advance ANY
@@ -2874,12 +3039,12 @@ class ContinuousBatcher:
                     eng.release(e.slot)
                     if r is not None:
                         r._finish(e)
-            if flying is not None and not spec:
+            if flying is not None:
                 self._land(*flying)
         except Exception as e:
             self._replay_all(e)
             return 0, 1     # its spans booked their seconds: no idle pass
-        return dispatched, int(flying is not None)
+        return dispatched, touched
 
     def _expire(self) -> None:
         """Free every deadline-expired request (worker thread): queued
